@@ -5,8 +5,8 @@ Four sections:
 * ``run``            — interaction-tile throughput vs tile shape (jnp path
                        plus a Pallas interpret-mode parity point).
 * ``run_compaction`` — ``ops.query_block`` with ``compaction="dense"`` (two
-                       XLA phases: mask materialization + cumsum/scatter +
-                       interval recompute) vs ``compaction="fused"`` (PR 2's
+                       passes: dense mask and interval planes, then an XLA
+                       cumsum/scatter) vs ``compaction="fused"`` (PR 2's
                        in-kernel compaction), both through the Pallas
                        kernel so the comparison isolates the compaction
                        strategy.

@@ -104,6 +104,9 @@ def main(argv=None) -> int:
                     help="baseline report bench_pr10 compares against")
     args = ap.parse_args(argv)
 
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
+
     from benchmarks import (broker_bench, fault_bench, fig3_interactions,
                             kernel_bench, lint_bench, prune_bench,
                             roofline_report, shard_bench, speedup_vs_rtree,

@@ -18,8 +18,11 @@ Run:  PYTHONPATH=src python examples/batch_tuning.py
 import time
 
 from repro.api import ExecutionPolicy, TrajectoryDB
+from repro.compile_cache import enable_compile_cache
 from repro.core.perfmodel import (ResponseTimeModel, benchmark_device_curves,
                                   benchmark_host_curves)
+
+enable_compile_cache()
 
 db = TrajectoryDB.from_scenario(
     "S5", scale=0.01,

@@ -21,6 +21,9 @@ Run:  PYTHONPATH=src python examples/quickstart.py
 import numpy as np
 
 from repro.api import ExecutionPolicy, TrajectoryDB
+from repro.compile_cache import enable_compile_cache
+
+enable_compile_cache()
 
 # 1. dataset + index: one constructor owns sorting and index construction
 policy = ExecutionPolicy(batching="periodic", batch_params={"s": 64},

@@ -13,10 +13,13 @@ import time
 import jax
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.configs import ARCHS
 from repro.models import transformer as T
 from repro.serve import batcher
 from repro.serve.engine import ServeEngine
+
+enable_compile_cache()
 
 rng = np.random.default_rng(0)
 requests = [batcher.Request(i, list(rng.integers(1, 60,

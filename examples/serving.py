@@ -18,6 +18,10 @@ Run: ``PYTHONPATH=src python examples/serving.py``
 import numpy as np
 
 from repro.api import AdmissionError, TrajectoryDB
+from repro.compile_cache import enable_compile_cache
+
+enable_compile_cache()
+
 
 def main():
     db = TrajectoryDB.from_scenario("S2", scale=0.01)

@@ -21,7 +21,8 @@ preconditions (pre-sorted queries, manual plan construction, reaching into
   **caller's original query order** (the raw engine indexes the internally
   sorted array — a silent off-by-permutation trap this facade removes).
 * Execution strategy is pluggable via the :class:`QueryBackend` protocol:
-  ``"pallas"`` (the TPU kernel, interpret mode on CPU), ``"jnp"`` (the XLA
+  ``"pallas"`` (the TPU kernel: compiled on a TPU, interpreted on a
+  CPU), ``"jnp"`` (the XLA
   oracle — the right default on CPU), ``"rtree"`` (the paper's §7.3
   search-and-refine CPU baseline), ``"brute"`` (the all-pairs oracle) and
   ``"shard"`` (the temporal-pod mesh backend from ``repro.core.
@@ -72,7 +73,8 @@ from repro.core.planner import PRUNINGS, QueryPlan, QueryPlanner
 from repro.core.rtree import RTreeEngine
 from repro.core.scheduler import DeadlineScheduler, SchedulerStats
 from repro.core.segments import SegmentArray
-from repro.kernels.distthresh import DEFAULT_CAND_BLK, DEFAULT_QRY_BLK
+from repro.kernels.distthresh import (DEFAULT_CAND_BLK, DEFAULT_QRY_BLK,
+                                      resolve_interpret)
 
 #: Names accepted by ``TrajectoryDB.query(backend=...)``.
 BACKENDS = ("pallas", "jnp", "rtree", "brute", "shard")
@@ -135,7 +137,9 @@ class ExecutionPolicy:
     cand_blk: int = DEFAULT_CAND_BLK
     qry_blk: int = DEFAULT_QRY_BLK
     capacity: int = 4096                  # result-buffer slots per batch
-    interpret: bool = True                # Pallas interpret mode (CPU)
+    #: Pallas interpret mode; None → from the device's platform
+    #: (``distthresh.resolve_interpret``: interpreted only on a CPU)
+    interpret: bool | None = None
     compaction: str = "fused"             # "fused" in-kernel | "fused_rowloop"
     #                                       gather-free hatch | "dense" 2-phase
     pipeline: bool = True                 # async 2-phase executor (O(1) syncs)
@@ -152,7 +156,9 @@ class ExecutionPolicy:
     # -- sharded mesh backend (backend="shard") -------------------------
     shard_pods: int | None = None         # None → every local device
     shard_capacity: int = 4096            # result slots per pod per batch
-    shard_use_pallas: bool = False        # Pallas kernels inside shard_map
+    #: Pallas kernels inside shard_map; None → wherever they compile
+    #: (every platform but the CPU, where the mesh runs the jnp oracle)
+    shard_use_pallas: bool | None = None
     shard_balance: str = "time"           # pod partition: "time" | "num_ints"
     #: Sparse routed dispatch (PR 8): pods with zero candidates for a
     #: batch short-circuit the sharded step (``lax.cond``) instead of
@@ -198,6 +204,14 @@ class ExecutionPolicy:
             "greedysetsplit-min": {"bound": s},
             "greedysetsplit-max": {"bound": 2 * s},
         }[self.batching]
+
+
+def _shard_use_pallas(pol: ExecutionPolicy) -> bool:
+    """The mesh backend's kernel choice: the policy's, or else the Pallas
+    kernel wherever it compiles (every platform but the CPU)."""
+    if pol.shard_use_pallas is not None:
+        return bool(pol.shard_use_pallas)
+    return not resolve_interpret()
 
 
 # ----------------------------------------------------------------------
@@ -498,20 +512,21 @@ class TrajectoryDB:
                     pol.compaction, pol.pipeline, pol.pruning,
                     pol.max_capacity_retries)
         if name == "shard":
+            use_pallas = _shard_use_pallas(pol)
             # compaction (and kernel pruning) only matter on the Pallas
             # path — key on the effective values so policies differing in
             # an irrelevant knob share one (expensively constructed) mesh
             # engine.
-            compaction = pol.compaction if pol.shard_use_pallas else "dense"
+            compaction = pol.compaction if use_pallas else "dense"
             # kernel-level pruning exists only on the fused Pallas path
             # (mirrors ShardedEngine.__init__'s normalization)
-            pruning = (pol.pruning if pol.shard_use_pallas
+            pruning = (pol.pruning if use_pallas
                        and compaction in ("fused", "fused_rowloop")
                        else "none")
             # pol.pruning itself (not just the kernel-effective value)
             # shapes construction too: hierarchical builds the pod-local
             # K-box plan index (PR 8)
-            return (pol.shard_pods, pol.shard_capacity, pol.shard_use_pallas,
+            return (pol.shard_pods, pol.shard_capacity, use_pallas,
                     pol.shard_balance, pol.interpret, pol.cand_blk,
                     pol.qry_blk, compaction, pol.pipeline, pruning,
                     pol.pruning, pol.shard_sparse, pol.max_capacity_retries)
@@ -532,7 +547,7 @@ class TrajectoryDB:
             if name in ("pallas", "jnp"):
                 eng = copy.copy(self._base_engine)   # shares db/index/_packed
                 eng.use_pallas = (name == "pallas")
-                eng.interpret = pol.interpret
+                eng.interpret = resolve_interpret(pol.interpret)
                 eng.cand_blk = pol.cand_blk
                 eng.qry_blk = pol.qry_blk
                 eng.default_capacity = pol.capacity
@@ -543,12 +558,12 @@ class TrajectoryDB:
                 self._backends[key] = EngineBackend(name, eng)
             elif name == "shard":
                 from repro.core.distributed import ShardedEngine
-                compaction = (pol.compaction if pol.shard_use_pallas
-                              else "dense")
+                use_pallas = _shard_use_pallas(pol)
+                compaction = pol.compaction if use_pallas else "dense"
                 self._backends[key] = ShardBackend(ShardedEngine(
                     self.segments, pods=pol.shard_pods,
                     capacity_per_shard=pol.shard_capacity,
-                    use_pallas=pol.shard_use_pallas, interpret=pol.interpret,
+                    use_pallas=use_pallas, interpret=pol.interpret,
                     cand_blk=pol.cand_blk, qry_blk=pol.qry_blk,
                     compaction=compaction, pipeline=pol.pipeline,
                     balance=pol.shard_balance, pruning=pol.pruning,

@@ -50,12 +50,7 @@ from repro.core.executor import Dispatch, ResultSet, make_executor
 from repro.core.planner import as_query_plan, bucket_capacity
 from repro.core.segments import SegmentArray
 from repro.kernels import ops, ref
-
-# jax.shard_map graduated from jax.experimental after 0.4.x; support both.
-if hasattr(jax, "shard_map"):
-    _shard_map = jax.shard_map
-else:  # pragma: no cover - depends on installed jax
-    from jax.experimental.shard_map import shard_map as _shard_map
+from repro.kernels.distthresh import resolve_interpret
 
 
 # ----------------------------------------------------------------------
@@ -196,7 +191,8 @@ def choose_sharding(num_candidates: int, num_queries: int,
 
 def make_sharded_count_fn(mesh: Mesh, cand_axes: Sequence[str],
                           qry_axes: Sequence[str] = (), *,
-                          use_pallas: bool = False, interpret: bool = True):
+                          use_pallas: bool = False,
+                          interpret: bool | None = None):
     """Jitted global-count function: entries sharded on dim 0 over
     ``cand_axes``, queries sharded over ``qry_axes`` (replicated if empty).
 
@@ -213,7 +209,7 @@ def make_sharded_count_fn(mesh: Mesh, cand_axes: Sequence[str],
         cnt = jnp.sum(hit.astype(jnp.int32))
         return jax.lax.psum(cnt, all_axes) if all_axes else cnt
 
-    shmapped = _shard_map(
+    shmapped = jax.shard_map(
         local, mesh=mesh,
         in_specs=(P(cand_axes if cand_axes else None, None),
                   P(qry_axes if qry_axes else None, None), P()),
@@ -225,7 +221,8 @@ def make_sharded_count_fn(mesh: Mesh, cand_axes: Sequence[str],
 def make_sharded_query_fn(mesh: Mesh, cand_axes: Sequence[str],
                           capacity_per_shard: int, *,
                           qry_axes: Sequence[str] = (),
-                          use_pallas: bool = False, interpret: bool = True,
+                          use_pallas: bool = False,
+                          interpret: bool | None = None,
                           cand_blk: int = 256, qry_blk: int = 256):
     """Jitted full query step with local compaction, sharded in 2-D.
 
@@ -270,20 +267,21 @@ def make_sharded_query_fn(mesh: Mesh, cand_axes: Sequence[str],
         out["count"] = out["count"][None]
         return out
 
-    shmapped = _shard_map(
+    shmapped = jax.shard_map(
         local, mesh=mesh,
         in_specs=(P(cand_axes, None),
                   P(qry_axes if qry_axes else None, None), P()),
         out_specs={"entry_idx": P(all_axes), "query_idx": P(all_axes),
                    "t_enter": P(all_axes), "t_exit": P(all_axes),
                    "count": P(all_axes)},
+        check_vma=False,     # as in make_pod_query_fn: Pallas may run here
     )
     return jax.jit(shmapped), ways
 
 
 def make_pod_query_fn(mesh: Mesh, capacity_per_shard: int, *,
                       pod_axis: str = "pod", use_pallas: bool = False,
-                      interpret: bool = True, cand_blk: int = 256,
+                      interpret: bool | None = None, cand_blk: int = 256,
                       qry_blk: int = 256, compaction: str = "dense",
                       pruning: str = "none", sparse: bool = False):
     """Jitted per-batch query step for the temporal-pod mesh backend.
@@ -364,13 +362,19 @@ def make_pod_query_fn(mesh: Mesh, capacity_per_shard: int, *,
         in_specs = (P(pod_axis, None, None), P(pod_axis), P(None, None),
                     P())
 
-    shmapped = _shard_map(
+    # check_vma=False: shard_map's varying-axes check refuses a
+    # pallas_call, whose out_shape names no varying axes, and would also
+    # refuse the cond, whose branches vary over the pods in different
+    # outputs (the constant empty block against the per-pod kernel).
+    # Every output is per pod except ``total``, which the psum replicates.
+    shmapped = jax.shard_map(
         local, mesh=mesh,
         in_specs=in_specs,
         out_specs={"entry_idx": P(pod_axis), "query_idx": P(pod_axis),
                    "t_enter": P(pod_axis), "t_exit": P(pod_axis),
                    "count": P(pod_axis), "total": P(),
                    "pruned_tiles": P(pod_axis), "num_tiles": P(pod_axis)},
+        check_vma=False,
     )
     return jax.jit(shmapped)
 
@@ -560,7 +564,7 @@ class ShardedEngine:
 
     def __init__(self, db: SegmentArray, *, mesh: Mesh | None = None,
                  pods: int | None = None, capacity_per_shard: int = 4096,
-                 use_pallas: bool = False, interpret: bool = True,
+                 use_pallas: bool = False, interpret: bool | None = None,
                  cand_blk: int = 256, qry_blk: int = 256,
                  compaction: str = "dense", pipeline: bool = True,
                  balance: str = "time", pruning: str = "spatial",
@@ -581,7 +585,8 @@ class ShardedEngine:
                                                  balance=balance)
         self.capacity_per_shard = capacity_per_shard
         self.use_pallas = use_pallas
-        self.interpret = interpret
+        # Interpret mode follows the platform of the mesh's devices.
+        self.interpret = resolve_interpret(interpret, mesh.devices.flat[0])
         self.cand_blk = cand_blk
         self.qry_blk = qry_blk
         self.compaction = compaction
@@ -611,19 +616,6 @@ class ShardedEngine:
                         else "none")
         self._pad_t = float(self.db.temporal_extent[1]) + 1.0
         self._fns: dict[int, object] = {}
-        if self.use_pallas and self.compaction == "fused":
-            # ops.query_block's automatic fused→rowloop fallback cannot
-            # trigger inside the shard_map closure — a Mosaic lowering
-            # failure there surfaces at the *outer* jit's compile, outside
-            # its try/except.  Probe the fused path with a direct tiny
-            # compile now and bake the resolved strategy into the step.
-            probe = np.zeros((1, 8), np.float32)
-            ops.query_block(probe, probe, np.float32(1.0), capacity=8,
-                            use_pallas=True, interpret=self.interpret,
-                            cand_blk=self.cand_blk, qry_blk=self.qry_blk,
-                            compaction="fused")
-            if ops._fused_fallback["tripped"]:
-                self.compaction = "fused_rowloop"
 
     # ------------------------------------------------------------------
     def _fn(self, capacity: int):

@@ -54,7 +54,8 @@ from repro.core.planner import (QueryPlan, as_query_plan,
                                 bucket_capacity as _bucket)
 from repro.core.segments import SegmentArray
 from repro.kernels import ops
-from repro.kernels.distthresh import DEFAULT_CAND_BLK, DEFAULT_QRY_BLK
+from repro.kernels.distthresh import (DEFAULT_CAND_BLK, DEFAULT_QRY_BLK,
+                                      resolve_interpret)
 
 
 class _QueryBlockDispatcher:
@@ -141,7 +142,7 @@ class DistanceThresholdEngine:
     """In-memory distance-threshold query engine over a trajectory database."""
 
     def __init__(self, db: SegmentArray, *, num_bins: int = DEFAULT_NUM_BINS,
-                 use_pallas: bool = False, interpret: bool = True,
+                 use_pallas: bool = False, interpret: bool | None = None,
                  cand_blk: int = DEFAULT_CAND_BLK, qry_blk: int = DEFAULT_QRY_BLK,
                  default_capacity: int = 4096, compaction: str = "fused",
                  pipeline: bool = True, pruning: str = "spatial",
@@ -149,12 +150,15 @@ class DistanceThresholdEngine:
         """``use_pallas=False`` routes interactions through the jnp oracle —
         the right default on CPU where Pallas runs in interpret mode.  Both
         paths share identical semantics (tests assert equality).
+        ``interpret=None`` resolves from the default device
+        (``repro.kernels.distthresh.resolve_interpret``): the Pallas
+        interpreter on a CPU, the compiled kernel on a TPU.
 
         ``compaction`` selects the result-compaction strategy ("fused" uses
-        the in-kernel compaction kernel on the Pallas path, falling back to
-        "fused_rowloop" if the gather path fails to lower — see
-        ``repro.kernels.ops``; "dense" forces the two-phase fallback; the
-        jnp oracle is always dense).  ``pipeline`` selects the async
+        the in-kernel compaction kernel on the Pallas path; "fused_rowloop"
+        its interpret-only per-row variant — see ``repro.kernels.ops``;
+        "dense" forces the two-phase pass; the jnp oracle is always
+        dense).  ``pipeline`` selects the async
         two-phase executor (see the module docstring); both can be
         overridden per call on :meth:`execute`.
 
@@ -186,7 +190,7 @@ class DistanceThresholdEngine:
         self._packed_perm = (self._packed if self.index.perm is None
                              else self._packed[self.index.perm])
         self.use_pallas = use_pallas
-        self.interpret = interpret
+        self.interpret = resolve_interpret(interpret)
         self.cand_blk = cand_blk
         self.qry_blk = qry_blk
         self.default_capacity = default_capacity

@@ -20,32 +20,40 @@ Layout choices (the important part):
   entry block stays VMEM-resident while query blocks stream past it — the
   same reuse the GPU kernel gets from its thread-private candidate copy
   (paper §8.1.3's observation about Mixed-execution reuse).
+* scalars (``d``, the inflated prune threshold, the running hit counter,
+  the pruned-tile counter, the tile MBRs) live in SMEM; the flat result
+  buffers are ``(rows, 128)`` VMEM planes, slot ``f`` at ``(f // 128,
+  f % 128)``.
 
-Two kernels share the interval math (:func:`_tile_intervals`):
+Two kernels share the interval math (:func:`_interval_math`):
 
 * :func:`distthresh_pallas` — the dense kernel: materializes the full
   (C, Q) ``(t_enter, t_exit, hit)`` tile set in HBM; a host-side XLA pass
   compacts it (``ops.query_block(compaction="dense")``).
 * :func:`distthresh_compact_pallas` — the **fused in-kernel compaction**
-  kernel (this PR's tentpole): the TPU grid runs its tiles *sequentially*
-  on one core, so a running hit counter carried in the revisited ``count``
-  output block is the deterministic analogue of the paper's §5
-  ``atomic_inc`` result append.  Each tile computes its hit mask, locates
-  every hit with a masked prefix sum + rank-selection (row-major over the
-  tile), recomputes the hit pairs' intervals on small VMEM gathers, and
-  appends them at the running counter's offset into capacity-bounded flat
-  result buffers.  Non-hits never touch HBM — neither the dense interval
-  tiles nor the hit mask leave the core — and the exact total hit count
-  comes back with the results, so overflow detection needs no dense pass
-  and no host-side recompute phase.
+  kernel: the TPU grid runs its tiles *sequentially* on one core, so a
+  running hit counter carried in SMEM across the grid is the deterministic
+  analogue of the paper's §5 ``atomic_inc`` result append.  Each tile
+  computes its hit mask and appends its hits, row-major over the tile, at
+  the running counter's offset into capacity-bounded result buffers.
+  Non-hits never touch HBM, and the exact total hit count comes back with
+  the results, so overflow detection needs no dense pass.
 
-The fused kernel has two append strategies (``append=``): ``"chunk"`` — the
-masked-prefix-sum rank-selection path described above (in-kernel gathers) —
-and ``"rowloop"`` — a gather-free per-row ``pl.ds`` append loop kept as the
-Mosaic-lowering escape hatch (``ops.query_block(compaction=
-"fused_rowloop")``; also the automatic fallback if the gather path fails to
-lower outside interpret mode).  Both emit the identical deterministic
-order.
+The fused kernel has two append strategies (``append=``):
+
+* ``"chunk"`` — compiles for the TPU.  Hits are located 128 result slots
+  at a time with one-hot matrix products on the MXU (prefix sums against
+  triangular ones matrices, row/column selection, and an exact three-way
+  bf16 split for the f32 segment gathers — see :func:`_chunk_tile_body`),
+  the hit pairs' intervals are recomputed on the gathered segments, and
+  each 128-slot window is written with a lane rotation into two buffer
+  rows (:func:`_append_window`).
+* ``"rowloop"`` — a per-row ``fori_loop`` append over the dense tile
+  intervals, kept as an interpret-mode cross-check of the chunk path's
+  order.  It does not lower for the TPU (dynamic value slices and
+  reductions to 1-D vectors) and raises when asked to compile.
+
+Both emit the identical deterministic order.
 
 Both fused kernels optionally take a **tile-level spatial early-out**
 (PR 5, the device half of the two-level pruning subsystem — the host half
@@ -69,10 +77,14 @@ cost nothing; dead *slots* (list padding past ``n_live``) cost one scalar
 compare.  Output order is identical to the full-grid kernels because the
 list is sorted in grid order.
 
-The interval math matches ``ref.interaction_tile`` bit-for-bit in float32;
-tests sweep shapes/dtypes and assert allclose against the oracle, and the
-fused kernel's compacted rows are asserted equal to the dense kernel's
-nonzero set (tests/test_kernels.py).
+Every entry point takes ``interpret=None``, resolved by
+:func:`resolve_interpret`: the Pallas interpreter on a CPU, the compiled
+kernel everywhere else.
+
+The interval math matches ``ref.interaction_tile`` bit-for-bit in float32
+on the CPU; tests sweep shapes/dtypes and assert allclose against the
+oracle, and the fused kernel's compacted rows are asserted equal to the
+dense kernel's nonzero set (tests/test_kernels.py).
 """
 from __future__ import annotations
 
@@ -88,13 +100,31 @@ from jax.experimental.pallas import tpu as pltpu
 DEFAULT_CAND_BLK = 256
 DEFAULT_QRY_BLK = 256
 
-# Fused-compaction append granularity: hits are appended to the result
-# buffers in chunks of this many slots, so per-tile compaction work scales
-# with the hit count, not the tile size.
-APPEND_BLK = 256
+#: Result slots per append window: one vreg row of lanes.  The chunk
+#: append writes hits in windows of this many slots, so per-tile
+#: compaction work scales with the hit count, not the tile size.
+APPEND_BLK = 128
+
+#: Largest tile side the chunk append supports: its in-tile prefix counts
+#: (≤ the tile side) ride the MXU as bf16, exact only up to 256.
+MAX_FUSED_BLK = 256
 
 _A_EPS = 1e-12
 _B_EPS = 1e-12
+
+
+def resolve_interpret(interpret: bool | None = None, device=None) -> bool:
+    """Whether Pallas kernels run in interpret mode.
+
+    An explicit ``interpret`` wins.  Otherwise the platform of ``device``
+    (default: ``jax.devices()[0]``, the device an engine places its work
+    on) decides: the interpreter on ``cpu``, the compiled kernel on every
+    other platform.
+    """
+    if interpret is not None:
+        return bool(interpret)
+    device = device if device is not None else jax.devices()[0]
+    return device.platform == "cpu"
 
 
 def _tile_intervals(e, q, d):
@@ -115,27 +145,13 @@ def _tile_intervals(e, q, d):
                           d, e.dtype)
 
 
-def _pair_intervals(e_rows, q_cols, d):
-    """Interval math for N explicit (entry, query) pairs.
-
-    Args:
-      e_rows: (N, 8) gathered entry segments.
-      q_cols: (8, N) gathered (transposed) query segments.
-      d: scalar threshold.
-
-    Returns (t_enter, t_exit, hit) of shape (N,).
-    """
-    return _interval_math(tuple(e_rows[:, k] for k in range(8)),
-                          tuple(q_cols[k, :] for k in range(8)),
-                          d, e_rows.dtype)
-
-
-def _interval_math(e8, q8, d, dtype):
+def _interval_math(e8, q8, d, dtype, *, zero_misses: bool = True):
     """Shared branchless interval solve over broadcastable components.
 
     ``e8`` / ``q8`` are the 8 packed-segment components (x0, y0, z0, x1,
     y1, z1, ts, te) of the entries and queries, in mutually broadcastable
-    shapes; all outputs take the broadcast shape.
+    shapes; all outputs take the broadcast shape.  ``zero_misses=False``
+    leaves the interval of a miss unzeroed.
     """
     ex0, ey0, ez0, ex1, ey1, ez1, ets, ete = e8
     qx0, qy0, qz0, qx1, qy1, qz1, qts, qte = q8
@@ -190,15 +206,24 @@ def _interval_math(e8, q8, d, dtype):
 
     rlo = jnp.where(is_quad, q_rlo, jnp.where(is_lin, lin_rlo, -inf))
     rhi = jnp.where(is_quad, q_rhi, jnp.where(is_lin, lin_rhi, inf))
-    nonempty = jnp.where(is_quad, disc >= 0.0,
-                         jnp.where(is_lin, True, c <= 0.0))
+    # Boolean algebra, not a select over booleans: Mosaic cannot lower an
+    # i1 select (it round-trips through i8 and refuses the truncation).
+    nonempty = ((is_quad & (disc >= 0.0))
+                | (~is_quad & (is_lin | (c <= 0.0))))
 
     t_enter = jnp.maximum(rlo, lo)
     t_exit = jnp.minimum(rhi, hi)
     hit = t_overlap & nonempty & (t_enter <= t_exit)
+    if not zero_misses:
+        return t_enter, t_exit, hit
 
     zero = jnp.zeros((), dtype)
     return (jnp.where(hit, t_enter, zero), jnp.where(hit, t_exit, zero), hit)
+
+
+def _smem():
+    """Whole-array SMEM operand: scalars and the small MBR tables."""
+    return pl.BlockSpec(memory_space=pltpu.SMEM)
 
 
 def _distthresh_kernel(d_ref, entries_ref, queries_t_ref,
@@ -214,7 +239,8 @@ def _distthresh_kernel(d_ref, entries_ref, queries_t_ref,
 def distthresh_pallas(entries: jnp.ndarray, queries_t: jnp.ndarray, d,
                       *, cand_blk: int = DEFAULT_CAND_BLK,
                       qry_blk: int = DEFAULT_QRY_BLK,
-                      interpret: bool = True) -> tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
+                      interpret: bool | None = None
+                      ) -> tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
     """Raw pallas_call over pre-padded inputs (dense outputs).
 
     Args:
@@ -243,188 +269,201 @@ def distthresh_pallas(entries: jnp.ndarray, queries_t: jnp.ndarray, d,
         _distthresh_kernel,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1, 1), lambda i, j: (0, 0)),          # d (scalar)
+            _smem(),                                            # d (scalar)
             pl.BlockSpec((cand_blk, 8), lambda i, j: (i, 0)),   # entries: stay on i
             pl.BlockSpec((8, qry_blk), lambda i, j: (0, j)),    # queries: stream on j
         ],
         out_specs=(out_spec, out_spec, out_spec),
         out_shape=out_shapes,
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(d_arr, entries, queries_t)
 
 
 # ----------------------------------------------------------------------
 # Fused in-kernel compaction (the §5 atomic_inc analogue, sequential grid)
 # ----------------------------------------------------------------------
-def _tile_mbr_live(embr_ref, qmbr_ref, dprune_ref):
-    """The tile-level early-out test: squared box distance between the
-    tile's entry/query MBRs vs the (conservatively inflated) threshold.
+def _window_width(append: str, qry_blk: int) -> int:
+    """Slots one append writes from its start: one APPEND_BLK window for
+    the chunk path, a whole tile row (in APPEND_BLK windows) for rowloop."""
+    if append == "rowloop":
+        return -(-qry_blk // APPEND_BLK) * APPEND_BLK
+    return APPEND_BLK
 
-    The MBR rows are laid out ``(lo_x, lo_y, lo_z, hi_x, hi_y, hi_z, _, _)``;
-    all-padding tiles carry the empty box (``lo=+inf, hi=-inf``) whose gap
-    is ``inf`` — always pruned.  A handful of scalar VPU ops per tile,
-    against a full (CAND_BLK × QRY_BLK) interval evaluation saved.
+
+def _buffer_rows(capacity: int, width: int) -> int:
+    """Rows of a ``(rows, APPEND_BLK)`` result plane: an append may start
+    at any slot ``<= capacity`` and writes ``width`` slots, its last
+    APPEND_BLK window straddling two rows."""
+    return (capacity + width) // APPEND_BLK + 1
+
+
+def _init_buffers(bufs, count_ref, pruned_ref=None):
+    """First grid step: index planes to -1, interval planes to 0, counters
+    to 0."""
+    e_idx_ref, q_idx_ref, enter_ref, exit_ref = bufs
+    e_idx_ref[...] = jnp.full(e_idx_ref.shape, -1, jnp.int32)
+    q_idx_ref[...] = jnp.full(q_idx_ref.shape, -1, jnp.int32)
+    enter_ref[...] = jnp.zeros(enter_ref.shape, enter_ref.dtype)
+    exit_ref[...] = jnp.zeros(exit_ref.shape, exit_ref.dtype)
+    count_ref[0, 0] = 0
+    if pruned_ref is not None:
+        pruned_ref[0, 0] = 0
+
+
+def _append_window(buf_ref, vec, dst):
+    """Write the (1, APPEND_BLK) lane vector ``vec`` at flat slots ``[dst,
+    dst + APPEND_BLK)`` of the ``(rows, APPEND_BLK)`` plane ``buf_ref``.
+
+    The window straddles rows ``dst // APPEND_BLK`` and the one after: a
+    lane rotation by ``dst % APPEND_BLK`` puts every slot on its lane, and
+    one two-row read-modify-write keeps the slots before ``dst`` (earlier
+    hits) and after the window as they were.
     """
-    gap2 = jnp.zeros((), embr_ref.dtype)
-    for ax in range(3):
-        elo, ehi = embr_ref[0, ax], embr_ref[0, 3 + ax]
-        qlo, qhi = qmbr_ref[0, ax], qmbr_ref[0, 3 + ax]
-        g = jnp.maximum(jnp.maximum(qlo - ehi, elo - qhi), 0.0)
-        gap2 = gap2 + g * g
-    dp = dprune_ref[0, 0]
-    return gap2 <= dp * dp
+    o = dst % APPEND_BLK
+    row = dst // APPEND_BLK
+    rolled = jnp.broadcast_to(pltpu.roll(vec, o, 1), (2, APPEND_BLK))
+    sub = jax.lax.broadcasted_iota(jnp.int32, (2, APPEND_BLK), 0)
+    lane = jax.lax.broadcasted_iota(jnp.int32, (2, APPEND_BLK), 1)
+    take = ((sub == 0) & (lane >= o)) | ((sub == 1) & (lane < o))
+    window = buf_ref[pl.ds(row, 2), :]
+    buf_ref[pl.ds(row, 2), :] = jnp.where(take, rolled, window)
 
 
-def _chunk_tile_body(i, j, d_ref, entries_ref, queries_t_ref,
-                     e_idx_ref, q_idx_ref, enter_ref, exit_ref, count_ref,
-                     *, cand_blk: int, qry_blk: int, capacity: int,
-                     valid_c: int, valid_q: int):
-    """Evaluate tile (i, j) and chunk-append its hits (shared by the
-    full-grid and live-tile kernels; ``i``/``j`` may be traced scalars
-    read from a scalar-prefetch ref)."""
-    tile = cand_blk * qry_blk
+def _exact_gather(x, onehot):
+    """``x @ onehot`` for f32 ``x`` and a 0/1 ``onehot`` with at most one 1
+    per column, exact on the MXU: ``x`` splits into three bf16 parts whose
+    sum is ``x`` exactly, and each product selects a single value."""
+    oh = onehot.astype(jnp.bfloat16)
+    hi = x.astype(jnp.bfloat16)
+    r1 = x - hi.astype(x.dtype)
+    mid = r1.astype(jnp.bfloat16)
+    lo = (r1 - mid.astype(x.dtype)).astype(jnp.bfloat16)
+
+    def dot(part):
+        return jnp.dot(part, oh, preferred_element_type=jnp.float32)
+
+    return (dot(hi) + dot(mid)) + dot(lo)
+
+
+def _hit_mask(i, j, d, e_blk, q_blk, *, cand_blk, qry_blk, valid_c, valid_q):
+    """Tile (i, j)'s hit mask with padding rows/cols masked out (broadcast
+    vectors, no full index tiles), so pad×pad pairs never append."""
+    _, _, hit = _tile_intervals(e_blk, q_blk, d)
+    row_ok = (jax.lax.broadcasted_iota(jnp.int32, (cand_blk, 1), 0)
+              + i * cand_blk) < valid_c
+    col_ok = (jax.lax.broadcasted_iota(jnp.int32, (1, qry_blk), 1)
+              + j * qry_blk) < valid_q
+    return hit & row_ok & col_ok
+
+
+def _chunk_tile_body(i, j, d_ref, entries_ref, queries_t_ref, bufs,
+                     count_ref, *, cand_blk: int, qry_blk: int,
+                     capacity: int, valid_c: int, valid_q: int):
+    """Evaluate tile (i, j) and append its hits, row-major, in
+    APPEND_BLK-slot windows (shared by the full-grid and live-tile kernels;
+    ``i``/``j`` may be traced scalars read from a scalar-prefetch ref).
+
+    Only the hit mask is computed over the tile; the intervals are
+    recomputed, unmasked, for the ≤ APPEND_BLK hit pairs of each window
+    (the tile's mask decided the hit; the recompute's own hit test must
+    not zero it on a last-ulp difference).  The k-th hit of the tile
+    (row-major) sits in row ``r_k`` = the number of rows
+    whose inclusive hit prefix ends at or before ``k``, and in that row's
+    column ``c_k`` = the number of columns whose in-row inclusive prefix is
+    at most ``k``'s rank in the row.  Prefix counts are bf16 0/1 products
+    against triangular ones matrices on the MXU (exact: every count is
+    ≤ 256 per factor and < 2^24 in f32), and the per-slot row of prefixes
+    and the slot's entry/query segments are one-hot products
+    (:func:`_exact_gather`).  Zero-hit tiles skip all of it.
+    """
     e_blk = entries_ref[...]                 # (cand_blk, 8), VMEM
     q_blk = queries_t_ref[...]               # (8, qry_blk), VMEM
     d = d_ref[0, 0]
-    # Only the hit mask is live here — the dense (C, Q) interval tiles
-    # are dead code and never materialize; intervals are recomputed per
-    # hit in the append loop below (≈ 70 FLOPs each, on ≤ tile_hits
-    # pairs).
-    _, _, hit = _tile_intervals(e_blk, q_blk, d)
-
-    # Mask padding rows/cols (broadcast vectors, no full index tiles)
-    # so pad×pad pairs (identical zero segments at the pad time) never
-    # append.
-    row_ok = (jax.lax.broadcasted_iota(jnp.int32, (cand_blk, 1), 0)
-              + i * cand_blk) < valid_c
-    col_ok = (jax.lax.broadcasted_iota(jnp.int32, (1, qry_blk), 1)
-              + j * qry_blk) < valid_q
-    hit2 = hit & row_ok & col_ok
-
-    # Masked prefix sum over the row-major flattened tile: cum[f] is
-    # the number of hits at flat index <= f, so the k-th hit
-    # (k = 1..tile_hits) sits at the first f with cum[f] == k — a
-    # rank-selection gather moves the hits to the tile prefix in
-    # row-major order without any scatter: slot s reads flat index
-    # searchsorted(cum, s + 1).
-    cum = jnp.cumsum(hit2.astype(jnp.int32).reshape(tile))
-    tile_hits = cum[-1]
+    hit = _hit_mask(i, j, d, e_blk, q_blk, cand_blk=cand_blk,
+                    qry_blk=qry_blk, valid_c=valid_c, valid_q=valid_q)
+    hit_f = hit.astype(jnp.float32)
+    tile_hits = jnp.sum(hit_f).astype(jnp.int32)
     offset = count_ref[0, 0]
-
-    # Append in APPEND_BLK-slot chunks, looping only
-    # ceil(tile_hits / blk) times: the work is O(hits · log tile), not
-    # O(tile) — in sparse workloads (the common case: α is small, paper
-    # §8.1.2) a tile pays the hit-mask math, one cumsum and at most one
-    # small chunk; zero-hit tiles skip the loop entirely.
-    blk = min(tile, APPEND_BLK)
-    zero = jnp.zeros((), enter_ref.dtype)
-
-    def _append_chunk(k, carry):
-        base = k * blk
-        slot = base + jax.lax.broadcasted_iota(jnp.int32, (blk, 1),
-                                               0)[:, 0]
-        src = jnp.minimum(
-            jnp.searchsorted(cum, slot + 1, method="scan_unrolled"),
-            tile - 1)
-        valid = slot < tile_hits             # slots past the hit count
-        dst = offset + base
-        # local/global (entry row, query col) indices from the flat src
-        e_loc = src // qry_blk
-        q_loc = src % qry_blk
-        e_idx = jnp.where(valid, i * cand_blk + e_loc, -1)
-        q_idx = jnp.where(valid, j * qry_blk + q_loc, -1)
-        # per-pair interval recompute on small (blk, 8)/(8, blk)
-        # gathers — keeps the dense interval tiles out of the live set
-        t_enter, t_exit, _ = _pair_intervals(e_blk[e_loc, :],
-                                             q_blk[:, q_loc], d)
-
-        @pl.when(dst <= capacity)            # overflow: drop, keep count
-        def _():
-            e_idx_ref[pl.ds(dst, blk)] = e_idx
-            q_idx_ref[pl.ds(dst, blk)] = q_idx
-            enter_ref[pl.ds(dst, blk)] = jnp.where(valid, t_enter, zero)
-            exit_ref[pl.ds(dst, blk)] = jnp.where(valid, t_exit, zero)
-
-        return carry
-
-    jax.lax.fori_loop(0, (tile_hits + blk - 1) // blk, _append_chunk, 0)
     count_ref[0, 0] = offset + tile_hits
 
+    @pl.when(tile_hits > 0)
+    def _append():
+        f32, bf16 = jnp.float32, jnp.bfloat16
+        hit_b = hit_f.astype(bf16)
 
-def _distthresh_compact_kernel(d_ref, entries_ref, queries_t_ref,
-                               e_idx_ref, q_idx_ref, enter_ref, exit_ref,
-                               count_ref, pruned_ref, *, cand_blk: int,
-                               qry_blk: int, capacity: int, valid_c: int,
-                               valid_q: int, prune_refs=None):
-    """One grid step: evaluate a tile, append its hits at the running offset.
+        def tri(n):                          # tri[a, b] = 1 iff b <= a
+            return (jax.lax.broadcasted_iota(jnp.int32, (n, n), 0)
+                    >= jax.lax.broadcasted_iota(jnp.int32, (n, n), 1)
+                    ).astype(bf16)
 
-    The four flat result buffers and the (1, 1) ``count`` block use constant
-    index maps, so they stay resident across the sequential grid — ``count``
-    doubles as the running hit counter (SMEM-resident scalar on hardware).
-    Appends use the *overwritten-tail* scheme: a tile writes
-    ``ceil(tile_hits / APPEND_BLK)`` fixed-width windows whose rows are the
-    compacted hits, the last window's tail being pad rows; the next tile's
-    first window starts at ``offset + tile_hits``, overwriting the tail.
-    Buffers carry one window of slack beyond ``capacity`` so a window
-    starting at any offset ``<= capacity`` fits; once the counter passes
-    ``capacity`` appends are skipped (the caller sees ``count > capacity``
-    and retries larger — the counter itself keeps accumulating, so ``count``
-    is always exact).
+        # rowend[r]: hits in rows <= r, (cand_blk, 1); rowstart excludes r.
+        rowend = jnp.sum(jnp.dot(tri(cand_blk), hit_b,
+                                 preferred_element_type=f32),
+                         axis=1, keepdims=True)
+        rowstart = rowend - jnp.sum(hit_f, axis=1, keepdims=True)
+        # rcum_t[c, r]: hits of row r in columns <= c, (qry_blk, cand_blk).
+        rcum_t = jax.lax.dot_general(
+            tri(qry_blk), hit_b, (((1,), (1,)), ((), ())),
+            preferred_element_type=f32).astype(bf16)
+        e_t = e_blk.T                        # (8, cand_blk)
+        row_ids = jax.lax.broadcasted_iota(jnp.int32, (cand_blk, APPEND_BLK),
+                                           0).astype(f32)
+        col_ids = jax.lax.broadcasted_iota(jnp.int32, (qry_blk, APPEND_BLK),
+                                           0).astype(f32)
+        zero = jnp.zeros((), bufs[2].dtype)
 
-    With ``prune_refs`` (the per-tile MBR blocks + inflated threshold) the
-    tile body runs under ``@pl.when``: a tile whose entry/query boxes are
-    farther apart than the threshold skips the interval math entirely and
-    bumps the resident ``pruned`` counter instead — the unwritten result
-    buffers and ``count`` block simply carry over to the next grid step.
-    """
-    i = pl.program_id(0)
-    j = pl.program_id(1)
+        def _window(k, carry):
+            base = k * APPEND_BLK
+            slot = base + jax.lax.broadcasted_iota(jnp.int32,
+                                                   (1, APPEND_BLK), 1)
+            slot_f = slot.astype(f32)
+            r_k = jnp.sum((rowend <= slot_f).astype(f32), axis=0,
+                          keepdims=True)                 # (1, APPEND_BLK)
+            row_oh = row_ids == r_k                      # (cand_blk, APPEND_BLK)
+            rank = slot_f - jnp.sum(jnp.where(row_oh, rowstart, 0.0),
+                                    axis=0, keepdims=True)
+            prefix = jnp.dot(rcum_t, row_oh.astype(bf16),
+                             preferred_element_type=f32)  # (qry_blk, APPEND_BLK)
+            c_k = jnp.sum((prefix <= rank).astype(f32), axis=0,
+                          keepdims=True)
+            col_oh = col_ids == c_k                      # (qry_blk, APPEND_BLK)
+            e_g = _exact_gather(e_t, row_oh)             # (8, APPEND_BLK)
+            q_g = _exact_gather(q_blk, col_oh)
+            t_enter, t_exit, _ = _interval_math(
+                tuple(e_g[m:m + 1, :] for m in range(8)),
+                tuple(q_g[m:m + 1, :] for m in range(8)), d, e_blk.dtype,
+                zero_misses=False)
+            valid = slot < tile_hits             # slots past the hit count
+            dst = offset + base
 
-    @pl.when((i == 0) & (j == 0))
-    def _init():
-        e_idx_ref[...] = jnp.full(e_idx_ref.shape, -1, jnp.int32)
-        q_idx_ref[...] = jnp.full(q_idx_ref.shape, -1, jnp.int32)
-        enter_ref[...] = jnp.zeros(enter_ref.shape, enter_ref.dtype)
-        exit_ref[...] = jnp.zeros(exit_ref.shape, exit_ref.dtype)
-        count_ref[0, 0] = 0
-        pruned_ref[0, 0] = 0
+            @pl.when(dst <= capacity)            # overflow: drop, keep count
+            def _():
+                e_idx_ref, q_idx_ref, enter_ref, exit_ref = bufs
+                _append_window(e_idx_ref, jnp.where(
+                    valid, i * cand_blk + r_k.astype(jnp.int32), -1), dst)
+                _append_window(q_idx_ref, jnp.where(
+                    valid, j * qry_blk + c_k.astype(jnp.int32), -1), dst)
+                _append_window(enter_ref, jnp.where(valid, t_enter, zero),
+                               dst)
+                _append_window(exit_ref, jnp.where(valid, t_exit, zero), dst)
 
-    def _body():
-        _chunk_tile_body(i, j, d_ref, entries_ref, queries_t_ref,
-                         e_idx_ref, q_idx_ref, enter_ref, exit_ref,
-                         count_ref, cand_blk=cand_blk, qry_blk=qry_blk,
-                         capacity=capacity, valid_c=valid_c,
-                         valid_q=valid_q)
+            return carry
 
-    if prune_refs is None:
-        _body()
-        return
-    embr_ref, qmbr_ref, dprune_ref = prune_refs
-    live = _tile_mbr_live(embr_ref, qmbr_ref, dprune_ref)
-
-    @pl.when(jnp.logical_not(live))
-    def _skip():
-        pruned_ref[0, 0] = pruned_ref[0, 0] + 1
-
-    pl.when(live)(_body)
+        jax.lax.fori_loop(0, (tile_hits + APPEND_BLK - 1) // APPEND_BLK,
+                          _window, 0)
 
 
-def _rowloop_tile_body(i, j, d_ref, entries_ref, queries_t_ref,
-                       e_idx_ref, q_idx_ref, enter_ref, exit_ref, count_ref,
-                       *, cand_blk: int, qry_blk: int, capacity: int,
-                       valid_c: int, valid_q: int):
+def _rowloop_tile_body(i, j, d_ref, entries_ref, queries_t_ref, bufs,
+                       count_ref, *, cand_blk: int, qry_blk: int,
+                       capacity: int, valid_c: int, valid_q: int):
     """Evaluate tile (i, j) and row-append its hits (shared by the
-    full-grid and live-tile kernels)."""
+    full-grid and live-tile kernels; interpret mode only)."""
     e_blk = entries_ref[...]
     q_blk = queries_t_ref[...]
     d = d_ref[0, 0]
-    t_enter, t_exit, hit = _tile_intervals(e_blk, q_blk, d)
-
-    row_ok = (jax.lax.broadcasted_iota(jnp.int32, (cand_blk, 1), 0)
-              + i * cand_blk) < valid_c
-    col_ok = (jax.lax.broadcasted_iota(jnp.int32, (1, qry_blk), 1)
-              + j * qry_blk) < valid_q
-    hit2 = hit & row_ok & col_ok
+    t_enter, t_exit, _ = _tile_intervals(e_blk, q_blk, d)
+    hit2 = _hit_mask(i, j, d, e_blk, q_blk, cand_blk=cand_blk,
+                     qry_blk=qry_blk, valid_c=valid_c, valid_q=valid_q)
 
     hit_i = hit2.astype(jnp.int32)
     row_cum = jnp.cumsum(hit_i, axis=1)      # (cand_blk, qry_blk)
@@ -437,7 +476,14 @@ def _rowloop_tile_body(i, j, d_ref, entries_ref, queries_t_ref,
     col_plane = jax.lax.broadcasted_iota(jnp.int32,
                                          (qry_blk, qry_blk), 1)
     slot_vec = jax.lax.broadcasted_iota(jnp.int32, (qry_blk, 1), 0)[:, 0]
-    zero = jnp.zeros((), enter_ref.dtype)
+    zero = jnp.zeros((), bufs[2].dtype)
+    width = _window_width("rowloop", qry_blk)
+    n_win = width // APPEND_BLK
+
+    def _windows(v, pad):
+        v = jnp.pad(v, (0, width - qry_blk), constant_values=pad)
+        return [v[w * APPEND_BLK:(w + 1) * APPEND_BLK][None, :]
+                for w in range(n_win)]
 
     def _row_body(r, dst):
         rh = jax.lax.dynamic_slice(hit_i, (r, 0), (1, qry_blk))
@@ -454,18 +500,18 @@ def _rowloop_tile_body(i, j, d_ref, entries_ref, queries_t_ref,
         comp_ent = jnp.sum(sel_f * rent, axis=1)
         comp_ext = jnp.sum(sel_f * rext, axis=1)
         valid = slot_vec < n_r
-        e_val = jnp.where(valid, i * cand_blk + r, -1).astype(jnp.int32)
-        q_val = jnp.where(valid, j * qry_blk + comp_col,
-                          -1).astype(jnp.int32)
+        planes = (
+            (jnp.where(valid, i * cand_blk + r, -1).astype(jnp.int32), -1),
+            (jnp.where(valid, j * qry_blk + comp_col,
+                       -1).astype(jnp.int32), -1),
+            (jnp.where(valid, comp_ent, zero), zero),
+            (jnp.where(valid, comp_ext, zero), zero))
 
         @pl.when((n_r > 0) & (dst <= capacity))  # overflow: drop,
         def _():                                  # keep count
-            e_idx_ref[pl.ds(dst, qry_blk)] = e_val
-            q_idx_ref[pl.ds(dst, qry_blk)] = q_val
-            enter_ref[pl.ds(dst, qry_blk)] = jnp.where(valid, comp_ent,
-                                                       zero)
-            exit_ref[pl.ds(dst, qry_blk)] = jnp.where(valid, comp_ext,
-                                                      zero)
+            for ref, (vals, pad) in zip(bufs, planes):
+                for w, win in enumerate(_windows(vals, pad)):
+                    _append_window(ref, win, dst + w * APPEND_BLK)
 
         return dst + n_r
 
@@ -473,51 +519,50 @@ def _rowloop_tile_body(i, j, d_ref, entries_ref, queries_t_ref,
     count_ref[0, 0] = end
 
 
-def _distthresh_compact_rowloop_kernel(d_ref, entries_ref, queries_t_ref,
-                                       e_idx_ref, q_idx_ref, enter_ref,
-                                       exit_ref, count_ref, pruned_ref, *,
-                                       cand_blk: int, qry_blk: int,
-                                       capacity: int, valid_c: int,
-                                       valid_q: int, prune_refs=None):
-    """Gather-free fallback append: one ``pl.ds`` window per *entry row*.
+def _distthresh_compact_kernel(d_ref, entries_ref, queries_t_ref, *refs,
+                               body, cand_blk: int, qry_blk: int,
+                               capacity: int, valid_c: int, valid_q: int,
+                               prune: bool):
+    """One grid step: evaluate a tile, append its hits at the running offset.
 
-    The chunked kernel above compacts each tile with rank-selection
-    (``searchsorted``) plus dynamic row/column **gathers** of the hit pairs
-    — the one construct the ROADMAP flags as needing a Mosaic-lowering
-    check on real hardware.  This variant trades arithmetic for lowering
-    safety: it materializes the dense per-tile intervals (the pre-fusion
-    cost), then walks the tile's rows with ``fori_loop``, compacting each
-    row's hits to its prefix with a **selection matmul** — ``sel[s, c] = 1``
-    iff column ``c`` holds the row's (s+1)-th hit, so compacted values are
-    plain ``sum(sel * row)`` reductions (VPU/MXU-friendly; no gather, no
-    scatter, no searchsorted) — and appending the row's window with a
-    single dynamic-slice store.  Row windows use the same overwritten-tail
-    scheme as the chunked kernel, with ``qry_blk`` slots of slack.
+    The four ``(rows, APPEND_BLK)`` result planes use constant index maps,
+    so they stay resident across the sequential grid; the SMEM ``count``
+    output is the running hit counter.  Appends use the *overwritten-tail*
+    scheme: a tile writes ``ceil(tile_hits / window)`` fixed-width windows
+    whose slots are the compacted hits, the last window's tail being pad
+    slots; the next tile's first window starts at ``offset + tile_hits``,
+    overwriting the tail.  The planes carry one window of slack beyond
+    ``capacity`` so a window starting at any offset ``<= capacity`` fits;
+    once the counter passes ``capacity`` appends are skipped (the caller
+    sees ``count > capacity`` and retries larger — the counter itself keeps
+    accumulating, so ``count`` is always exact).
+
+    With ``prune`` the refs after the three inputs start with the SMEM
+    entry-tile / query-tile MBR tables (flattened ``(tiles * 8,)``) and the
+    inflated threshold: a tile whose boxes are farther apart than the
+    threshold skips the interval math entirely and bumps the ``pruned``
+    counter instead — the unwritten result buffers and ``count`` simply
+    carry over to the next grid step.
     """
+    if prune:
+        embr_ref, qmbr_ref, dprune_ref, *refs = refs
+    *bufs, count_ref, pruned_ref = refs
     i = pl.program_id(0)
     j = pl.program_id(1)
 
     @pl.when((i == 0) & (j == 0))
     def _init():
-        e_idx_ref[...] = jnp.full(e_idx_ref.shape, -1, jnp.int32)
-        q_idx_ref[...] = jnp.full(q_idx_ref.shape, -1, jnp.int32)
-        enter_ref[...] = jnp.zeros(enter_ref.shape, enter_ref.dtype)
-        exit_ref[...] = jnp.zeros(exit_ref.shape, exit_ref.dtype)
-        count_ref[0, 0] = 0
-        pruned_ref[0, 0] = 0
+        _init_buffers(bufs, count_ref, pruned_ref)
 
     def _body():
-        _rowloop_tile_body(i, j, d_ref, entries_ref, queries_t_ref,
-                           e_idx_ref, q_idx_ref, enter_ref, exit_ref,
-                           count_ref, cand_blk=cand_blk, qry_blk=qry_blk,
-                           capacity=capacity, valid_c=valid_c,
-                           valid_q=valid_q)
+        body(i, j, d_ref, entries_ref, queries_t_ref, bufs, count_ref,
+             cand_blk=cand_blk, qry_blk=qry_blk, capacity=capacity,
+             valid_c=valid_c, valid_q=valid_q)
 
-    if prune_refs is None:
+    if not prune:
         _body()
         return
-    embr_ref, qmbr_ref, dprune_ref = prune_refs
-    live = _tile_mbr_live(embr_ref, qmbr_ref, dprune_ref)
+    live = _tile_mbr_live(embr_ref, qmbr_ref, dprune_ref, i, j)
 
     @pl.when(jnp.logical_not(live))
     def _skip():
@@ -526,8 +571,74 @@ def _distthresh_compact_rowloop_kernel(d_ref, entries_ref, queries_t_ref,
     pl.when(live)(_body)
 
 
+def _tile_mbr_live(embr_ref, qmbr_ref, dprune_ref, i, j):
+    """The tile-level early-out test: squared box distance between entry
+    tile ``i``'s and query tile ``j``'s MBRs vs the (conservatively
+    inflated) threshold.
+
+    The MBR rows are laid out ``(lo_x, lo_y, lo_z, hi_x, hi_y, hi_z, _, _)``
+    and flattened, 8 scalars per tile; all-padding tiles carry the empty
+    box (``lo=+inf, hi=-inf``) whose gap is ``inf`` — always pruned.  A
+    handful of scalar ops per tile, against a full (CAND_BLK × QRY_BLK)
+    interval evaluation saved.
+    """
+    gap2 = jnp.zeros((), jnp.float32)
+    for ax in range(3):
+        elo, ehi = embr_ref[i * 8 + ax], embr_ref[i * 8 + 3 + ax]
+        qlo, qhi = qmbr_ref[j * 8 + ax], qmbr_ref[j * 8 + 3 + ax]
+        g = jnp.maximum(jnp.maximum(qlo - ehi, elo - qhi), 0.0)
+        gap2 = gap2 + g * g
+    dp = dprune_ref[0, 0]
+    return gap2 <= dp * dp
+
+
 #: append strategies accepted by :func:`distthresh_compact_pallas`.
 APPEND_MODES = ("chunk", "rowloop")
+
+
+def _append_body(append: str, interpret: bool, cand_blk: int, qry_blk: int):
+    """The tile body for ``append`` — validated, and refused where it
+    cannot run (an explicit error, never a silent switch of strategy)."""
+    if append not in APPEND_MODES:
+        raise ValueError(f"unknown append mode {append!r}; "
+                         f"choose from {APPEND_MODES}")
+    if append == "rowloop":
+        if not interpret:
+            raise NotImplementedError(
+                "append='rowloop' (compaction='fused_rowloop') does not "
+                "lower for the TPU; it runs only in interpret mode. Use "
+                "compaction='fused' (append='chunk') on the chip.")
+        return _rowloop_tile_body
+    if max(cand_blk, qry_blk) > MAX_FUSED_BLK:
+        raise ValueError(f"append='chunk' supports tiles up to "
+                         f"{MAX_FUSED_BLK}×{MAX_FUSED_BLK}; got "
+                         f"{cand_blk}×{qry_blk}")
+    return _chunk_tile_body
+
+
+def _result_planes(rows: int, dtype, index_map):
+    """Specs and shapes of the four resident ``(rows, APPEND_BLK)`` result
+    planes (entry index, query index, t_enter, t_exit) and the SMEM hit
+    counter."""
+    spec = pl.BlockSpec((rows, APPEND_BLK), index_map)
+    shapes = tuple(jax.ShapeDtypeStruct((rows, APPEND_BLK), dt)
+                   for dt in (jnp.int32, jnp.int32, dtype, dtype))
+    return ((spec,) * 4 + (_smem(),),
+            shapes + (jax.ShapeDtypeStruct((1, 1), jnp.int32),))
+
+
+def _compiler_params(rows: int, dims: tuple[str, ...]):
+    """Sequential grid (the running counter needs it) and enough scoped
+    VMEM for the double-buffered resident result planes on top of the
+    tile temporaries."""
+    planes = 2 * 4 * rows * APPEND_BLK * 4
+    return pltpu.CompilerParams(
+        dimension_semantics=dims,
+        vmem_limit_bytes=min(32 * 2**20 + planes, 120 * 2**20))
+
+
+def _flat(plane, capacity: int):
+    return plane.reshape(-1)[:capacity]
 
 
 @functools.partial(jax.jit, static_argnames=(
@@ -539,7 +650,7 @@ def distthresh_compact_pallas(entries: jnp.ndarray, queries_t: jnp.ndarray, d,
                               qry_blk: int = DEFAULT_QRY_BLK,
                               valid_c: int | None = None,
                               valid_q: int | None = None,
-                              interpret: bool = True,
+                              interpret: bool | None = None,
                               append: str = "chunk",
                               e_mbr: jnp.ndarray | None = None,
                               q_mbr: jnp.ndarray | None = None,
@@ -554,16 +665,17 @@ def distthresh_compact_pallas(entries: jnp.ndarray, queries_t: jnp.ndarray, d,
         still reports the exact total, so callers detect overflow exactly).
       valid_c / valid_q: number of *real* (non-padding) rows/cols; pairs at
         or beyond them are masked out of the result.  Default: all.
-      append: ``"chunk"`` — masked-prefix-sum rank-selection appends in
-        APPEND_BLK windows (the fast path; uses in-kernel gathers).
-        ``"rowloop"`` — the gather-free per-row ``pl.ds`` append loop (the
-        Mosaic-lowering escape hatch; same results, same determinism).
+      interpret: Pallas interpret mode; ``None`` resolves from the default
+        device (:func:`resolve_interpret`).
+      append: ``"chunk"`` — one-hot MXU rank selection in APPEND_BLK-slot
+        windows (the path that compiles for the TPU).  ``"rowloop"`` — the
+        per-row append loop, interpret mode only (same results, same
+        determinism).
       e_mbr / q_mbr / d_prune: the tile-level spatial early-out (PR 5).
         ``e_mbr`` is (C/cand_blk, 8) — per entry tile ``(lo_xyz, hi_xyz,
         0, 0)`` — and ``q_mbr`` (Q/qry_blk, 8) the same per query tile,
-        precomputed upstream of the ``pallas_call`` (``ops._tile_mbrs``;
-        on hardware these belong in SMEM / scalar prefetch — they are tiny
-        and read as scalars only).  A grid tile whose boxes are farther
+        precomputed upstream of the ``pallas_call`` (``ops._tile_mbrs``)
+        and read from SMEM as scalars.  A grid tile whose boxes are farther
         apart than ``d_prune`` (the conservatively inflated threshold, see
         ``repro.core.index.prune_limit``) skips all interval math and
         increments the ``pruned`` counter.  All three must be given
@@ -577,9 +689,8 @@ def distthresh_compact_pallas(entries: jnp.ndarray, queries_t: jnp.ndarray, d,
     modes *and* pruning on/off — pruned tiles contribute no rows): tiles
     in grid order (query tiles innermost), row-major within each tile.
     """
-    if append not in APPEND_MODES:
-        raise ValueError(f"unknown append mode {append!r}; "
-                         f"choose from {APPEND_MODES}")
+    interpret = resolve_interpret(interpret)
+    body = _append_body(append, interpret, cand_blk, qry_blk)
     prune = e_mbr is not None
     if (q_mbr is None) == prune or (d_prune is None) == prune:
         raise ValueError("e_mbr, q_mbr and d_prune must be given together "
@@ -595,59 +706,35 @@ def distthresh_compact_pallas(entries: jnp.ndarray, queries_t: jnp.ndarray, d,
     dtype = entries.dtype
     d_arr = jnp.asarray(d, dtype).reshape(1, 1)
 
-    # One append window of slack: a window starting at any offset
-    # <= capacity stays in bounds, so no clamping can slide it over valid
-    # rows.  Rowloop windows are qry_blk wide; chunked ones APPEND_BLK.
-    tile = cand_blk * qry_blk
-    window = qry_blk if append == "rowloop" else min(tile, APPEND_BLK)
-    cap_pad = capacity + window
-    flat_spec = pl.BlockSpec((cap_pad,), lambda i, j: (0,))
-    scalar_spec = pl.BlockSpec((1, 1), lambda i, j: (0, 0))
-    out_shapes = (
-        jax.ShapeDtypeStruct((cap_pad,), jnp.int32),
-        jax.ShapeDtypeStruct((cap_pad,), jnp.int32),
-        jax.ShapeDtypeStruct((cap_pad,), dtype),
-        jax.ShapeDtypeStruct((cap_pad,), dtype),
-        jax.ShapeDtypeStruct((1, 1), jnp.int32),
-        jax.ShapeDtypeStruct((1, 1), jnp.int32),
-    )
-    kernel_fn = functools.partial(
-        _distthresh_compact_rowloop_kernel if append == "rowloop"
-        else _distthresh_compact_kernel,
-        cand_blk=cand_blk, qry_blk=qry_blk,
-        capacity=capacity, valid_c=valid_c, valid_q=valid_q)
+    rows = _buffer_rows(capacity, _window_width(append, qry_blk))
+    out_specs, out_shapes = _result_planes(rows, dtype,
+                                           lambda i, j: (0, 0))
     in_specs = [
-        scalar_spec,                                        # d (scalar)
+        _smem(),                                            # d (scalar)
         pl.BlockSpec((cand_blk, 8), lambda i, j: (i, 0)),   # entries
         pl.BlockSpec((8, qry_blk), lambda i, j: (0, j)),    # queries
     ]
+    args = [d_arr, entries, queries_t]
     if prune:
-        in_specs += [
-            pl.BlockSpec((1, 8), lambda i, j: (i, 0)),      # entry-tile MBR
-            pl.BlockSpec((1, 8), lambda i, j: (j, 0)),      # query-tile MBR
-            scalar_spec,                                    # inflated d
-        ]
-        args = (d_arr, entries, queries_t, e_mbr, q_mbr,
-                jnp.asarray(d_prune, dtype).reshape(1, 1))
-
-        def kernel(d_ref, entries_ref, queries_t_ref, embr_ref, qmbr_ref,
-                   dprune_ref, *out_refs):
-            kernel_fn(d_ref, entries_ref, queries_t_ref, *out_refs,
-                      prune_refs=(embr_ref, qmbr_ref, dprune_ref))
-    else:
-        args = (d_arr, entries, queries_t)
-        kernel = kernel_fn
+        in_specs += [_smem(), _smem(), _smem()]   # tile MBRs, inflated d
+        args += [jnp.asarray(e_mbr, jnp.float32).reshape(-1),
+                 jnp.asarray(q_mbr, jnp.float32).reshape(-1),
+                 jnp.asarray(d_prune, jnp.float32).reshape(1, 1)]
+    kernel = functools.partial(
+        _distthresh_compact_kernel, body=body, cand_blk=cand_blk,
+        qry_blk=qry_blk, capacity=capacity, valid_c=valid_c,
+        valid_q=valid_q, prune=prune)
     e_idx, q_idx, t_enter, t_exit, count, pruned = pl.pallas_call(
         kernel,
         grid=grid,
         in_specs=in_specs,
-        out_specs=(flat_spec, flat_spec, flat_spec, flat_spec,
-                   scalar_spec, scalar_spec),
-        out_shape=out_shapes,
+        out_specs=out_specs + (_smem(),),
+        out_shape=out_shapes + (jax.ShapeDtypeStruct((1, 1), jnp.int32),),
+        compiler_params=_compiler_params(rows, ("arbitrary",) * 2),
         interpret=interpret,
     )(*args)
-    return (e_idx[:capacity], q_idx[:capacity],
-            t_enter[:capacity], t_exit[:capacity], count[0, 0],
+    return (_flat(e_idx, capacity), _flat(q_idx, capacity),
+            _flat(t_enter, capacity), _flat(t_exit, capacity), count[0, 0],
             pruned[0, 0])
 
 
@@ -655,10 +742,8 @@ def distthresh_compact_pallas(entries: jnp.ndarray, queries_t: jnp.ndarray, d,
 # Live-tile dispatch (PR 7): ragged grid over a precomputed tile list
 # ----------------------------------------------------------------------
 def _distthresh_compact_live_kernel(ti_ref, tj_ref, nlive_ref, d_ref,
-                                    entries_ref, queries_t_ref,
-                                    e_idx_ref, q_idx_ref, enter_ref,
-                                    exit_ref, count_ref, *, body,
-                                    cand_blk: int, qry_blk: int,
+                                    entries_ref, queries_t_ref, *refs,
+                                    body, cand_blk: int, qry_blk: int,
                                     capacity: int, valid_c: int,
                                     valid_q: int):
     """One live-list slot: evaluate tile ``(ti[s], tj[s])`` if the slot is
@@ -673,22 +758,18 @@ def _distthresh_compact_live_kernel(ti_ref, tj_ref, nlive_ref, d_ref,
     (query tiles innermost) and the append bodies are shared with the
     full-grid kernels, the output rows are byte-identical to theirs.
     """
+    *bufs, count_ref = refs
     s = pl.program_id(0)
 
     @pl.when(s == 0)
     def _init():
-        e_idx_ref[...] = jnp.full(e_idx_ref.shape, -1, jnp.int32)
-        q_idx_ref[...] = jnp.full(q_idx_ref.shape, -1, jnp.int32)
-        enter_ref[...] = jnp.zeros(enter_ref.shape, enter_ref.dtype)
-        exit_ref[...] = jnp.zeros(exit_ref.shape, exit_ref.dtype)
-        count_ref[0, 0] = 0
+        _init_buffers(bufs, count_ref)
 
     @pl.when(s < nlive_ref[0])
     def _run():
-        body(ti_ref[s], tj_ref[s], d_ref, entries_ref, queries_t_ref,
-             e_idx_ref, q_idx_ref, enter_ref, exit_ref, count_ref,
-             cand_blk=cand_blk, qry_blk=qry_blk, capacity=capacity,
-             valid_c=valid_c, valid_q=valid_q)
+        body(ti_ref[s], tj_ref[s], d_ref, entries_ref, queries_t_ref, bufs,
+             count_ref, cand_blk=cand_blk, qry_blk=qry_blk,
+             capacity=capacity, valid_c=valid_c, valid_q=valid_q)
 
 
 @functools.partial(jax.jit, static_argnames=(
@@ -702,7 +783,7 @@ def distthresh_compact_live_pallas(entries: jnp.ndarray,
                                    qry_blk: int = DEFAULT_QRY_BLK,
                                    valid_c: int | None = None,
                                    valid_q: int | None = None,
-                                   interpret: bool = True,
+                                   interpret: bool | None = None,
                                    append: str = "chunk"):
     """Fused compaction kernel driven by a precomputed live-tile list.
 
@@ -726,7 +807,7 @@ def distthresh_compact_live_pallas(entries: jnp.ndarray,
         skipped but still prefetched.
       n_live: (1,) int32 count of live slots (``<= S``).  Traced, so one
         compiled kernel serves every list that fits the same padded ``S``.
-      capacity / valid_c / valid_q / append: as in
+      capacity / valid_c / valid_q / interpret / append: as in
         :func:`distthresh_compact_pallas`.
 
     Returns ``(entry_idx, query_idx, t_enter, t_exit, count)``; no
@@ -734,9 +815,8 @@ def distthresh_compact_live_pallas(entries: jnp.ndarray,
     Output order is byte-identical to the full-grid kernels' (the live
     list is in grid order and pruned tiles contribute no rows).
     """
-    if append not in APPEND_MODES:
-        raise ValueError(f"unknown append mode {append!r}; "
-                         f"choose from {APPEND_MODES}")
+    interpret = resolve_interpret(interpret)
+    body = _append_body(append, interpret, cand_blk, qry_blk)
     cc, eight = entries.shape
     assert eight == 8, entries.shape
     eight2, qq = queries_t.shape
@@ -750,19 +830,9 @@ def distthresh_compact_live_pallas(entries: jnp.ndarray,
     dtype = entries.dtype
     d_arr = jnp.asarray(d, dtype).reshape(1, 1)
 
-    tile = cand_blk * qry_blk
-    window = qry_blk if append == "rowloop" else min(tile, APPEND_BLK)
-    cap_pad = capacity + window
-    flat_spec = pl.BlockSpec((cap_pad,), lambda s, ti, tj, nl: (0,))
-    scalar_out = pl.BlockSpec((1, 1), lambda s, ti, tj, nl: (0, 0))
-    out_shapes = (
-        jax.ShapeDtypeStruct((cap_pad,), jnp.int32),
-        jax.ShapeDtypeStruct((cap_pad,), jnp.int32),
-        jax.ShapeDtypeStruct((cap_pad,), dtype),
-        jax.ShapeDtypeStruct((cap_pad,), dtype),
-        jax.ShapeDtypeStruct((1, 1), jnp.int32),
-    )
-    body = _rowloop_tile_body if append == "rowloop" else _chunk_tile_body
+    rows = _buffer_rows(capacity, _window_width(append, qry_blk))
+    out_specs, out_shapes = _result_planes(
+        rows, dtype, lambda s, ti, tj, nl: (0, 0))
     kernel = functools.partial(
         _distthresh_compact_live_kernel, body=body, cand_blk=cand_blk,
         qry_blk=qry_blk, capacity=capacity, valid_c=valid_c,
@@ -771,20 +841,21 @@ def distthresh_compact_live_pallas(entries: jnp.ndarray,
         num_scalar_prefetch=3,          # tile_i, tile_j, n_live
         grid=(n_slots,),
         in_specs=[
-            pl.BlockSpec((1, 1), lambda s, ti, tj, nl: (0, 0)),  # d
+            _smem(),                                            # d
             # The scalar-prefetched list drives the block fetches: slot s
             # pulls entry block ti[s] and query block tj[s].
             pl.BlockSpec((cand_blk, 8), lambda s, ti, tj, nl: (ti[s], 0)),
             pl.BlockSpec((8, qry_blk), lambda s, ti, tj, nl: (0, tj[s])),
         ],
-        out_specs=(flat_spec, flat_spec, flat_spec, flat_spec, scalar_out),
+        out_specs=out_specs,
     )
     e_idx, q_idx, t_enter, t_exit, count = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=out_shapes,
+        compiler_params=_compiler_params(rows, ("arbitrary",)),
         interpret=interpret,
     )(tile_i.astype(jnp.int32), tile_j.astype(jnp.int32),
       n_live.astype(jnp.int32), d_arr, entries, queries_t)
-    return (e_idx[:capacity], q_idx[:capacity],
-            t_enter[:capacity], t_exit[:capacity], count[0, 0])
+    return (_flat(e_idx, capacity), _flat(q_idx, capacity),
+            _flat(t_enter, capacity), _flat(t_exit, capacity), count[0, 0])

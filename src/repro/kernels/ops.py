@@ -18,24 +18,15 @@ Two layers:
   counter, and each tile appends its masked-prefix-sum-compacted hits
   directly into the flat result buffers.  Per-interaction HBM traffic is
   zero for non-hits, and the exact count comes back with the results.
-* ``"fused_rowloop"`` — the gather-free escape hatch: the same fused kernel
-  with the per-row ``pl.ds`` append loop (``append="rowloop"``).  Identical
-  results and output order; slower (it pays the dense-tile interval cost)
-  but free of the in-kernel gathers whose Mosaic lowering the ROADMAP
-  flags.  ``compaction="fused"`` *automatically* falls back to it — with a
-  one-time warning — if the gather path fails to lower outside interpret
-  mode.  The fallback fires where the compile happens: a *direct*
-  ``query_block`` call (the single-device engine path).  When
-  ``query_block`` is traced inside an outer jit (e.g. a ``shard_map``
-  closure), the lowering failure surfaces at the outer compile, beyond the
-  try/except — such callers must resolve the strategy up front, as
-  ``repro.core.distributed.ShardedEngine`` does with a tiny direct probe
-  compile at construction.
-* ``"dense"`` — the two-phase fallback (and the only strategy for the jnp
-  oracle path): phase 1 materializes the dense int8 hit mask, phase 2
-  compacts it with an XLA cumsum + scatter and recomputes the interval for
-  the ≤ capacity compacted hits.  Kept as the validation baseline: tests
-  assert the strategies produce identical hit sets.
+* ``"fused_rowloop"`` — the same fused kernel with the per-row append
+  loop (``append="rowloop"``).  Identical results and output order; an
+  interpret-mode cross-check only — it does not lower for the TPU and
+  raises there.  No strategy ever stands in for another: a kernel that
+  fails to lower raises to the caller.
+* ``"dense"`` — the two-pass fallback (and the only strategy for the jnp
+  oracle path): the dense tile's hit mask and intervals are materialized,
+  then compacted with an XLA cumsum + scatter.  Kept as the validation
+  baseline: tests assert the strategies produce identical hit sets.
 
 The two strategies emit different (both deterministic) row orders —
 ``"dense"`` is row-major over the full (C, Q) block, ``"fused"`` is
@@ -52,7 +43,6 @@ depend on cropping.
 from __future__ import annotations
 
 import functools
-import warnings
 
 import jax
 import jax.numpy as jnp
@@ -82,14 +72,6 @@ COMPACTIONS = ("fused", "fused_rowloop", "dense")
 #: inflated ``prune_limit`` threshold), only the work.
 PRUNINGS = ("spatial", "hierarchical", "none")
 
-#: One-time fused→rowloop fallback state: ``tripped`` flips when the fused
-#: (gather) compaction path fails to lower/compile; every later
-#: ``compaction="fused"`` call silently routes through the rowloop kernel.
-#: Module-level on purpose — a lowering capability is a property of the
-#: process's backend, not of one call site.  Tests reset it.
-_fused_fallback = {"tripped": False}
-
-
 def _pad_rows(x: jnp.ndarray, multiple: int, pad_t: jnp.ndarray) -> jnp.ndarray:
     """Pad (N, 8) packed segments to a row multiple with non-hitting rows."""
     n = x.shape[0]
@@ -113,7 +95,8 @@ def _pad_time(entries: jnp.ndarray, queries: jnp.ndarray) -> jnp.ndarray:
 @functools.partial(jax.jit, static_argnames=("use_pallas", "interpret",
                                              "cand_blk", "qry_blk"))
 def interaction_tiles(entries: jnp.ndarray, queries: jnp.ndarray, d,
-                      *, use_pallas: bool = True, interpret: bool = True,
+                      *, use_pallas: bool = True,
+                      interpret: bool | None = None,
                       cand_blk: int = DEFAULT_CAND_BLK,
                       qry_blk: int = DEFAULT_QRY_BLK):
     """Dense all-pairs distance-threshold intervals.
@@ -122,8 +105,10 @@ def interaction_tiles(entries: jnp.ndarray, queries: jnp.ndarray, d,
       entries: (C, 8) packed entry segments (no padding required).
       queries: (Q, 8) packed query segments.
       d: scalar threshold.
-      use_pallas: route through the Pallas kernel (interpret mode on CPU) or
-        the pure-jnp oracle (faster on CPU; identical semantics).
+      use_pallas: route through the Pallas kernel or the pure-jnp oracle
+        (faster on CPU; identical semantics).
+      interpret: Pallas interpret mode; ``None`` resolves from the device
+        (``distthresh.resolve_interpret``: interpreted only on a CPU).
 
     Returns (t_enter, t_exit, hit) of shape (C, Q), hit bool.
     """
@@ -322,7 +307,8 @@ def _host_tile_prune(entries: np.ndarray, queries: np.ndarray, d,
 
 
 def query_block(entries: jnp.ndarray, queries: jnp.ndarray, d, *,
-                capacity: int, use_pallas: bool = True, interpret: bool = True,
+                capacity: int, use_pallas: bool = True,
+                interpret: bool | None = None,
                 cand_blk: int = DEFAULT_CAND_BLK, qry_blk: int = DEFAULT_QRY_BLK,
                 compaction: str = "fused", pruning: str = "none"):
     """Interaction evaluation + deterministic compaction into flat buffers.
@@ -340,12 +326,12 @@ def query_block(entries: jnp.ndarray, queries: jnp.ndarray, d, *,
 
     ``compaction="fused"`` routes through the in-kernel compaction kernel
     when ``use_pallas`` is set (the jnp oracle has no kernel to fuse into,
-    so it always uses the dense two-phase pass), falling back **once, with
-    a warning** to ``"fused_rowloop"`` — the gather-free per-row ``pl.ds``
-    append variant — if the gather path fails to lower (see the module
-    docstring).  ``"fused_rowloop"`` selects that escape hatch explicitly;
-    ``"dense"`` forces the two-phase fallback.  All orders are
-    deterministic; see the module docstring for how they differ.
+    so it always uses the dense two-phase pass).  ``"fused_rowloop"``
+    selects the per-row append variant (interpret mode only); ``"dense"``
+    forces the two-phase pass.  A kernel that fails to lower raises; no
+    other strategy is tried.  All orders are deterministic; see the module
+    docstring for how they differ.  ``interpret=None`` resolves from the
+    device (``distthresh.resolve_interpret``).
 
     ``pruning="spatial"`` arms the fused kernels' tile-level MBR early-out:
     per-tile entry/query bounding boxes and the (inflated — see
@@ -408,35 +394,11 @@ def query_block(entries: jnp.ndarray, queries: jnp.ndarray, d, *,
                 return out
             prune_arrays = dict(tile_i=tile_i, tile_j=tile_j,
                                 n_live=n_live)
-    kw = dict(capacity=capacity, use_pallas=use_pallas, interpret=interpret,
-              cand_blk=cand_blk, qry_blk=qry_blk, pruning=pruning,
-              **prune_arrays)
-    if compaction == "fused" and use_pallas:
-        if _fused_fallback["tripped"]:
-            compaction = "fused_rowloop"
-        else:
-            try:
-                return _query_block_jit(entries, queries, d,
-                                        compaction="fused", **kw)
-            except Exception as err:
-                # Only fall back when the rowloop variant *succeeds* where
-                # the gather path failed — anything else (bad shapes, OOM,
-                # a broken install) is a real error and re-raises as-is.
-                try:
-                    out = _query_block_jit(entries, queries, d,
-                                           compaction="fused_rowloop", **kw)
-                except Exception:
-                    raise err
-                _fused_fallback["tripped"] = True
-                warnings.warn(
-                    "fused in-kernel compaction failed to lower "
-                    f"({type(err).__name__}: {err}); falling back to the "
-                    "gather-free compaction=\"fused_rowloop\" append loop "
-                    "for the rest of this process (pass "
-                    "compaction=\"fused_rowloop\" explicitly to silence)",
-                    RuntimeWarning, stacklevel=2)
-                return out
-    return _query_block_jit(entries, queries, d, compaction=compaction, **kw)
+    return _query_block_jit(entries, queries, d, capacity=capacity,
+                            use_pallas=use_pallas, interpret=interpret,
+                            cand_blk=cand_blk, qry_blk=qry_blk,
+                            compaction=compaction, pruning=pruning,
+                            **prune_arrays)
 
 
 @functools.partial(jax.jit, static_argnames=("capacity", "use_pallas",
@@ -444,7 +406,8 @@ def query_block(entries: jnp.ndarray, queries: jnp.ndarray, d, *,
                                              "qry_blk", "compaction",
                                              "pruning"))
 def _query_block_jit(entries: jnp.ndarray, queries: jnp.ndarray, d, *,
-                     capacity: int, use_pallas: bool, interpret: bool,
+                     capacity: int, use_pallas: bool,
+                     interpret: bool | None,
                      cand_blk: int, qry_blk: int, compaction: str,
                      pruning: str = "none", e_mbr=None, q_mbr=None,
                      d_prune=None, tile_i=None, tile_j=None, n_live=None):
@@ -500,13 +463,13 @@ def _query_block_jit(entries: jnp.ndarray, queries: jnp.ndarray, d, *,
                 "pruned_tiles": pruned,
                 "num_tiles": jnp.asarray(num_tiles, jnp.int32)}
 
-    # Dense two-phase compaction (the pre-fusion path; EXPERIMENTS §Perf
-    # galaxy-db): phase 1 materializes ONLY the dense int8 hit mask — XLA
-    # dead-code-eliminates the interval arithmetic for the dense tile, so
-    # the per-interaction HBM traffic drops from (2·f32 intervals + mask +
-    # i32 positions) to (mask + i32 positions).  Phase 2 recomputes the
-    # interval for the ≤ capacity compacted hits only (70 FLOPs each).
-    _, _, hit = interaction_tiles(
+    # Dense compaction (the pre-fusion path): evaluate the dense tile, then
+    # compact hits and their intervals with an XLA prefix sum + scatter.
+    # The intervals are the tile's own, never recomputed: in float32 the
+    # interval of a near-tangent pair is ill-conditioned, and a second
+    # evaluation that XLA rounds differently can move it by a tenth of a
+    # time unit (seen on a TPU v5e at S2's size).
+    t_enter, t_exit, hit = interaction_tiles(
         entries, queries, d, use_pallas=use_pallas, interpret=interpret,
         cand_blk=cand_blk, qry_blk=qry_blk)
     flat_hit = hit.reshape(-1)
@@ -518,20 +481,15 @@ def _query_block_jit(entries: jnp.ndarray, queries: jnp.ndarray, d, *,
     dest = jnp.where(flat_hit, pos, capacity)
     dest = jnp.where(dest < capacity, dest, capacity)
     lin = jnp.arange(c * q, dtype=jnp.int32)
-    e_idx = lin // q
-    q_idx = lin % q
-    out_e = jnp.full((capacity,), -1, jnp.int32).at[dest].set(e_idx, mode="drop")
-    out_q = jnp.full((capacity,), -1, jnp.int32).at[dest].set(q_idx, mode="drop")
-    # phase 2: pairwise interval recompute on the compacted hits.
-    valid = out_e >= 0
-    e_rows = entries[jnp.maximum(out_e, 0)]            # (capacity, 8)
-    q_rows = queries[jnp.maximum(out_q, 0)]
-    pair_enter, pair_exit, _ = jax.vmap(
-        lambda er, qr: tuple(x[0, 0] for x in ref.interaction_tile(
-            er[None], qr[None], d)))(e_rows, q_rows)
-    zero = jnp.zeros((), pair_enter.dtype)
-    out_ent = jnp.where(valid, pair_enter, zero)
-    out_ext = jnp.where(valid, pair_exit, zero)
+
+    def compact(values, fill):
+        return jnp.full((capacity,), fill, values.dtype).at[dest].set(
+            values, mode="drop")
+
+    out_e = compact(lin // q, -1)
+    out_q = compact(lin % q, -1)
+    out_ent = compact(t_enter.reshape(-1), 0)
+    out_ext = compact(t_exit.reshape(-1), 0)
     return {"entry_idx": out_e, "query_idx": out_q,
             "t_enter": out_ent, "t_exit": out_ext, "count": count,
             "pruned_tiles": jnp.zeros((), jnp.int32),
@@ -541,7 +499,7 @@ def _query_block_jit(entries: jnp.ndarray, queries: jnp.ndarray, d, *,
 @functools.partial(jax.jit, static_argnames=("use_pallas", "interpret",
                                              "cand_blk", "qry_blk"))
 def count_hits(entries: jnp.ndarray, queries: jnp.ndarray, d, *,
-               use_pallas: bool = True, interpret: bool = True,
+               use_pallas: bool = True, interpret: bool | None = None,
                cand_blk: int = DEFAULT_CAND_BLK,
                qry_blk: int = DEFAULT_QRY_BLK) -> jnp.ndarray:
     """Number of result-set items without materializing them (for sizing)."""

@@ -18,7 +18,8 @@ def random_segments(rng: np.random.Generator, n: int, *, t_span=(0.0, 50.0),
                     box=30.0, max_len=3.0) -> SegmentArray:
     """Random packed segments helper used across tests."""
     ts = rng.uniform(*t_span, n).astype(np.float32)
-    te = ts + rng.uniform(0.1, max_len, n).astype(np.float32)
+    # Lengths in [0.1, max_len), or (max_len/2, max_len) when max_len < 0.2.
+    te = ts + rng.uniform(min(0.1, max_len / 2), max_len, n).astype(np.float32)
     p0 = rng.uniform(0, box, (n, 3)).astype(np.float32)
     p1 = p0 + rng.normal(0, 2.0, (n, 3)).astype(np.float32)
     order = np.argsort(ts, kind="stable")

@@ -310,33 +310,6 @@ class TestShardedEngineSingleDevice:
         assert not stats.pipelined
         assert len(rs.sorted_canonical()) == len(bf)
 
-    def test_fused_probe_resolves_rowloop_when_gather_fails(self, world,
-                                                            monkeypatch):
-        """The in-jit shard step can't use ops.query_block's automatic
-        fused→rowloop fallback (lowering fails at the outer compile), so
-        ShardedEngine probes the fused path directly at construction and
-        bakes the resolved strategy in."""
-        import warnings
-        from repro.core.distributed import ShardedEngine
-        from repro.kernels import distthresh as dt
-        from repro.kernels import ops
-        db, *_ = world
-        orig = dt.distthresh_compact_pallas
-
-        def no_gather_lowering(*args, **kwargs):
-            if kwargs.get("append", "chunk") == "chunk":
-                raise RuntimeError("Mosaic lowering failed: gather")
-            return orig(*args, **kwargs)
-
-        monkeypatch.setattr(dt, "distthresh_compact_pallas",
-                            no_gather_lowering)
-        monkeypatch.setitem(ops._fused_fallback, "tripped", False)
-        with warnings.catch_warnings():
-            warnings.simplefilter("ignore", RuntimeWarning)
-            se = ShardedEngine(db, use_pallas=True, compaction="fused",
-                               cand_blk=16, qry_blk=16)
-        assert se.compaction == "fused_rowloop"
-
     def test_overflow_redispatch_reuses_prepared_inputs(self, world):
         """Overflow retries re-launch with the prepared per-pod blocks from
         Dispatch.ctx instead of rebuilding/re-slicing them."""

@@ -1,6 +1,4 @@
 """Per-kernel allclose sweeps: Pallas kernels vs pure-jnp oracles."""
-import warnings
-
 import numpy as np
 import pytest
 from _hypothesis_compat import given, settings
@@ -125,8 +123,8 @@ class TestFusedCompaction:
         de, dq, den, dex = canon(dense, nd)
         np.testing.assert_array_equal(fe, de)
         np.testing.assert_array_equal(fq, dq)
-        # fused computes intervals in-kernel; dense recomputes them via the
-        # oracle — identical up to f32 fusion order
+        # fused recomputes intervals on the gathered hit segments; dense
+        # keeps the dense tile's — identical up to f32 fusion order
         np.testing.assert_allclose(fen, den, rtol=1e-4, atol=1e-3)
         np.testing.assert_allclose(fex, dex, rtol=1e-4, atol=1e-3)
         # pad slots beyond the count are -1 on both paths
@@ -177,9 +175,9 @@ class TestFusedCompaction:
 
 
 class TestRowloopEscapeHatch:
-    """The gather-free per-row ``pl.ds`` append variant: identical results
-    *and identical order* to the chunked fused kernel, plus the one-time
-    automatic fallback when the gather path fails to lower."""
+    """The per-row append variant: identical results *and identical
+    order* to the chunked fused kernel; a kernel that fails to lower
+    raises instead of switching strategy."""
 
     @pytest.mark.parametrize("c,q,cblk,qblk", [
         (16, 16, 16, 16),      # single tile
@@ -222,62 +220,59 @@ class TestRowloopEscapeHatch:
         # the capacity prefix is still a valid (deterministic) hit prefix
         assert np.all(np.asarray(out["entry_idx"][:16]) >= 0)
 
-    def test_fused_falls_back_to_rowloop_with_one_warning(self, monkeypatch):
-        """If the gather-path kernel fails to lower, compaction="fused"
-        warns once and reroutes through the rowloop kernel — but only when
-        the rowloop variant actually works (other errors re-raise)."""
+    def test_lowering_error_reaches_caller_unchanged(self, monkeypatch):
+        """A kernel that fails to lower raises to the caller as-is: no
+        other compaction strategy is tried in its place."""
         from repro.kernels import distthresh as dt
-        orig = dt.distthresh_compact_pallas
+        err = RuntimeError("Mosaic failed to compile TPU kernel")
+        calls = []
 
-        def no_gather_lowering(*args, **kwargs):
-            if kwargs.get("append", "chunk") == "chunk":
-                raise RuntimeError("Mosaic lowering failed: gather")
-            return orig(*args, **kwargs)
+        def refuses_to_lower(*args, **kwargs):
+            calls.append(kwargs.get("append", "chunk"))
+            raise err
 
         monkeypatch.setattr(dt, "distthresh_compact_pallas",
-                            no_gather_lowering)
-        monkeypatch.setitem(ops._fused_fallback, "tripped", False)
+                            refuses_to_lower)
         rng = np.random.default_rng(23)
         # Unseen shapes, so the monkeypatched callable is actually traced.
         entries = random_segments(rng, 72).packed()
         queries = random_segments(rng, 24).packed()
-        d = np.float32(15.0)
-        dense = ops.query_block(entries, queries, d, capacity=1024,
-                                use_pallas=True, compaction="dense",
-                                cand_blk=8, qry_blk=8)
-        with pytest.warns(RuntimeWarning, match="fused_rowloop"):
-            out = ops.query_block(entries, queries, d, capacity=1024,
-                                  use_pallas=True, compaction="fused",
-                                  cand_blk=8, qry_blk=8)
-        assert ops._fused_fallback["tripped"]
-        n = int(out["count"])
-        assert n == int(dense["count"]) > 0
-        # second call routes silently (one-time warning)
-        with warnings.catch_warnings():
-            warnings.simplefilter("error")
-            out2 = ops.query_block(entries, queries, d, capacity=1024,
-                                   use_pallas=True, compaction="fused",
-                                   cand_blk=8, qry_blk=8)
-        assert int(out2["count"]) == n
+        with pytest.raises(RuntimeError) as info:
+            ops.query_block(entries, queries, np.float32(15.0),
+                            capacity=1024, use_pallas=True,
+                            compaction="fused", cand_blk=8, qry_blk=8)
+        assert info.value is err
+        assert calls == ["chunk"]
 
-    def test_non_lowering_errors_reraise_untripped(self, monkeypatch):
-        """An error that also breaks the rowloop variant is a real bug: it
-        propagates unchanged and does NOT trip the global fallback."""
+    def test_rowloop_refuses_to_compile(self):
+        """The rowloop append is interpret-only: asked to compile, it
+        raises an error that names it instead of running another path."""
         from repro.kernels import distthresh as dt
+        rng = np.random.default_rng(31)
+        entries = random_segments(rng, 8).packed()
+        queries = random_segments(rng, 8).packed()
+        with pytest.raises(NotImplementedError, match="rowloop"):
+            dt.distthresh_compact_pallas(entries, queries.T, np.float32(1.0),
+                                         capacity=256, cand_blk=8, qry_blk=8,
+                                         interpret=False, append="rowloop")
 
-        def broken(*args, **kwargs):
-            raise RuntimeError("everything is broken")
 
-        monkeypatch.setattr(dt, "distthresh_compact_pallas", broken)
-        monkeypatch.setitem(ops._fused_fallback, "tripped", False)
-        rng = np.random.default_rng(29)
-        entries = random_segments(rng, 56).packed()
-        queries = random_segments(rng, 40).packed()
-        with pytest.raises(RuntimeError, match="everything is broken"):
-            ops.query_block(entries, queries, np.float32(2.0), capacity=256,
-                            use_pallas=True, compaction="fused",
-                            cand_blk=8, qry_blk=8)
-        assert not ops._fused_fallback["tripped"]
+class TestInterpretResolution:
+    def test_interpret_resolves_true_on_cpu(self):
+        """With no explicit choice, Pallas interprets on the CPU (the test
+        platform) — and an explicit value always wins."""
+        import jax
+
+        import repro
+        from repro.kernels.distthresh import resolve_interpret
+        assert jax.devices()[0].platform == "cpu"
+        assert resolve_interpret() is True
+        assert resolve_interpret(None, jax.devices()[0]) is True
+        assert resolve_interpret(False) is False
+        rng = np.random.default_rng(37)
+        db = repro.TrajectoryDB.from_segments(random_segments(rng, 32))
+        assert db.policy.interpret is None
+        assert db.engine("pallas").interpret is True
 
 
 class TestEmptyInputGuards:

@@ -587,10 +587,10 @@ class TestPlants:
 
     def test_plant_index_map_arity(self, real_sources):
         path = "src/repro/kernels/distthresh.py"
-        anchor = "flat_spec = pl.BlockSpec((cap_pad,), lambda i, j: (0,))"
-        assert anchor in real_sources[path]
+        anchor = "pl.BlockSpec((cand_blk, 8), lambda i, j: (i, 0)),   # entries\n"
+        assert real_sources[path].count(anchor) == 1
         mutated = real_sources[path].replace(
-            anchor, "flat_spec = pl.BlockSpec((cap_pad,), lambda i: (0,))")
+            anchor, "pl.BlockSpec((cand_blk, 8), lambda i: (i, 0)),\n")
         vs = lint_sources([(path, mutated)], select=("KERN001",))
         assert [v.rule for v in vs] == ["KERN001"]
         vs = lint_sources([(path, real_sources[path])],
