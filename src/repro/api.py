@@ -64,6 +64,7 @@ from typing import Callable, Mapping, Protocol, runtime_checkable
 
 import numpy as np
 
+from repro.core import spans
 from repro.core.batching import ALGORITHMS, BatchPlan
 from repro.core.engine import (DistanceThresholdEngine, ExecStats, ResultSet,
                                brute_force)
@@ -256,18 +257,19 @@ class QueryResult:
         ``order`` is the sort permutation (sorted position → caller
         position); ``None`` means the caller's queries were already sorted.
         """
-        q_caller = (rs.query_idx if order is None
-                    else order[rs.query_idx])
-        rank = np.lexsort((rs.entry_idx, q_caller))
-        return QueryResult(
-            entry_idx=rs.entry_idx[rank],
-            entry_traj=rs.entry_traj[rank],
-            entry_seg=rs.entry_seg[rank],
-            query_idx=q_caller[rank],
-            t_enter=rs.t_enter[rank],
-            t_exit=rs.t_exit[rank],
-            d=d, backend=backend, stats=stats, plan=plan,
-        )
+        with spans.span("repro.facade.canonical", rows=len(rs)):
+            q_caller = (rs.query_idx if order is None
+                        else order[rs.query_idx])
+            rank = np.lexsort((rs.entry_idx, q_caller))
+            return QueryResult(
+                entry_idx=rs.entry_idx[rank],
+                entry_traj=rs.entry_traj[rank],
+                entry_seg=rs.entry_seg[rank],
+                query_idx=q_caller[rank],
+                t_enter=rs.t_enter[rank],
+                t_exit=rs.t_exit[rank],
+                d=d, backend=backend, stats=stats, plan=plan,
+            )
 
     # ------------------------------------------------------------------
     def matches_for(self, query_idx: int) -> "QueryResult":
@@ -636,8 +638,9 @@ class TrajectoryDB:
 
     def _make_plan(self, sorted_queries: SegmentArray, pol: ExecutionPolicy,
                    backend: str = "jnp", d: float | None = None) -> QueryPlan:
-        return self.planner(pol, num_queries=len(sorted_queries),
-                            backend=backend).plan(sorted_queries, d=d)
+        with spans.span("repro.plan"):
+            return self.planner(pol, num_queries=len(sorted_queries),
+                                backend=backend).plan(sorted_queries, d=d)
 
     @staticmethod
     def _sorted(queries: SegmentArray
@@ -645,10 +648,11 @@ class TrajectoryDB:
         """Sort queries by t_start, returning (sorted, permutation) where
         ``permutation[i]`` is the caller index of sorted position ``i``
         (None when already sorted)."""
-        if queries.is_sorted():
-            return queries, None
-        order = np.argsort(queries.ts, kind="stable").astype(np.int64)
-        return queries.take(order), order
+        with spans.span("repro.facade.sort"):
+            if queries.is_sorted():
+                return queries, None
+            order = np.argsort(queries.ts, kind="stable").astype(np.int64)
+            return queries.take(order), order
 
     def _resolve_policy(self, batching: str | None,
                         policy: ExecutionPolicy | None,
@@ -690,22 +694,29 @@ class TrajectoryDB:
         "spatial" bin-level candidate pruning, or "none" — all three give
         the same canonical result, in decreasing order of work avoided)
         for the engine backends (``"pallas"``/``"jnp"``/``"shard"``).
+
+        Each call records its steps' seconds and counters
+        (``repro.core.spans``) on ``QueryResult.stats``: ``span_seconds``
+        and ``counts``.
         """
-        d = _validate_threshold(d)
-        if len(queries) == 0:
+        with spans.recording(), spans.span("repro.query",
+                                           segments=len(queries)):
+            d = _validate_threshold(d)
+            if len(queries) == 0:
+                return QueryResult.from_result_set(
+                    ResultSet.empty(), order=None, d=float(d),
+                    backend=backend)
+            _validate_segments(queries, "queries")
+            pol = self._resolve_policy(batching, policy, batch_params,
+                                       compaction, pipeline, pruning)
+            be = self.backend(backend, pol)
+            qs, order = self._sorted(queries)
+            plan = (self._make_plan(qs, pol, backend, d=float(d))
+                    if be.needs_plan else None)
+            rs, stats = be.run(qs, float(d), plan)
             return QueryResult.from_result_set(
-                ResultSet.empty(), order=None, d=float(d), backend=backend)
-        _validate_segments(queries, "queries")
-        pol = self._resolve_policy(batching, policy, batch_params,
-                                   compaction, pipeline, pruning)
-        be = self.backend(backend, pol)
-        qs, order = self._sorted(queries)
-        plan = (self._make_plan(qs, pol, backend, d=float(d))
-                if be.needs_plan else None)
-        rs, stats = be.run(qs, float(d), plan)
-        return QueryResult.from_result_set(
-            rs, order=order, d=float(d), backend=backend,
-            stats=stats, plan=plan)
+                rs, order=order, d=float(d), backend=backend,
+                stats=stats, plan=plan)
 
     # -- streaming / serving ---------------------------------------------
     def query_stream(self, queries: SegmentArray, d: float, *,

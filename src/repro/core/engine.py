@@ -46,6 +46,7 @@ from __future__ import annotations
 import numpy as np
 
 from repro import faults
+from repro.core import spans
 from repro.core.batching import BatchPlan
 from repro.core.executor import (BatchStats, Dispatch,  # noqa: F401 (stable re-exports)
                                  ExecStats, ResultSet, make_executor)
@@ -115,12 +116,19 @@ class _QueryBlockDispatcher:
         # index buffers to -1) instead of trusting ``count``: a corrupted
         # overflow count then costs at most one spurious bounded retry — it
         # can never leak pad rows into results nor drop real ones.
-        e_buf = np.asarray(out["entry_idx"])
+        with spans.span("repro.engine.fetch"):
+            e_buf = np.asarray(out["entry_idx"])
+        spans.count("result_slots", e_buf.size)
         keep = e_buf >= 0
-        if not keep.any():
+        rows = int(np.count_nonzero(keep))
+        if not rows:
             return None
+        spans.count("result_rows", rows)
+        with spans.span("repro.engine.fetch"):
+            q_buf, t_enter, t_exit = (np.asarray(out[k]) for k in (
+                "query_idx", "t_enter", "t_exit"))
         e_local = e_buf[keep]
-        q_local = np.asarray(out["query_idx"])[keep]
+        q_local = q_buf[keep]
         e_global = batch.cand_first + e_local.astype(np.int64)
         if self.engine.pruning == "hierarchical":
             perm = self.engine.index.perm
@@ -133,8 +141,8 @@ class _QueryBlockDispatcher:
             entry_traj=db.traj_id[e_global].astype(np.int64),
             entry_seg=db.seg_id[e_global].astype(np.int64),
             query_idx=batch.q_first + q_local.astype(np.int64),
-            t_enter=np.asarray(out["t_enter"])[keep],
-            t_exit=np.asarray(out["t_exit"])[keep],
+            t_enter=t_enter[keep],
+            t_exit=t_exit[keep],
         )
 
 

@@ -26,8 +26,9 @@ A dispatcher answers four questions, all device-strategy-specific:
 Two executors drive a dispatcher over a plan:
 
 * :class:`SyncExecutor` — the classic per-batch loop (dispatch → sync →
-  maybe retry → marshal).  One host sync per invocation; per-batch device
-  timings are observable, which the §8 perf-model fits need.
+  maybe retry → marshal).  One host sync per invocation; per-batch
+  timings (host wall until the outputs are ready) are observable, which
+  the §8 perf-model fits need.
 * :class:`PipelinedExecutor` — the two-phase dispatch, *per dispatch
   group*: group k+1 is dispatched before group k is synced and marshalled,
   so host-side result assembly of group k overlaps device compute of group
@@ -37,18 +38,25 @@ Two executors drive a dispatcher over a plan:
 
 ``ResultSet`` / ``BatchStats`` / ``ExecStats`` moved here from
 ``repro.core.engine`` (which re-exports them — import paths are stable).
+
+Both executors time their steps as spans (``repro.core.spans``), and
+``ExecStats``/``BatchStats`` take their seconds from them:
+``repro.exec.group`` (a group or group phase), ``repro.engine.dispatch``
+(a first dispatch), ``repro.exec.sync`` (the first wait and its count
+reads), ``repro.exec.retry`` (overflow re-dispatches), ``repro.exec.marshal``
+and ``repro.exec.concat``.
 """
 from __future__ import annotations
 
 import contextlib
 import dataclasses
 import threading
-import time
 from typing import Callable, Protocol, runtime_checkable
 
 import jax
 import numpy as np
 
+from repro.core import spans
 from repro.core.batching import QueryBatch
 from repro.core.errors import CapacityError
 from repro.core.planner import QueryPlan
@@ -80,6 +88,14 @@ def _group_scope(label: str):
         yield
     finally:
         _dispatch_context.label = prev
+
+
+@contextlib.contextmanager
+def _group_span(label: str):
+    """One dispatch group's phase (``repro.exec.group``), which also opens
+    the group's label scope."""
+    with spans.span("repro.exec.group", group=label), _group_scope(label):
+        yield
 
 
 # ----------------------------------------------------------------------
@@ -123,11 +139,15 @@ class ResultSet:
 class BatchStats:
     """Per-invocation record (feeds the §8 performance model).
 
-    ``kernel_seconds`` is dispatch + device time of the batch's first
-    invocation (timed with ``block_until_ready``); ``retry_seconds`` is the
-    wall time of overflow re-dispatches, kept separate so perf-model fits
-    see clean per-invocation numbers.  Pipelined execution reports both as
-    zero per batch (see ``ExecStats.sync_seconds``).
+    ``kernel_seconds`` is the host wall of the batch's first invocation,
+    from the dispatch call until its outputs were ready and its count read
+    (the ``repro.engine.dispatch`` and ``repro.exec.sync`` spans of the
+    sync executor).  It is not device time: host slicing, the upload and
+    the wait all count.  ``retry_seconds`` is the wall time of overflow
+    re-dispatches (``repro.exec.retry``), kept separate so perf-model fits
+    see clean per-invocation numbers.  Pipelined execution reports
+    ``kernel_seconds`` as zero per batch (see ``ExecStats.sync_seconds``)
+    and shares each group's retry wall among its retried batches.
     """
 
     batch_size: int
@@ -152,10 +172,6 @@ class ExecStats:
     #: one per invocation (+retries) in sync mode; ≤ 2 per dispatch group in
     #: pipelined mode — ≤ 2 per query set with the default single group.
     num_syncs: int = 0
-    #: pipelined mode only: wall time of the phase A async dispatches and of
-    #: the phase B device waits (summed over dispatch groups).
-    dispatch_seconds: float = 0.0
-    sync_seconds: float = 0.0
     pipelined: bool = False
     #: dispatch groups the executor processed (1 = classic whole-plan phase).
     num_groups: int = 1
@@ -169,6 +185,29 @@ class ExecStats:
     #: compaction / backend / pruning / route downgrade.  Empty on every
     #: clean execution.
     degradations: list = dataclasses.field(default_factory=list)
+    #: host-clock seconds per span name (``repro.core.spans``), summed over
+    #: the execution; under ``TrajectoryDB.query`` it also holds the
+    #: facade's and the planner's spans of the same call.
+    span_seconds: dict = dataclasses.field(default_factory=dict)
+    #: the execution's counters: ``dispatches`` (first dispatches),
+    #: ``retried_dispatches`` (batches that needed an overflow
+    #: re-dispatch), ``h2d_bytes`` (host arrays handed to the kernels' jit
+    #: call, retries included), ``result_slots`` / ``result_rows``
+    #: (result-buffer slots copied back / rows kept).
+    counts: dict = dataclasses.field(default_factory=dict)
+
+    @property
+    def dispatch_seconds(self) -> float:
+        """Wall time of the first dispatches (``repro.engine.dispatch``):
+        host slicing, the jit call's enqueue and upload."""
+        return self.span_seconds.get("repro.engine.dispatch", 0.0)
+
+    @property
+    def sync_seconds(self) -> float:
+        """Wall time of the first waits on each dispatch group's (sync
+        mode: each batch's) outputs, with their count reads and overflow
+        checks (``repro.exec.sync``)."""
+        return self.span_seconds.get("repro.exec.sync", 0.0)
 
     @property
     def pruned_tiles(self) -> int:
@@ -180,10 +219,15 @@ class ExecStats:
 
     @property
     def kernel_seconds(self) -> float:
-        """First-dispatch device time (+ the pipelined device wait) — retry
-        re-dispatch time is deliberately excluded so perf-model fits see
+        """Host wall until the first invocations' outputs were ready: the
+        batches' ``kernel_seconds`` in sync mode, the group waits
+        (:attr:`sync_seconds`) in pipelined mode.  Not device time — the
+        kernel's device time is only in a profiler trace.  Retry
+        re-dispatch time is excluded so perf-model fits see
         per-invocation numbers; it is accounted in :attr:`retry_seconds`."""
-        return sum(b.kernel_seconds for b in self.batches) + self.sync_seconds
+        if self.pipelined:
+            return self.sync_seconds
+        return sum(b.kernel_seconds for b in self.batches)
 
     @property
     def retry_seconds(self) -> float:
@@ -191,8 +235,9 @@ class ExecStats:
 
     @property
     def host_seconds(self) -> float:
-        """Wall time not spent on device work: retries are device time too,
-        so they are subtracted alongside kernel_seconds."""
+        """Wall time outside :attr:`kernel_seconds` and
+        :attr:`retry_seconds` — both host walls that include the device's
+        work, so this is host work the device did not overlap."""
         return self.total_seconds - self.kernel_seconds - self.retry_seconds
 
     @property
@@ -283,6 +328,65 @@ def _empty_stats(batch: QueryBatch) -> BatchStats:
     return BatchStats(batch.size, 0, 0, 0, 0.0, 0)
 
 
+def _dispatch_span(batch: QueryBatch, capacity: int) -> spans.span:
+    """A batch's first dispatch (``repro.engine.dispatch``): the
+    dispatcher's host slicing, the jit call's enqueue and its upload.  Its
+    args name the shape, so a lowering inside it says which."""
+    return spans.span("repro.engine.dispatch",
+                      candidates=batch.num_candidates, queries=batch.size,
+                      capacity=capacity)
+
+
+def _overflows(dispatcher: BatchDispatcher, slots: dict,
+               idx) -> dict[int, int]:
+    """Batch index → re-dispatch capacity, for each synced dispatch in
+    ``idx`` whose buffers could not hold every hit."""
+    out = {}
+    for i in idx:
+        cap = dispatcher.retry_capacity(slots[i])
+        if cap is not None:
+            out[i] = cap
+    return out
+
+
+def _retry(dispatcher: BatchDispatcher, slots: dict, counts: dict,
+           rounds: dict, over: dict[int, int],
+           max_retries: int) -> tuple[int, float]:
+    """Re-dispatch the overflowed batches ``over`` (index → capacity) until
+    every count fits (``repro.exec.retry``), updating ``slots``, ``counts``
+    and ``rounds`` in place.  Exact counts make one retry sufficient on
+    honest devices, so ``max_retries`` only bites when counts are
+    corrupted or capacities adversarial.  Returns (host syncs, seconds)."""
+    spans.count("retried_dispatches", len(over))
+    syncs = 0
+    with spans.span("repro.exec.retry", batches=len(over)) as sp:
+        while over:
+            for i, cap in over.items():
+                if rounds.get(i, 0) >= max_retries:
+                    raise CapacityError(counts[i], slots[i].capacity,
+                                        batch_index=i,
+                                        retries=rounds.get(i, 0))
+                rounds[i] = rounds.get(i, 0) + 1
+                slots[i] = _redispatch(dispatcher, slots[i], cap)
+            jax.block_until_ready([slots[i].out for i in over])
+            syncs += 1
+            for i in over:
+                counts[i] = dispatcher.count(slots[i])
+            over = _overflows(dispatcher, slots, over)
+    return syncs, sp.seconds
+
+
+def _marshal(dispatcher: BatchDispatcher, dp: Dispatch,
+             count: int) -> ResultSet | None:
+    with spans.span("repro.exec.marshal"):
+        return dispatcher.marshal(dp, count)
+
+
+def _concat(parts: list[ResultSet]) -> ResultSet:
+    with spans.span("repro.exec.concat"):
+        return ResultSet.concatenate(parts)
+
+
 #: Group-completion hook ``(group_index, batch_indices, group_results)`` —
 #: fired by both executors as soon as one dispatch group's results are
 #: marshalled (for the pipelined executor that is while the *next* group is
@@ -300,8 +404,8 @@ GroupHook = Callable[[int, "list[int]", ResultSet], None]
 class SyncExecutor:
     """Classic per-batch loop: dispatch → sync → (maybe retry) → next.
 
-    Used for §8 perf-model fits, which need per-invocation device timings —
-    the pipelined executor deliberately makes those unobservable.
+    Used for §8 perf-model fits, which need per-invocation timings — the
+    pipelined executor deliberately makes those unobservable.
     """
 
     pipelined = False
@@ -313,8 +417,31 @@ class SyncExecutor:
         self.on_group = on_group
         self.max_capacity_retries = int(max_capacity_retries)
 
+    def _run_batch(self, i: int, batch: QueryBatch, capacity: int
+                   ) -> tuple[ResultSet | None, BatchStats, int]:
+        """Dispatch, wait, retry and marshal batch ``i``; returns its part,
+        its stats and the host syncs it took."""
+        disp = self.dispatcher
+        spans.count("dispatches")
+        with _dispatch_span(batch, capacity) as first:
+            slots = {i: disp.dispatch(batch, capacity)}
+        with spans.span("repro.exec.sync") as wait:
+            jax.block_until_ready(slots[i].out)
+            counts = {i: disp.count(slots[i])}
+            over = _overflows(disp, slots, (i,))
+        rounds: dict[int, int] = {}
+        syncs, retry_s = (_retry(disp, slots, counts, rounds, over,
+                                 self.max_capacity_retries)
+                          if over else (0, 0.0))
+        part = _marshal(disp, slots[i], counts[i])
+        pt, nt = _tile_stats(disp, slots[i])
+        return part, BatchStats(
+            batch.size, batch.num_candidates,
+            batch.size * batch.num_candidates, counts[i],
+            first.seconds + wait.seconds, rounds.get(i, 0), retry_s,
+            pruned_tiles=pt, num_tiles=nt), 1 + syncs
+
     def run(self, plan: QueryPlan) -> tuple[ResultSet, ExecStats]:
-        t_begin = time.perf_counter()
         disp = self.dispatcher
         nb = plan.num_batches
         groups = plan.groups if plan.groups else (
@@ -322,55 +449,32 @@ class SyncExecutor:
         parts: list[ResultSet] = []
         stats_by_idx: dict[int, BatchStats] = {}
         num_syncs = 0
-        for gi, g in enumerate(groups):
-            group_parts: list[ResultSet] = []
-            with _group_scope(f"sync:{gi}"):
-                for i in g:
-                    batch, capacity = plan.batches[i], plan.capacities[i]
-                    if batch.num_candidates == 0:
-                        _record_empty(disp, batch)
-                        stats_by_idx[i] = _empty_stats(batch)
-                        continue
-                    t0 = time.perf_counter()
-                    dp = disp.dispatch(batch, capacity)
-                    jax.block_until_ready(dp.out)
-                    kernel_s = time.perf_counter() - t0
-                    num_syncs += 1
-                    count = disp.count(dp)
-                    retries = 0
-                    retry_s = 0.0
-                    while (cap2 := disp.retry_capacity(dp)) is not None:
-                        if retries >= self.max_capacity_retries:
-                            raise CapacityError(
-                                count, dp.capacity, batch_index=i,
-                                retries=retries)
-                        t0r = time.perf_counter()
-                        dp = _redispatch(disp, dp, cap2)
-                        jax.block_until_ready(dp.out)
-                        retry_s += time.perf_counter() - t0r
-                        num_syncs += 1
-                        count = disp.count(dp)
-                        retries += 1
-                    part = disp.marshal(dp, count)
-                    if part is not None:
-                        group_parts.append(part)
-                    pt, nt = _tile_stats(disp, dp)
-                    stats_by_idx[i] = BatchStats(
-                        batch.size, batch.num_candidates,
-                        batch.size * batch.num_candidates, count,
-                        kernel_s, retries, retry_s,
-                        pruned_tiles=pt, num_tiles=nt)
-            parts.extend(group_parts)
-            if self.on_group is not None:
-                self.on_group(gi, list(g), ResultSet.concatenate(group_parts))
-        total = time.perf_counter() - t_begin
-        stats = [stats_by_idx[i] for i in range(nb)]
-        return (ResultSet.concatenate(parts),
-                ExecStats(plan.plan_seconds, total, stats,
-                          num_syncs=num_syncs, pipelined=False,
-                          num_groups=max(plan.num_groups, 1),
-                          pruned_interactions=getattr(
-                              plan, "pruned_interactions", 0)))
+        with spans.recording() as rec:
+            with spans.span("repro.exec.run") as whole:
+                for gi, g in enumerate(groups):
+                    group_parts: list[ResultSet] = []
+                    with _group_span(f"sync:{gi}"):
+                        for i in g:
+                            batch = plan.batches[i]
+                            if batch.num_candidates == 0:
+                                _record_empty(disp, batch)
+                                stats_by_idx[i] = _empty_stats(batch)
+                                continue
+                            part, stats_by_idx[i], syncs = self._run_batch(
+                                i, batch, plan.capacities[i])
+                            num_syncs += syncs
+                            if part is not None:
+                                group_parts.append(part)
+                    parts.extend(group_parts)
+                    if self.on_group is not None:
+                        self.on_group(gi, list(g), _concat(group_parts))
+            stats = [stats_by_idx[i] for i in range(nb)]
+            rs = _concat(parts)
+        return rs, ExecStats(
+            plan.plan_seconds, whole.seconds, stats, num_syncs=num_syncs,
+            pipelined=False, num_groups=max(plan.num_groups, 1),
+            pruned_interactions=getattr(plan, "pruned_interactions", 0),
+            span_seconds=rec.seconds, counts=rec.counts)
 
 
 class PipelinedExecutor:
@@ -398,7 +502,6 @@ class PipelinedExecutor:
         self.max_capacity_retries = int(max_capacity_retries)
 
     def run(self, plan: QueryPlan) -> tuple[ResultSet, ExecStats]:
-        t_begin = time.perf_counter()
         disp = self.dispatcher
         nb = plan.num_batches
         groups = plan.groups if plan.groups else (
@@ -408,100 +511,78 @@ class PipelinedExecutor:
         retried: dict[int, float] = {}     # batch idx -> retry wall share
         rounds: dict[int, int] = {}        # batch idx -> overflow retries
         parts: dict[int, ResultSet] = {}
-        timing = {"dispatch": 0.0, "sync": 0.0, "syncs": 0}
+        num_syncs = 0
 
         def dispatch_group(gi: int, g: list[int]) -> None:
-            t0 = time.perf_counter()
-            with _group_scope(f"pipelined:dispatch:{gi}"):
+            with _group_span(f"pipelined:dispatch:{gi}"):
                 for i in g:
                     batch = plan.batches[i]
                     if batch.num_candidates == 0:
                         _record_empty(disp, batch)
                         continue
-                    slots[i] = disp.dispatch(batch, plan.capacities[i])
-            timing["dispatch"] += time.perf_counter() - t0
+                    spans.count("dispatches")
+                    with _dispatch_span(batch, plan.capacities[i]):
+                        slots[i] = disp.dispatch(batch, plan.capacities[i])
 
         def finish_group(gi: int, g: list[int]) -> None:
+            nonlocal num_syncs
             live = [i for i in g if i in slots]
             if not live:
                 if self.on_group is not None:
                     self.on_group(gi, list(g), ResultSet.empty())
                 return
-            with _group_scope(f"pipelined:finish:{gi}"):
-                t0 = time.perf_counter()
-                jax.block_until_ready([slots[i].out for i in live])
-                timing["syncs"] += 1
-                for i in live:
-                    counts[i] = disp.count(slots[i])
-                # Re-dispatch only overflowed batches; exact counts make one
-                # retry sufficient on honest devices, so the bound below only
-                # bites when counts are corrupted or capacities adversarial.
-                t_retry = time.perf_counter()
-                any_redo = False
-                while True:
-                    redo = []
+            with _group_span(f"pipelined:finish:{gi}"):
+                with spans.span("repro.exec.sync"):
+                    jax.block_until_ready([slots[i].out for i in live])
+                    num_syncs += 1
                     for i in live:
-                        cap2 = disp.retry_capacity(slots[i])
-                        if cap2 is None:
-                            continue
-                        if rounds.get(i, 0) >= self.max_capacity_retries:
-                            raise CapacityError(
-                                counts[i], slots[i].capacity, batch_index=i,
-                                retries=rounds.get(i, 0))
-                        rounds[i] = rounds.get(i, 0) + 1
-                        slots[i] = _redispatch(disp, slots[i], cap2)
-                        redo.append(i)
-                    if not redo:
-                        break
-                    any_redo = True
-                    jax.block_until_ready([slots[i].out for i in redo])
-                    timing["syncs"] += 1
-                    for i in redo:
                         counts[i] = disp.count(slots[i])
-                retry_s = time.perf_counter() - t_retry if any_redo else 0.0
-                timing["sync"] += (time.perf_counter() - t0) - retry_s
-                grp_redo = [i for i in live if rounds.get(i, 0)]
-                for i in grp_redo:
-                    retried[i] = retry_s / len(grp_redo)
+                    over = _overflows(disp, slots, live)
+                # Re-dispatch only overflowed batches.
+                if over:
+                    syncs, retry_s = _retry(disp, slots, counts, rounds,
+                                            over, self.max_capacity_retries)
+                    num_syncs += syncs
+                    for i in over:
+                        retried[i] = retry_s / len(over)
                 # Host-side marshalling — by now the next group's phase A
                 # has already queued its device work, so this overlaps
                 # compute.
                 for i in live:
-                    part = disp.marshal(slots[i], counts[i])
+                    part = _marshal(disp, slots[i], counts[i])
                     if part is not None:
                         parts[i] = part
             if self.on_group is not None:
-                self.on_group(gi, list(g), ResultSet.concatenate(
+                self.on_group(gi, list(g), _concat(
                     [parts[i] for i in g if i in parts]))
 
-        for gi, g in enumerate(groups):
-            dispatch_group(gi, g)
-            if gi > 0:
-                finish_group(gi - 1, groups[gi - 1])
-        if groups:
-            finish_group(len(groups) - 1, groups[-1])
+        with spans.recording() as rec:
+            with spans.span("repro.exec.run") as whole:
+                for gi, g in enumerate(groups):
+                    dispatch_group(gi, g)
+                    if gi > 0:
+                        finish_group(gi - 1, groups[gi - 1])
+                if groups:
+                    finish_group(len(groups) - 1, groups[-1])
 
-        stats = []
-        for i, batch in enumerate(plan.batches):
-            if batch.num_candidates == 0:
-                stats.append(_empty_stats(batch))
-                continue
-            pt, nt = (_tile_stats(disp, slots[i]) if i in slots else (0, 0))
-            stats.append(BatchStats(
-                batch.size, batch.num_candidates,
-                batch.size * batch.num_candidates, counts.get(i, 0), 0.0,
-                rounds.get(i, 0), retried.get(i, 0.0),
-                pruned_tiles=pt, num_tiles=nt))
-        total = time.perf_counter() - t_begin
-        ordered = [parts[i] for i in sorted(parts)]
-        return (ResultSet.concatenate(ordered),
-                ExecStats(plan.plan_seconds, total, stats,
-                          num_syncs=timing["syncs"],
-                          dispatch_seconds=timing["dispatch"],
-                          sync_seconds=timing["sync"], pipelined=True,
-                          num_groups=max(len(groups), 1),
-                          pruned_interactions=getattr(
-                              plan, "pruned_interactions", 0)))
+                stats = []
+                for i, batch in enumerate(plan.batches):
+                    if batch.num_candidates == 0:
+                        stats.append(_empty_stats(batch))
+                        continue
+                    pt, nt = (_tile_stats(disp, slots[i]) if i in slots
+                              else (0, 0))
+                    stats.append(BatchStats(
+                        batch.size, batch.num_candidates,
+                        batch.size * batch.num_candidates, counts.get(i, 0),
+                        0.0, rounds.get(i, 0), retried.get(i, 0.0),
+                        pruned_tiles=pt, num_tiles=nt))
+            rs = _concat([parts[i] for i in sorted(parts)])
+        return rs, ExecStats(
+            plan.plan_seconds, whole.seconds, stats, num_syncs=num_syncs,
+            pipelined=True, num_groups=max(len(groups), 1),
+            pruned_interactions=getattr(plan, "pruned_interactions", 0),
+            span_seconds=rec.seconds, counts=rec.counts)
 
 
 def make_executor(dispatcher: BatchDispatcher, *, pipeline: bool,

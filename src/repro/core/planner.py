@@ -34,11 +34,11 @@ from __future__ import annotations
 
 import dataclasses
 import math
-import time
 from typing import Callable, Mapping, Sequence
 
 import numpy as np
 
+from repro.core import spans
 from repro.core.batching import (ALGORITHMS, BatchPlan, QueryBatch,
                                  SpatialInteractionCounter)
 from repro.core.index import TemporalBinIndex
@@ -104,7 +104,10 @@ class QueryPlan:
     batch_plan: BatchPlan
     capacities: list[int]          # result-buffer slots per batch (bucketed)
     groups: list[list[int]]        # dispatch groups: contiguous batch index runs
-    plan_seconds: float            # batching + refinement time
+    #: the batching algorithm's own time plus refinement; the pruning
+    #: pass and the pricing counter's set-up are outside it (the
+    #: facade's ``repro.plan`` span covers all of planning)
+    plan_seconds: float
     #: per-original-batch split counts when spatial pruning split candidate
     #: ranges (sum == num_batches); ``None`` when no splitting happened.
     #: Sibling batches of one run share a query range, so dispatch groups
@@ -300,23 +303,26 @@ class QueryPlanner:
         already be sorted by ``t_start`` (the facade guarantees it).
         ``d`` is the distance threshold — required for spatial pruning
         (``None`` plans temporal-only regardless of the pruning knob)."""
-        counter = None
-        if self.pruning in ("spatial", "hierarchical") and d is not None:
-            counter = SpatialInteractionCounter(
-                self.index, sorted_queries, float(d),
-                level="box" if self.pruning == "hierarchical" else "bin",
-                max_subranges=self.max_subranges)
-        try:
-            bp = ALGORITHMS[self.algorithm](self.index, sorted_queries,
-                                            counter=counter, **self.params)
-        except TypeError as e:
-            raise ValueError(
-                f"batch params {self.params} do not match algorithm "
-                f"{self.algorithm!r}: {e} (pass batching=... alongside the "
-                f"algorithm's parameters)") from None
+        with spans.span("repro.plan.batching"):
+            counter = None
+            if self.pruning in ("spatial", "hierarchical") and d is not None:
+                counter = SpatialInteractionCounter(
+                    self.index, sorted_queries, float(d),
+                    level="box" if self.pruning == "hierarchical" else "bin",
+                    max_subranges=self.max_subranges)
+            try:
+                bp = ALGORITHMS[self.algorithm](self.index, sorted_queries,
+                                                counter=counter,
+                                                **self.params)
+            except TypeError as e:
+                raise ValueError(
+                    f"batch params {self.params} do not match algorithm "
+                    f"{self.algorithm!r}: {e} (pass batching=... alongside "
+                    f"the algorithm's parameters)") from None
         if counter is None:
             return self.refine(bp)
-        bp, runs, pruned = self._prune_batches(bp, counter)
+        with spans.span("repro.plan.prune"):
+            bp, runs, pruned = self._prune_batches(bp, counter)
         return self.refine(bp, runs=runs, pruned_interactions=pruned)
 
     def _prune_batches(self, bp: BatchPlan,
@@ -376,16 +382,16 @@ class QueryPlanner:
         ``BatchPlan`` arguments).  The batches' candidate ranges are taken
         as given; ``runs``/``pruned_interactions`` carry the provenance of
         an upstream :meth:`_prune_batches` pass (groups align to runs)."""
-        t0 = time.perf_counter()
-        caps = [size_capacity(b, self.default_capacity, self.granularity)
-                for b in batch_plan.batches]
-        gs = self.group_size
-        if gs is None:
-            gs = derive_group_size(batch_plan.batches,
-                                   predict_hits=self.predict_hits)
-        groups = make_groups(len(batch_plan.batches), gs, runs=runs)
+        with spans.span("repro.plan.refine") as sp:
+            caps = [size_capacity(b, self.default_capacity, self.granularity)
+                    for b in batch_plan.batches]
+            gs = self.group_size
+            if gs is None:
+                gs = derive_group_size(batch_plan.batches,
+                                       predict_hits=self.predict_hits)
+            groups = make_groups(len(batch_plan.batches), gs, runs=runs)
         return QueryPlan(batch_plan, caps, groups,
-                         batch_plan.plan_seconds + time.perf_counter() - t0,
+                         batch_plan.plan_seconds + sp.seconds,
                          runs=runs, pruned_interactions=pruned_interactions)
 
 

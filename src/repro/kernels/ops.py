@@ -49,6 +49,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro import faults
+from repro.core import spans
 from repro.kernels import distthresh as _dt
 from repro.kernels import ref
 from repro.kernels.distthresh import (DEFAULT_CAND_BLK, DEFAULT_QRY_BLK,
@@ -355,6 +356,10 @@ def query_block(entries: jnp.ndarray, queries: jnp.ndarray, d, *,
 
     No pruning mode ever changes the result set, only the work; the dense
     path ignores both.
+
+    The bytes of the host arrays handed to the jit call (the upload) are
+    added to the ``h2d_bytes`` counter of the caller's recorder
+    (``repro.core.spans``).
     """
     if compaction not in COMPACTIONS:
         raise ValueError(f"unknown compaction {compaction!r}; "
@@ -394,6 +399,9 @@ def query_block(entries: jnp.ndarray, queries: jnp.ndarray, d, *,
                 return out
             prune_arrays = dict(tile_i=tile_i, tile_j=tile_j,
                                 n_live=n_live)
+    spans.count("h2d_bytes", sum(
+        a.nbytes for a in (entries, queries, d, *prune_arrays.values())
+        if isinstance(a, (np.ndarray, np.generic))))
     return _query_block_jit(entries, queries, d, capacity=capacity,
                             use_pallas=use_pallas, interpret=interpret,
                             cand_blk=cand_blk, qry_blk=qry_blk,
