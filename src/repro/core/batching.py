@@ -33,13 +33,18 @@ Algorithms:
 * :func:`setsplit_max` — Algorithm 3 with ``min=1`` (paper §6.2 last line).
 * :func:`greedysetsplit_min` / :func:`greedysetsplit_max` — Algorithm 4:
   one pass of "free" merges (merges that add zero interactions), then one
-  constraint pass.  O(|Q|) merge decisions.
+  constraint pass.  The free-merge pass is a left-to-right scan that
+  prices a look-ahead window of prefix unions per call, each under its own
+  prune threshold (as a one-row call would); the constraint pass decides
+  on sizes alone and prices its merged batches in one call.  O(|Q|) work,
+  about one pricing call per free-merge run, no per-merge array copy.
 
 The SETSPLIT loops are vectorized with numpy (all adjacent-pair merge costs
-are evaluated per iteration with ``candidate_range_batch``), which keeps
-the quadratic algorithms usable at |Q| of a few thousand.  The *semantics*
-are line-for-line the paper's: each iteration merges the adjacent pair with
-the smallest ``numIntsMerged − numIntsUnmerged``.
+are evaluated per iteration with ``candidate_range_batch``, the stack under
+one shared prune threshold), which keeps the quadratic algorithms usable at
+|Q| of a few thousand.  The *semantics* are line-for-line the paper's: each
+iteration merges the adjacent pair with the smallest
+``numIntsMerged − numIntsUnmerged``.
 
 Public entry point: algorithm selection lives in
 ``repro.api.ExecutionPolicy(batching=..., batch_params=...)``; the facade
@@ -53,6 +58,7 @@ from typing import Callable
 
 import numpy as np
 
+from repro.core import spans
 from repro.core.index import TemporalBinIndex
 from repro.core.segments import SegmentArray
 
@@ -128,6 +134,13 @@ class SpatialInteractionCounter:
         """Pruned candidate counts for batches with extents (qt0, qt1) and
         query-MBR unions (lo, hi) — all stacked arrays."""
         return self.index.estimate_pruned_candidates_batch(
+            qt0, qt1, lo, hi, self.d, level=self.level,
+            max_subranges=self.max_subranges)
+
+    def row_counts(self, qt0, qt1, lo, hi) -> np.ndarray:
+        """:meth:`counts` with each row under its own prune threshold, as
+        a one-row call would price it."""
+        return self.index.estimate_pruned_candidates_rows(
             qt0, qt1, lo, hi, self.d, level=self.level,
             max_subranges=self.max_subranges)
 
@@ -333,33 +346,122 @@ def setsplit_max(index: TemporalBinIndex, queries: SegmentArray,
 def _greedy(index: TemporalBinIndex, queries: SegmentArray, bound: int,
             variant: str,
             counter: SpatialInteractionCounter | None = None) -> BatchPlan:
+    """Algorithm 4 as one left-to-right scan and one pass over sizes.
+
+    Phase 1 (lines 4–11) merges ``B[i]`` and ``B[i+1]`` iff the merge is
+    free: ``numInts(merge) == numInts(B[i]) + numInts(B[i+1])``.  The right
+    neighbour of the batch being grown is always an untouched singleton,
+    so the pass is a scan: from the open batch ``A = [a, j]`` it prices the
+    prefix unions ``A ∪ {j+1 … k}`` of a look-ahead window of ``L``
+    segments in one call (running max of ``te``, running min/max of the
+    query MBRs), and the first ``k`` whose price is not the previous
+    prefix's plus singleton ``k``'s closes ``A`` at ``k−1``.  A window that
+    passes whole extends ``A`` and doubles ``L``; a new batch starts with
+    ``L`` twice the closed batch's size.  A scan with no free merge thus
+    makes one call per segment, long free runs O(log) calls each.
+
+    Each prefix union is priced under its own prune threshold
+    (:meth:`SpatialInteractionCounter.row_counts`), as the merge-by-merge
+    loop's one-row calls priced it; the singletons are priced in one
+    stacked call, as that loop's initial state was.  Phase 2 (lines
+    13–20) decides on sizes alone, so it runs over the phase-1 sizes and
+    prices only the batches it merged, in one call.  The plan is the
+    merge-by-merge loop's, batch for batch.  The spans counters
+    ``plan_price_calls`` and ``plan_price_rows`` count the pricing calls
+    and the rows they priced.
+    """
     t_begin = time.perf_counter()
-    st = _BatchState(index, queries, counter)
-    # Phase 1 (lines 4–11): single pass of free merges.  A merge is free iff
-    # numInts(merge) == numInts(B[i]) + numInts(B[i+1]).
+    if not queries.is_sorted():
+        raise ValueError("queries must be sorted by t_start (paper §4)")
+    nq = len(queries)
+    if nq == 0:
+        raise ValueError("empty query set")
+    ts = queries.ts.astype(np.float64)
+    te = queries.te.astype(np.float64)
+    calls, rows = 1, nq                   # the singletons' stacked call
+
+    def price(qt0, qt1, lo, hi, size) -> np.ndarray:
+        """numInts of batches, each under its own prune threshold."""
+        nonlocal calls, rows
+        calls += 1
+        rows += len(qt1)
+        if counter is None:
+            return size * index.num_candidates_batch(qt0, qt1)
+        return size * counter.row_counts(qt0, qt1, lo, hi)
+
+    if counter is not None:
+        qlo, qhi = counter.qlo, counter.qhi
+        single = counter.counts(ts, te, qlo, qhi)
+    else:                                 # MBRs carried along, never read
+        qlo = qhi = np.zeros((nq, 3))
+        single = index.num_candidates_batch(ts, te)
+
+    # Phase 1: the open batch is [a, j]; closed batches go to the lists.
+    starts: list[int] = []
+    ints: list[int] = []
+    a = j = 0
+    a_ints, a_t1, a_lo, a_hi = int(single[0]), te[0], qlo[0], qhi[0]
+    width = 2
+    while j < nq - 1:
+        win = slice(j + 1, min(j + width, nq - 1) + 1)
+        t1 = np.maximum(np.maximum.accumulate(te[win]), a_t1)
+        lo = np.minimum(np.minimum.accumulate(qlo[win]), a_lo)
+        hi = np.maximum(np.maximum.accumulate(qhi[win]), a_hi)
+        size = np.arange(j - a + 2, win.stop - a + 1)
+        merged = price(np.full(len(t1), ts[a]), t1, lo, hi, size)
+        prev = np.r_[a_ints, merged[:-1]]
+        free = merged == prev + single[win]
+        if free.all():
+            j = win.stop - 1
+            a_ints, a_t1, a_lo, a_hi = int(merged[-1]), t1[-1], lo[-1], hi[-1]
+            width *= 2
+            continue
+        f = int(np.argmin(free))
+        starts.append(a)
+        ints.append(int(prev[f]))
+        width = 2 * (j + f + 1 - a)
+        a = j = j + f + 1
+        a_ints, a_t1, a_lo, a_hi = int(single[a]), te[a], qlo[a], qhi[a]
+    starts.append(a)
+    ints.append(a_ints)
+
+    # Phase 2: merge batch i into its successors while it is under the
+    # bound ("min"), or has not yet exceeded it ("max": the bound is soft,
+    # the merge that crosses it is still made, as the literal
+    # transformation of line 14 dictates).
+    sizes = np.diff(np.r_[starts, nq]).tolist()
+    groups = []                           # first phase-1 batch of each
     i = 0
-    while i < len(st) - 1:
-        if st.merged_ints(i) == st.num_ints[i] + st.num_ints[i + 1]:
-            st.merge_at(i)
-        else:
+    while i < len(sizes):
+        groups.append(i)
+        size = sizes[i]
+        while i < len(sizes) - 1 and (size < bound if variant == "min"
+                                      else size <= bound):
             i += 1
-    # Phase 2 (lines 13–20): constraint pass.
-    i = 0
-    while i < len(st) - 1:
-        if variant == "min":
-            if st.sizes[i] < bound:
-                st.merge_at(i)
-            else:
-                i += 1
-        else:  # "max": paper swaps the test and the clauses — merge while the
-            # current batch has not yet exceeded the bound.  The bound is soft
-            # (the merge that crosses it is still performed), exactly as the
-            # literal transformation of line 14 dictates.
-            if st.sizes[i] > bound:
-                i += 1
-            else:
-                st.merge_at(i)
-    return _finish(f"greedysetsplit-{variant}", {"bound": bound}, st, t_begin)
+            size += sizes[i]
+        i += 1
+    first_q = np.asarray(starts, np.int64)[groups]
+    last_q = np.r_[first_q[1:], nq] - 1
+    qt0 = ts[first_q]
+    qt1 = np.maximum.reduceat(te, first_q)
+    num_ints = np.asarray(ints, np.int64)[groups]
+    joined = np.diff(np.r_[groups, len(sizes)]) > 1
+    if joined.any():
+        num_ints[joined] = price(
+            qt0[joined], qt1[joined],
+            np.minimum.reduceat(qlo, first_q, axis=0)[joined],
+            np.maximum.reduceat(qhi, first_q, axis=0)[joined],
+            (last_q - first_q + 1)[joined])
+    spans.count("plan_price_calls", calls)
+    spans.count("plan_price_rows", rows)
+    cand_first, cand_last = index.candidate_range_batch(qt0, qt1)
+    batches = [QueryBatch(int(q0), int(q1), float(t0), float(t1), int(f),
+                          int(l), int(n))
+               for q0, q1, t0, t1, f, l, n in zip(
+                   first_q, last_q, qt0, qt1, cand_first, cand_last,
+                   num_ints)]
+    return _finish(f"greedysetsplit-{variant}", {"bound": bound}, batches,
+                   t_begin)
 
 
 def greedysetsplit_min(index: TemporalBinIndex, queries: SegmentArray,

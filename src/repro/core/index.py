@@ -413,6 +413,18 @@ class TemporalBinIndex:
                   if finite.any() else 0.0)
         return prune_limit(d, max(self._prune_scale, qscale))
 
+    def _row_limits(self, d: float, qlo: np.ndarray,
+                    qhi: np.ndarray) -> np.ndarray:
+        """:meth:`_limit` of each row of an (n, 3) stack on its own: the
+        (n,) thresholds that n one-row calls would use, from the same
+        finite-entry rule and the same :func:`prune_limit` arithmetic."""
+        finite = np.isfinite(qlo) & np.isfinite(qhi)
+        qscale = np.where(finite, np.maximum(np.abs(qlo), np.abs(qhi)),
+                          0.0).max(axis=1, initial=0.0)
+        scales, inv = np.unique(np.maximum(qscale, self._prune_scale),
+                                return_inverse=True)
+        return np.array([prune_limit(d, s) for s in scales.tolist()])[inv]
+
     def _bin_runs(self, bins: slice, j_lo: int, qt0: float, qlo, qhi,
                   lim2: float, first: int, last: int) -> list[list[int]]:
         """Surviving bin runs as [first, last] segment sub-ranges (PR 5)."""
@@ -562,27 +574,53 @@ class TemporalBinIndex:
         the expected re-admission if the cap merges a proportional share
         of the internal gaps — keeping the pricing signal honest on
         heavily fragmented extents instead of silently under-counting
-        them.  This is the signal the SETSPLIT/GREEDYSETSPLIT merge loops
-        consume.
+        them.  This is the signal the merge loops consume.
+
+        The whole stack shares one threshold, :meth:`_limit` over every
+        row; :meth:`estimate_pruned_candidates_rows` gives each row its
+        own.
         """
-        qt0 = np.asarray(qt0, np.float64)
-        qt1 = np.asarray(qt1, np.float64)
         qlo = np.asarray(qlo, np.float64).reshape(-1, 3)
         qhi = np.asarray(qhi, np.float64).reshape(-1, 3)
+        return self._estimate_pruned(
+            qt0, qt1, qlo, qhi, self._limit(float(d), qlo, qhi),
+            level=level, max_subranges=max_subranges)
+
+    def estimate_pruned_candidates_rows(self, qt0, qt1, qlo, qhi,
+                                        d: float, *,
+                                        level: str = "bin",
+                                        max_subranges: int | None = None
+                                        ) -> np.ndarray:
+        """:meth:`estimate_pruned_candidates_batch` with each row priced
+        under its own threshold (:meth:`_row_limits`), so a row's count is
+        what a one-row call gives, whatever it is stacked with.  The
+        GREEDYSETSPLIT scan prices its prefix unions with it."""
+        qlo = np.asarray(qlo, np.float64).reshape(-1, 3)
+        qhi = np.asarray(qhi, np.float64).reshape(-1, 3)
+        return self._estimate_pruned(
+            qt0, qt1, qlo, qhi, self._row_limits(float(d), qlo, qhi),
+            level=level, max_subranges=max_subranges)
+
+    def _estimate_pruned(self, qt0, qt1, qlo, qhi, lim, *, level: str,
+                         max_subranges: int | None) -> np.ndarray:
+        """The coarse-grid estimate under threshold ``lim``: one float
+        for the whole stack, or an (n,) array of one per row."""
+        qt0 = np.asarray(qt0, np.float64)
+        qt1 = np.asarray(qt1, np.float64)
         first, last = self.candidate_range_batch(qt0, qt1)
         cf, cl = self._coarse_first, self._coarse_last
         ov = (np.minimum(last[:, None], cl[None, :])
               - np.maximum(first[:, None], cf[None, :]) + 1)
         ov = np.maximum(ov, 0)
-        lim = self._limit(float(d), qlo, qhi)
+        lim2 = np.reshape(np.multiply(lim, lim), (-1, 1))    # (n | 1, 1)
         if level == "box":
             gap2 = mbr_gap2(self._coarse_klo[None], self._coarse_khi[None],
                             qlo[:, None, None], qhi[:, None, None])  # (n,k,K)
-            keep = (gap2 <= lim * lim).any(axis=2)
+            keep = (gap2 <= lim2[:, :, None]).any(axis=2)
         else:
             gap2 = mbr_gap2(self._coarse_lo[None], self._coarse_hi[None],
                             qlo[:, None], qhi[:, None])     # (n, k)
-            keep = gap2 <= lim * lim
+            keep = gap2 <= lim2
         est = (ov * keep).sum(axis=1).astype(np.int64)
         if max_subranges is not None:
             kk = keep & (ov > 0)

@@ -161,6 +161,39 @@ class TestSpatialMBRs:
         np.testing.assert_array_equal(
             est, idx.num_candidates_batch(queries.ts, queries.te))
 
+    @pytest.mark.filterwarnings("ignore:invalid value:RuntimeWarning")
+    @pytest.mark.parametrize("level,cap", [("bin", None), ("box", 3)])
+    def test_row_estimate_is_each_row_alone(self, level, cap):
+        """``estimate_pruned_candidates_rows`` prices each row as a one-row
+        stack does: its threshold follows its own scale, floored at the
+        database's, from its finite coordinates only."""
+        rng = np.random.default_rng(4)
+        db = random_segments(rng, 300)
+        idx = TemporalBinIndex.build(db, num_bins=64, kboxes=4)
+        queries = random_segments(rng, 40, box=45.0)
+        qlo, qhi = queries.mbrs()
+        qlo[::5] *= 80.0                  # scales above the database's
+        qhi[::5] *= 80.0
+        qlo[1::5] *= 0.01                 # and below it
+        qhi[1::5] *= 0.01
+        qlo[2, 1], qhi[3, 2] = -np.inf, np.inf   # (inf − inf in the gaps)
+        qt0 = queries.ts.astype(np.float64)
+        qt1 = qt0 + 20.0
+        d = 0.5
+        limits = idx._row_limits(d, qlo, qhi)
+        rows = idx.estimate_pruned_candidates_rows(
+            qt0, qt1, qlo, qhi, d, level=level, max_subranges=cap)
+        for k in range(len(queries)):
+            one = slice(k, k + 1)
+            assert limits[k] == idx._limit(d, qlo[one], qhi[one])
+            assert rows[k] == idx.estimate_pruned_candidates_batch(
+                qt0[one], qt1[one], qlo[one], qhi[one], d, level=level,
+                max_subranges=cap)[0]
+        assert len(set(limits.tolist())) > 2
+        shared = idx.estimate_pruned_candidates_batch(
+            qt0, qt1, qlo, qhi, d, level=level, max_subranges=cap)
+        assert (shared != rows).any()
+
     def test_subranges_subset_of_candidate_range(self):
         rng = np.random.default_rng(3)
         db = random_segments(rng, 400)
