@@ -7,6 +7,10 @@ in a file of its own, so adding one is adding files:
   execution policy, the precision and guarantees it states;
 * ``bench/workloads/<cell>.json`` — a cell: its configuration, traffic
   kind, chips and traffic parameters;
+* ``bench/datasets/<generator>.py`` — a dataset generator
+  (``generate(seed, **params)``) with its rule for drawing query sets of
+  equal work (``strata(data, n)``), named by a configuration's
+  ``dataset.generator``;
 * ``bench/traffic/<kind>.py`` — a traffic driver (``warmup``, ``window``,
   ``check``), shared by every cell of that kind;
 * ``bench/metrics/<metric>.py`` — a reader with ``read(run)`` that returns
@@ -17,6 +21,7 @@ in a file of its own, so adding one is adding files:
 from __future__ import annotations
 
 import dataclasses
+import functools
 import importlib.util
 import json
 import os
@@ -57,12 +62,22 @@ def load_cell(name: str) -> Cell:
 
 
 def _module(kind: str, name: str):
-    path = _file(kind, name, ".py")
-    spec = importlib.util.spec_from_file_location(
-        f"bench.{kind}.{name.replace('.', '_').replace('-', '_')}", path)
+    return _load(f"bench.{kind}.{name.replace('.', '_').replace('-', '_')}",
+                 _file(kind, name, ".py"))
+
+
+@functools.lru_cache(maxsize=None)
+def _load(modname: str, path: str):
+    """The module at ``path``, run once per process."""
+    spec = importlib.util.spec_from_file_location(modname, path)
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
     return mod
+
+
+def dataset(generator: str):
+    """The dataset module of ``generator``."""
+    return _module("datasets", generator)
 
 
 def traffic(kind: str):

@@ -1,14 +1,15 @@
-"""The benchmark's own copies of the paper's §7.1 dataset generators.
+"""What the benchmark's dataset generators share.
 
-Copied from the program's ``repro.data.trajgen`` (``galaxy``) so that the
-yardstick does not move when the program's generators do.  The
-distributions and their parameters are the paper's as the program ships
-them; only the way the random numbers are drawn differs: every array is
-drawn in bulk (no per-trajectory loop), all of it from the run's seed,
-and each uniform parameter is drawn stratified (:func:`stratified`), so
-that every seed's dataset has the same density and so the same work.
-Galaxy's time grid is fixed by the paper (400 unit steps shared by every
-star), so every seed plans the same batch shapes.
+Each generator is a module of its own, ``bench/datasets/<generator>.py``,
+found by the name a configuration gives under ``dataset.generator``
+(:func:`module`).  It has two functions:
+
+* ``generate(seed, **params)`` — the :class:`Dataset`, every array drawn
+  in bulk from ``seed``, the parameters that set the work drawn stratified
+  (:func:`stratified`), so that every seed's dataset holds the same work;
+* ``strata(data, n)`` — the dataset's trajectories in ``n`` strata of
+  equal work, from which the traffic draws one trajectory each for a
+  query set, so that every seed's sets hold the same work too.
 
 A dataset is a :class:`Dataset`: struct-of-arrays float32 segment columns
 in trajectory order (trajectory ``k``'s segments are rows
@@ -20,6 +21,8 @@ from __future__ import annotations
 import dataclasses
 
 import numpy as np
+
+from bench import cells
 
 #: Segment columns, in the order the reference and the program's packed
 #: layout both use: start point, end point, temporal extent.
@@ -50,8 +53,8 @@ class Dataset:
                 else np.zeros(0, np.int64))
 
 
-def _from_points(name: str, points: np.ndarray, times: np.ndarray,
-                 lengths: np.ndarray) -> Dataset:
+def from_points(name: str, points: np.ndarray, times: np.ndarray,
+                lengths: np.ndarray) -> Dataset:
     """Segments between consecutive points of each trajectory.
 
     ``points`` is (P, 3) and ``times`` (P,), all trajectories' points
@@ -85,41 +88,14 @@ def stratified(rng: np.random.Generator, lo: float, hi: float,
     return lo + (hi - lo) * u
 
 
-def galaxy(seed: int, *, num_traj: int = 2500,
-           num_segments: int = 400) -> Dataset:
-    """GALAXY: disk-galaxy stellar orbits (flat rotation curve, radial
-    epicycles, vertical oscillation), every star on one shared time grid
-    of ``num_segments`` unit steps over [0, 400]."""
-    rng = np.random.default_rng(seed)
-    nt = num_traj
-    steps = num_segments + 1
-    t = np.linspace(0.0, 400.0, steps, dtype=np.float64)
-    r0 = stratified(rng, 4.0, 12.0, nt)
-    v0 = 0.22
-    omega = v0 / r0
-    phi0 = stratified(rng, 0.0, 2 * np.pi, nt)
-    a_r = stratified(rng, 0.0, 0.6, nt)
-    kappa = np.sqrt(2.0) * omega
-    psi0 = stratified(rng, 0.0, 2 * np.pi, nt)
-    a_z = stratified(rng, 0.0, 0.3, nt)
-    nu = 2.0 * omega
-    zeta0 = stratified(rng, 0.0, 2 * np.pi, nt)
-    tt = t[None, :]
-    r = r0[:, None] + a_r[:, None] * np.cos(kappa[:, None] * tt
-                                            + psi0[:, None])
-    ang = phi0[:, None] + omega[:, None] * tt
-    pts = np.stack([r * np.cos(ang), r * np.sin(ang),
-                    a_z[:, None] * np.sin(nu[:, None] * tt + zeta0[:, None])],
-                   axis=-1).reshape(-1, 3)
-    times = np.tile(t, nt)
-    return _from_points("galaxy", pts, times, np.full(nt, num_segments))
-
-
-GENERATORS = {"galaxy": galaxy}
+def module(config: dict):
+    """The dataset module a configuration names under
+    ``dataset.generator``."""
+    return cells.dataset(config["dataset"]["generator"])
 
 
 def make(config: dict, seed: int) -> Dataset:
     """The dataset a configuration names, drawn from ``seed``."""
-    spec = config["dataset"]
-    params = {k: v for k, v in spec.items() if k != "generator"}
-    return GENERATORS[spec["generator"]](seed, **params)
+    params = {k: v for k, v in config["dataset"].items()
+              if k != "generator"}
+    return module(config).generate(seed, **params)

@@ -1,4 +1,4 @@
-"""assemble_ms.batch: median over the checked query sets of the seconds
+"""assemble_ms.batch: median over the window's query sets of the seconds
 of result assembly on the host: marshalling less its copies from the
 device (``repro.exec.marshal`` less ``repro.engine.fetch``: masks and
 id gathers) and the concatenation of the parts (``repro.exec.concat``),

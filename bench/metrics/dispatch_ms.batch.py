@@ -1,4 +1,4 @@
-"""dispatch_ms.batch: median over the checked query sets of the seconds
+"""dispatch_ms.batch: median over the window's query sets of the seconds
 of their first dispatches (the program's ``repro.engine.dispatch`` span:
 host slicing, the jit call's enqueue and the upload), in ms."""
 from bench import spans

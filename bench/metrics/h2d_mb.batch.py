@@ -1,4 +1,4 @@
-"""h2d_mb.batch: median over the checked query sets of the bytes of the
+"""h2d_mb.batch: median over the window's query sets of the bytes of the
 host arrays handed to the kernels' jit call, retries included (the
 program's ``h2d_bytes`` counter), in MB (10^6 bytes)."""
 from bench import spans

@@ -1,4 +1,4 @@
-"""slot_use.batch: median over the checked query sets of the share of the
+"""slot_use.batch: median over the window's query sets of the share of the
 result-buffer slots copied back that held a row (the program's
 ``result_rows`` over ``result_slots``), a fraction."""
 from bench import spans

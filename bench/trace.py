@@ -7,8 +7,15 @@ into the program.  From the ``.xplane.pb`` this module reads:
 * the device's busy time: the union of the intervals in which an operation
   ran on the device, clipped to the window, averaged over the chips used;
 * seconds per device operation name, within the window;
-* the device's idle gaps, each given to the innermost benchmark host span
-  that covers its midpoint (``"outside"`` when none does).
+* the device's idle time by what the host was doing: each idle gap is cut
+  at the edges of the host spans it overlaps (the benchmark's ``bench.``
+  spans and the program's ``repro.`` spans), and each piece goes to the
+  innermost span that covers it (``"outside"`` where none does).  Only
+  the spans of the host thread that runs the window count: a traffic kind
+  that queries from that thread (``closed_sets``) has the program's spans
+  there; one that hands its queries to other threads sees their time as
+  the window thread's own spans or ``"outside"``, and needs a rule of its
+  own for which thread keeps the device waiting.
 
 Device operations are the events of the ``XLA Ops`` line of each
 ``/device:TPU:<n>`` plane.  The patterns are arguments, so a test can
@@ -17,16 +24,18 @@ thread.
 """
 from __future__ import annotations
 
+import bisect
 import dataclasses
 import glob
+import heapq
 import os
 import re
 
 #: Where a TPU trace keeps the device's operations.
 TPU_PLANES = r"^/device:TPU:\d+$"
 TPU_OP_LINE = r"^XLA Ops$"
-#: Prefix of the benchmark's own host spans.
-SPAN_PREFIX = "bench."
+#: Prefixes of the host spans: the benchmark's own and the program's.
+SPAN_PREFIX = ("bench.", "repro.")
 #: The span around the measured window.
 WINDOW_SPAN = "bench.window"
 
@@ -37,7 +46,6 @@ class Reduced:
     busy_s: float                   # averaged over device planes
     op_seconds: dict                # op name -> seconds (all planes)
     idle_by_span: dict              # host span -> idle seconds, averaged
-    longest_gaps: list              # [(span, seconds)] longest first
     num_devices: int
 
 
@@ -86,24 +94,59 @@ def _union(intervals: list, lo: float, hi: float) -> list:
     return merged
 
 
-def host_spans(planes: list, prefix: str = SPAN_PREFIX) -> list:
-    """Every host event whose name starts with ``prefix``:
-    ``[(name, start_ns, end_ns)]``."""
-    spans = []
+def host_spans(planes: list, prefix=SPAN_PREFIX) -> list:
+    """The host events whose name starts with ``prefix`` (a string or a
+    tuple of them), by the host thread (the trace's line) that recorded
+    them: ``[[(name, start_ns, end_ns)], ...]``, one list per thread."""
+    threads = []
     for pname, lines in planes:
         if pname.startswith("/device:"):
             continue
         for _, events in lines:
-            spans.extend(e for e in events if e[0].startswith(prefix))
-    return spans
+            spans = [e for e in events if e[0].startswith(prefix)]
+            if spans:
+                threads.append(spans)
+    return threads
+
+
+def _stretches(spans: list) -> tuple[list, list]:
+    """The host timeline cut at every span's edges: the sorted edges, and
+    for each stretch between two consecutive edges the innermost (the
+    shortest) span that covers it, or ``"outside"``."""
+    edges = sorted({x for _, s, e in spans for x in (s, e)})
+    by_start = sorted(spans, key=lambda sp: sp[1])
+    active, owners, j = [], [], 0
+    for lo in edges[:-1]:
+        while j < len(by_start) and by_start[j][1] <= lo:
+            name, s, e = by_start[j]
+            heapq.heappush(active, (e - s, j, e, name))
+            j += 1
+        while active and active[0][2] <= lo:
+            heapq.heappop(active)
+        owners.append(active[0][3] if active else "outside")
+    return edges, owners
+
+
+def _pieces(lo: float, hi: float, edges: list, owners: list):
+    """``(owner, ns)`` of each piece of the gap [lo, hi], cut at the
+    edges."""
+    k = bisect.bisect_right(edges, lo)
+    while lo < hi:
+        cut = min(hi, edges[k]) if k < len(edges) else hi
+        yield (owners[k - 1] if 0 < k <= len(owners) else "outside",
+               cut - lo)
+        lo, k = cut, k + 1
 
 
 def reduce(planes: list, *, plane_re: str = TPU_PLANES,
-           line_re: str = TPU_OP_LINE, window: str = WINDOW_SPAN,
-           top: int = 10) -> Reduced:
-    """Busy time, per-op seconds and idle gaps within the ``window`` span
-    (the whole trace when no such span exists)."""
-    spans = host_spans(planes)
+           line_re: str = TPU_OP_LINE, window: str = WINDOW_SPAN
+           ) -> Reduced:
+    """Busy time, per-op seconds and idle time by host span within the
+    ``window`` span, by the spans of the thread that holds it (the whole
+    trace and every thread's spans when no such span exists)."""
+    threads = host_spans(planes)
+    spans = next((t for t in threads if any(s[0] == window for s in t)),
+                 [s for t in threads for s in t])
     devices = []
     for pname, lines in planes:
         if not re.search(plane_re, pname):
@@ -133,23 +176,16 @@ def reduce(planes: list, *, plane_re: str = TPU_PLANES,
         gaps.extend((edges[i], edges[i + 1])
                     for i in range(0, len(edges), 2)
                     if edges[i + 1] > edges[i])
-    inner = sorted((s for s in spans if s[0] != window),
-                   key=lambda s: s[2] - s[1])
+    cuts, owners = _stretches([s for s in spans if s[0] != window])
     n = max(len(devices), 1)
     idle: dict = {}
-    named = []
     for s, e in gaps:
-        mid = 0.5 * (s + e)
-        owner = next((sp[0] for sp in inner if sp[1] <= mid <= sp[2]),
-                     "outside")
-        idle[owner] = idle.get(owner, 0.0) + (e - s) * 1e-9 / n
-        named.append((owner, (e - s) * 1e-9))
-    named.sort(key=lambda g: -g[1])
+        for owner, ns in _pieces(s, e, cuts, owners):
+            idle[owner] = idle.get(owner, 0.0) + ns * 1e-9 / n
     return Reduced(
         window_s=(hi - lo) * 1e-9, busy_s=busy * 1e-9 / n,
         op_seconds={k: v * 1e-9 for k, v in op_ns.items()},
-        idle_by_span=idle, longest_gaps=named[:top],
-        num_devices=len(devices))
+        idle_by_span=idle, num_devices=len(devices))
 
 
 def breakdown(red: Reduced, top: int = 10) -> dict:

@@ -7,10 +7,13 @@ the previous one returned.
 
 Every seed gets the same amount of work: a set takes one trajectory,
 drawn from the run seed, from each of ``query_set_trajectories`` strata
-of equal size, formed by the trajectories' mean distance from the
-dataset's centre.  A star's neighbours, and so a set's hit rows, follow
-its radius: drawn plainly, GALAXY sets of 10 spread by about 8 % in
-their hit rows; drawn so, by about 1.3 %.
+of equal work, which the dataset's module forms
+(``bench/datasets/<generator>.py``, ``strata``).
+
+Each execution's record copies the program's spans and counters
+(``ExecStats.span_seconds`` and ``counts``) under ``spans`` and
+``counts``, which the per-layer readers (``bench/spans.py``) read over
+every execution of the window.
 
 Parameters (the cell's ``params``):
 
@@ -29,27 +32,15 @@ import time
 
 import numpy as np
 
-from bench import harness, reference
+from bench import datagen, harness, reference
 from bench.traffic import common
-
-
-def strata(data, n: int) -> list[np.ndarray]:
-    """The trajectories in ``n`` strata of (nearly) equal size, by the
-    mean distance of their segments' start points from the dataset's
-    centre."""
-    xyz = np.stack([data.cols[c] for c in ("xs", "ys", "zs")], axis=1)
-    centre = xyz.mean(axis=0)
-    dist = np.linalg.norm(xyz - centre, axis=1)
-    mean = (np.add.reduceat(dist, data.offsets[:-1])
-            / np.diff(data.offsets))
-    return np.array_split(np.argsort(mean, kind="stable"), n)
 
 
 def prepare(ctx, seconds: float) -> dict:
     p = ctx.cell.params
     n = int(ctx.cell.config["query_set_trajectories"])
     rng = harness.stream(ctx.seed, "window")
-    groups = strata(ctx.data, n)
+    groups = datagen.module(ctx.cell.config).strata(ctx.data, n)
     comps = [np.array([g[rng.integers(len(g))] for g in groups])
              for _ in range(int(p["sets"]))]
     return {"comps": comps,
@@ -91,7 +82,9 @@ def window(ctx, state, seconds: float) -> dict:
                           "plan_s": st.plan_seconds,
                           "dispatch_s": st.dispatch_seconds,
                           "sync_s": st.sync_seconds,
-                          "qsegs": len(state["rows"][k]), "hits": len(res)})
+                          "qsegs": len(state["rows"][k]), "hits": len(res),
+                          "spans": dict(getattr(st, "span_seconds", {})),
+                          "counts": dict(getattr(st, "counts", {}))})
             sample.offer((k, res))
             del res
         i += 1

@@ -1,36 +1,31 @@
-"""The closed loop's query sets: one trajectory of each stratum, drawn
-from the seed, and every set of the window run in the warm-up."""
+"""The closed loop's query sets: one trajectory of each of the dataset's
+strata, drawn from the seed, and every set of the window run in the
+warm-up."""
 import contextlib
 import types
 
 import numpy as np
 
 import _bench_tiny  # noqa: F401 (puts the checkout root on sys.path)
-from bench import datagen
+from bench import cells
 from bench.traffic import closed_sets
+
+GALAXY = cells.dataset("galaxy")
 
 
 def _ctx(seed, sets=5, n=4):
-    data = datagen.galaxy(7, num_traj=40, num_segments=6)
+    data = GALAXY.generate(7, num_traj=40, num_segments=6)
     cell = types.SimpleNamespace(params={"sets": sets},
-                                 config={"query_set_trajectories": n})
+                                 config={"query_set_trajectories": n,
+                                         "dataset": {"generator": "galaxy"}})
     return types.SimpleNamespace(cell=cell, seed=seed, data=data,
                                  segments=lambda rows: rows)
-
-
-def test_strata_split_every_trajectory_by_distance():
-    data = datagen.galaxy(7, num_traj=42, num_segments=6)
-    groups = closed_sets.strata(data, 4)
-    assert [len(g) for g in groups] == [11, 11, 10, 10]
-    assert np.array_equal(np.sort(np.concatenate(groups)), np.arange(42))
-    r = np.hypot(data.cols["xs"], data.cols["ys"]).reshape(42, 6).mean(1)
-    assert r[groups[0]].max() < r[groups[-1]].min()
 
 
 def test_every_set_takes_one_trajectory_of_each_stratum():
     big = 2 ** 33 + 3
     state = closed_sets.prepare(_ctx(big), 1.0)
-    groups = closed_sets.strata(_ctx(big).data, 4)
+    groups = GALAXY.strata(_ctx(big).data, 4)
     assert len(state["comps"]) == 5
     for comp in state["comps"]:
         assert all(t in g for t, g in zip(comp, groups))

@@ -1,10 +1,12 @@
-"""The benchmark's dataset generators: deterministic per seed, at the
-paper's sizes."""
+"""What the dataset generators share (``bench/datagen.py``): a
+configuration's generator found by name, as a file of its own, and the
+stratified draws."""
 import numpy as np
 import pytest
 
 import _bench_tiny  # noqa: F401 (puts the checkout root on sys.path)
-from bench import datagen
+from bench import cells, datagen, harness
+from bench.traffic import closed_sets
 
 
 def _same(a, b):
@@ -14,44 +16,58 @@ def _same(a, b):
             and np.array_equal(a.seg_id, b.seg_id))
 
 
-@pytest.mark.parametrize("make", [
-    lambda s: datagen.galaxy(s, num_traj=30, num_segments=20),
-    lambda s: datagen.galaxy(s, num_traj=3, num_segments=200),
-])
-def test_same_seed_same_data_other_seed_other_data(make):
-    big = 2 ** 33 + 17                   # seeds beyond 32 signed bits
-    assert _same(make(big), make(big))
-    assert not _same(make(big), make(big + 1))
-
-
-def test_galaxy_at_the_papers_size():
-    data = datagen.galaxy(3)
-    assert len(data) == 10 ** 6 and data.num_traj == 2500
-    assert np.all(data.cols["te"] - data.cols["ts"] == 1.0)
-    assert data.cols["ts"].min() == 0.0 and data.cols["te"].max() == 400.0
-    rows = data.rows_of([7])
-    assert np.all(data.traj_id[rows] == 7)
-    assert np.array_equal(data.seg_id[rows], np.arange(400))
-    # Consecutive segments of a trajectory join end to start.
-    assert np.array_equal(data.cols["xe"][rows[:-1]],
-                          data.cols["xs"][rows[1:]])
-
-
-def test_every_seed_has_the_same_time_grid():
-    a, b = datagen.galaxy(1, num_traj=50), datagen.galaxy(2, num_traj=50)
-    # Same segment time extents, so every seed plans the same batch
-    # shapes; different orbits.
-    assert np.array_equal(a.cols["ts"], b.cols["ts"])
-    assert np.array_equal(a.cols["te"], b.cols["te"])
-    assert not np.array_equal(a.cols["xs"], b.cols["xs"])
-
-
 def test_make_reads_the_configuration():
     cfg = {"dataset": {"generator": "galaxy", "num_traj": 5,
                        "num_segments": 4}}
     data = datagen.make(cfg, 9)
-    assert len(data) == 20 and _same(data, datagen.galaxy(
+    assert len(data) == 20 and _same(data, cells.dataset("galaxy").generate(
         9, num_traj=5, num_segments=4))
+
+
+#: A dataset of a new kind, as a later configuration would bring it: one
+#: file, nothing else edited.
+WALK = '''
+import numpy as np
+
+from bench import datagen
+
+
+def generate(seed, *, num_traj, num_segments):
+    rng = np.random.default_rng(seed)
+    pts = rng.normal(size=(num_traj, num_segments + 1, 3)).cumsum(axis=1)
+    times = np.tile(np.arange(num_segments + 1.0), num_traj)
+    return datagen.from_points("walk", pts.reshape(-1, 3), times,
+                               np.full(num_traj, num_segments))
+
+
+def strata(data, n):
+    return np.array_split(np.arange(data.num_traj), n)
+'''
+
+
+def test_a_new_generator_file_builds_through_the_harness(tmp_path,
+                                                         monkeypatch):
+    (tmp_path / "datasets").mkdir()
+    (tmp_path / "datasets" / "walk.py").write_text(WALK)
+    monkeypatch.setattr(cells, "BENCH", str(tmp_path))
+    config = {"name": "walk", "d": 1.0, "query_set_trajectories": 3,
+              "backend": "jnp", "policy": {},
+              "dataset": {"generator": "walk", "num_traj": 9,
+                          "num_segments": 5}}
+    cell = cells.Cell(name="walk.batch", config_name="walk", config=config,
+                      traffic="closed_sets", chips=1, params={"sets": 2})
+    ctx = harness.build(cell, 2 ** 33 + 1)
+    assert len(ctx.data) == len(ctx.db) == 45
+    assert ctx.data.name == "walk"
+    # The sets are drawn from the new module's strata.
+    state = closed_sets.prepare(ctx, 1.0)
+    assert [sorted(c // 3) for c in state["comps"]] == [[0, 1, 2]] * 2
+
+
+def test_an_unknown_generator_is_refused_with_its_path():
+    cfg = {"dataset": {"generator": "no_such_generator"}}
+    with pytest.raises(KeyError, match=r"datasets/no_such_generator\.py"):
+        datagen.make(cfg, 1)
 
 
 def test_stratified_draws_one_value_in_each_stratum():
@@ -59,14 +75,3 @@ def test_stratified_draws_one_value_in_each_stratum():
     assert np.all((u >= 4.0) & (u < 12.0))
     assert np.array_equal(np.sort(np.floor((u - 4.0) / 8.0 * 50)),
                           np.arange(50))
-
-
-def test_every_seed_has_the_same_radii_per_stratum():
-    def radii(seed):
-        data = datagen.galaxy(seed, num_traj=40, num_segments=8)
-        r = np.hypot(data.cols["xs"], data.cols["ys"]).reshape(40, 8)
-        return np.sort(r.mean(axis=1))
-
-    # r0 is stratified: the k-th smallest star of any seed lies within a
-    # stratum (0.2) and the epicycle (0.6) of any other's.
-    assert np.all(np.abs(radii(1) - radii(2)) < 1.4)

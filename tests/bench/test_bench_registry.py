@@ -52,6 +52,9 @@ def test_each_configuration_is_its_own_file(c):
     assert cfg["reduced"] == c["reduced"]
     assert set(cfg["reduced"]) <= set(cfg["source_values"])
     assert any(w["config"] == c["name"] for w in SPEC["workloads"])
+    # Its dataset's generator is a file of its own.
+    gen = cells.dataset(cfg["dataset"]["generator"])
+    assert callable(gen.generate) and callable(gen.strata)
 
 
 METRICS = SPEC["end_to_end"] + SPEC["per_layer"]
@@ -79,7 +82,7 @@ def _run(kind):
                       "hits": 5}] * 2}
     red = trace.Reduced(window_s=2.0, busy_s=0.5,
                         op_seconds={"distthresh_kernel": 0.25, "copy": 0.1},
-                        idle_by_span={}, longest_gaps=[], num_devices=1)
+                        idle_by_span={}, num_devices=1)
     return harness.Run(cell=None, setup_s=3.0, record=rec,
                        compiles={"compile_s": 1.5}, device_kind="TPU v5 lite",
                        trace=red, work=(1e9, 1e8))

@@ -6,7 +6,7 @@ import numpy as np
 import pytest
 
 import _bench_tiny  # noqa: F401 (puts the checkout root on sys.path)
-from bench import datagen, harness, reference
+from bench import cells, datagen, harness, reference
 from bench.traffic import common
 
 
@@ -37,7 +37,8 @@ def test_reservoir_covers_the_whole_window():
 
 
 def _ctx(seed=4):
-    data = datagen.galaxy(seed, num_traj=12, num_segments=30)
+    data = cells.dataset("galaxy").generate(seed, num_traj=12,
+                                            num_segments=30)
     ctx = types.SimpleNamespace(data=data, d=0.8, control=None,
                                 index=reference.EntryIndex(data))
     return ctx

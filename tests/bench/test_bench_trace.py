@@ -78,11 +78,48 @@ def test_hand_built_trace():
     assert red.op_seconds == pytest.approx(
         {"fusion.1": 10 * ns, "distthresh_kernel": 25 * ns,
          "copy": 5 * ns})
-    # Gaps [0, 10] and [40, 90]: midpoints 5 (bench.step) and 65
-    # (bench.wait); [95, 100] midpoint 97.5 (bench.wait).
+    # Gap [0, 10] lies in bench.step; [40, 90] is cut at 45, 50 and 60:
+    # bench.step, its inner bench.submit, bench.step, bench.wait; [95,
+    # 100] lies in bench.wait.
     assert red.idle_by_span == pytest.approx(
-        {"bench.step": 10 * ns, "bench.wait": 55 * ns})
-    assert red.longest_gaps[0] == ("bench.wait", pytest.approx(50 * ns))
+        {"bench.step": 25 * ns, "bench.submit": 5 * ns,
+         "bench.wait": 35 * ns})
+
+
+def test_a_gap_is_split_among_the_spans_it_overlaps():
+    """One gap, [10, 90], across two nested spans and stretches that no
+    span covers: each piece goes to the innermost span over it."""
+    ops = [("fusion", 0.0, 10.0), ("copy", 90.0, 100.0)]
+    spans = [(trace.WINDOW_SPAN, 0.0, 100.0),
+             ("bench.query_set", 20.0, 80.0), ("repro.plan", 30.0, 50.0),
+             ("other.span", 0.0, 100.0)]     # neither bench. nor repro.
+    red = trace.reduce(_plane(ops, spans))
+    ns = 1e-9
+    assert red.idle_by_span == pytest.approx(
+        {"outside": 20 * ns, "bench.query_set": 40 * ns,
+         "repro.plan": 20 * ns})
+    assert trace.breakdown(red)["idle_gaps"][0] == [
+        "bench.query_set", pytest.approx(40 * ns)]
+
+
+def test_spans_of_other_threads_own_no_idle_time():
+    """Idle time goes to the spans of the thread that holds the window; a
+    span that another thread records over the same gap owns none of it,
+    unless the trace has no window span."""
+    ops = [("fusion", 0.0, 10.0), ("copy", 90.0, 100.0)]
+    main = [("bench.query_set", 0.0, 100.0)]
+    worker = [("repro.plan", 20.0, 60.0)]
+    ns = 1e-9
+
+    def planes(main):
+        return [("/device:TPU:0", [("XLA Ops", ops)]),
+                ("/host:CPU", [("python", main), ("worker", worker)])]
+
+    red = trace.reduce(planes([(trace.WINDOW_SPAN, 0.0, 100.0)] + main))
+    assert red.idle_by_span == pytest.approx({"bench.query_set": 80 * ns})
+    red = trace.reduce(planes(main))
+    assert red.idle_by_span == pytest.approx(
+        {"bench.query_set": 40 * ns, "repro.plan": 40 * ns})
 
 
 def test_op_name_drops_the_hlo_text():
