@@ -67,7 +67,7 @@ import numpy as np
 from repro.core import spans
 from repro.core.batching import ALGORITHMS, BatchPlan
 from repro.core.engine import (DistanceThresholdEngine, ExecStats, ResultSet,
-                               brute_force)
+                               brute_force, check_ladder)
 from repro.core.errors import CapacityError, PodFailedError
 from repro.core.index import DEFAULT_NUM_BINS, TemporalBinIndex
 from repro.core.planner import PRUNINGS, QueryPlan, QueryPlanner
@@ -153,6 +153,13 @@ class ExecutionPolicy:
     #: :class:`~repro.core.errors.CapacityError` carrying the exact count
     #: instead of growing (and recompiling) without bound.
     max_capacity_retries: int = 3
+    #: steps per octave of the single-device dispatch-shape ladder: the
+    #: engine pads each batch's candidate and query slices to whole tiles on
+    #: a ladder of that many lengths per octave (padding below 1/steps), so
+    #: a query set whose batches have many lengths lowers few shapes.
+    #: ``None`` dispatches exact slices, one jit shape per distinct length.
+    #: The shard backend always pads, on its own power-of-two ladder.
+    dispatch_ladder: int | None = None
 
     # -- sharded mesh backend (backend="shard") -------------------------
     shard_pods: int | None = None         # None → every local device
@@ -512,7 +519,7 @@ class TrajectoryDB:
         if name in ("pallas", "jnp"):
             return (pol.interpret, pol.cand_blk, pol.qry_blk, pol.capacity,
                     pol.compaction, pol.pipeline, pol.pruning,
-                    pol.max_capacity_retries)
+                    pol.max_capacity_retries, pol.dispatch_ladder)
         if name == "shard":
             use_pallas = _shard_use_pallas(pol)
             # compaction (and kernel pruning) only matter on the Pallas
@@ -557,6 +564,7 @@ class TrajectoryDB:
                 eng.pipeline = pol.pipeline
                 eng.pruning = pol.pruning
                 eng.max_capacity_retries = pol.max_capacity_retries
+                eng.dispatch_ladder = check_ladder(pol.dispatch_ladder)
                 self._backends[key] = EngineBackend(name, eng)
             elif name == "shard":
                 from repro.core.distributed import ShardedEngine

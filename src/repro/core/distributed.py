@@ -395,15 +395,10 @@ class _PodShardDispatcher:
         self.engine = engine
         self.q_packed = q_packed
         self.d = d
-        # Pad instants must lie beyond the database AND this query set —
-        # a query extending past the database's extent must not overlap
-        # entry pad rows (the single-device path gets this from
-        # ops._pad_time; the pre-padded shard blocks must reproduce it).
-        pad = engine._pad_t
-        if q_packed.shape[0]:
-            pad = max(pad, float(q_packed[:, 7].max()) + 1.0)
-        self._pad_e = pad          # entry pad rows: [pad, pad]
-        self._pad_q = pad + 1.0    # query pad rows: disjoint instant
+        # Pad instants beyond the database AND this query set — a query
+        # extending past the database's extent must not overlap entry pad
+        # rows — with query pads at an instant of their own.
+        self._pad_e, self._pad_q = ops.pad_instants(engine._t_end, q_packed)
 
     def _pod_lens(self, batch) -> tuple[list[int], list[int]]:
         """Per-pod (first index, length) of the batch's candidate range
@@ -437,22 +432,14 @@ class _PodShardDispatcher:
         # perm reorders only within bin ∩ pod pieces).
         src = (se._packed_perm if se.plan_pruning == "hierarchical"
                else se._packed)
-        stacked = np.zeros((se.ways, c_loc, 8), np.float32)
-        stacked[:, :, 6] = stacked[:, :, 7] = self._pad_e
-        for p, (lo, n) in enumerate(zip(los, lens)):
-            if n:
-                stacked[p, :n] = src[lo:lo + n]
+        stacked = np.stack([ops.pad_block(src[lo:lo + n], c_loc, self._pad_e)
+                            for lo, n in zip(los, lens)])
         offsets = np.asarray(los, np.int32)
         # Replicated query batch, bucketed on the same ladder as the
         # candidate blocks so the jit cache stays O(log²).
         qs = self.q_packed[batch.q_first:batch.q_last + 1]
-        qn = qs.shape[0]
-        qb = bucket_capacity(qn, se.qry_blk)
-        if qb != qn:
-            qpad = np.zeros((qb, 8), np.float32)
-            qpad[:, 6] = qpad[:, 7] = self._pad_q
-            qpad[:qn] = qs
-            qs = qpad
+        qs = ops.pad_block(qs, bucket_capacity(qs.shape[0], se.qry_blk),
+                           self._pad_q)
         lens_arr = np.asarray(lens, np.int32)
         return self._launch(batch, capacity, (stacked, offsets, lens_arr, qs))
 
@@ -614,7 +601,7 @@ class ShardedEngine:
         self.pruning = (pruning if use_pallas
                         and compaction in ("fused", "fused_rowloop")
                         else "none")
-        self._pad_t = float(self.db.temporal_extent[1]) + 1.0
+        self._t_end = float(self.db.temporal_extent[1])
         self._fns: dict[int, object] = {}
 
     # ------------------------------------------------------------------

@@ -1,11 +1,12 @@
 """End-to-end distance-threshold query engine (paper §4–§5).
 
-Pipeline per the paper's "general approach" (§4): the sorted entry segments
-live on the device once and for all; the host keeps the temporal-bin index
-and the sorted query set; queries are partitioned into batches (see
-``repro.core.batching``); for each batch the host computes the contiguous
-candidate index range from the bins and dispatches one device computation
-comparing the batch's query segments against that candidate slice.
+Pipeline per the paper's "general approach" (§4): the host keeps the
+sorted entry segments, the temporal-bin index and the sorted query set;
+queries are partitioned into batches (see ``repro.core.batching``); for
+each batch the host computes the contiguous candidate index range from the
+bins, slices that range and the batch's query segments out of its packed
+arrays, and dispatches one device computation comparing the two, which
+uploads both slices anew.
 
 PR 3 split this module's former responsibilities three ways:
 
@@ -25,8 +26,13 @@ the mechanics):
 
 * **Shape bucketing.**  The GPU pays a per-invocation overhead Θ; the XLA
   analogue is *compilation* of every new shape.  Result capacities round up
-  to power-of-two buckets (``planner.bucket_capacity``) so the jit cache
-  stays O(log²) instead of O(batches).
+  to power-of-two buckets (``planner.bucket_capacity``).  With the engine's
+  ``dispatch_ladder`` set (``ExecutionPolicy.dispatch_ladder``), the
+  candidate and query slices are padded too, to whole tiles on a ladder of
+  that many lengths per octave (``planner.bucket_rows``), with pad rows
+  that can never hit (``ops.pad_block``): a query set whose batches have
+  thousands of lengths then lowers tens of shapes.  Unset, the slices go
+  out as they are, one shape per distinct length.
 * **Overflow-retry result buffers.**  The paper statically allocates |D|
   result slots (§5).  We allocate ``capacity`` slots per batch and retry
   with doubled (bucketed) capacity on overflow — the kernel always reports
@@ -52,11 +58,21 @@ from repro.core.executor import (BatchStats, Dispatch,  # noqa: F401 (stable re-
                                  ExecStats, ResultSet, make_executor)
 from repro.core.index import DEFAULT_NUM_BINS, TemporalBinIndex
 from repro.core.planner import (QueryPlan, as_query_plan,
-                                bucket_capacity as _bucket)
+                                bucket_capacity as _bucket, bucket_rows)
 from repro.core.segments import SegmentArray
 from repro.kernels import ops
 from repro.kernels.distthresh import (DEFAULT_CAND_BLK, DEFAULT_QRY_BLK,
                                       resolve_interpret)
+
+
+def check_ladder(steps: int | None) -> int | None:
+    """``steps`` as a dispatch ladder: None, or a positive count of steps
+    per octave."""
+    if steps is not None and (isinstance(steps, bool) or int(steps) != steps
+                              or steps < 1):
+        raise ValueError(f"dispatch_ladder must be None or a positive "
+                         f"number of steps per octave, not {steps!r}")
+    return steps
 
 
 class _QueryBlockDispatcher:
@@ -71,6 +87,9 @@ class _QueryBlockDispatcher:
         self.engine = engine
         self.q_packed = q_packed
         self.d = d
+        if engine.dispatch_ladder:
+            self._pad_e, self._pad_q = ops.pad_instants(
+                engine.db.temporal_extent[1], q_packed)
 
     def dispatch(self, batch, capacity: int) -> Dispatch:
         eng = self.engine
@@ -85,11 +104,26 @@ class _QueryBlockDispatcher:
                   else eng._packed)
         e_slice = packed[batch.cand_first:batch.cand_last + 1]
         q_slice = self.q_packed[batch.q_first:batch.q_last + 1]
+        real = (e_slice.shape[0], q_slice.shape[0])
+        valid_rows = None
+        if eng.dispatch_ladder:
+            # Both slices padded onto the ladder, entry pads and query pads
+            # at instants of their own beyond every real one: entry_idx
+            # still indexes the real slice, since pads never hit.
+            e_slice = ops.pad_block(e_slice, bucket_rows(
+                real[0], eng.cand_blk, eng.dispatch_ladder), self._pad_e)
+            q_slice = ops.pad_block(q_slice, bucket_rows(
+                real[1], eng.qry_blk, eng.dispatch_ladder), self._pad_q)
+            valid_rows = real
+        rows = e_slice.shape[0] + q_slice.shape[0]
+        spans.count("dispatched_rows", rows)
+        spans.count("pad_rows", rows - sum(real))
         out = ops.query_block(
             e_slice, q_slice, np.float32(self.d), capacity=capacity,
             use_pallas=eng.use_pallas, interpret=eng.interpret,
             cand_blk=eng.cand_blk, qry_blk=eng.qry_blk,
-            compaction=eng.compaction, pruning=eng.pruning)
+            compaction=eng.compaction, pruning=eng.pruning,
+            valid_rows=valid_rows)
         return Dispatch(batch, capacity, out)
 
     def count(self, dp: Dispatch) -> int:
@@ -154,7 +188,8 @@ class DistanceThresholdEngine:
                  cand_blk: int = DEFAULT_CAND_BLK, qry_blk: int = DEFAULT_QRY_BLK,
                  default_capacity: int = 4096, compaction: str = "fused",
                  pipeline: bool = True, pruning: str = "spatial",
-                 index_kboxes: int = 1, max_capacity_retries: int = 3):
+                 index_kboxes: int = 1, max_capacity_retries: int = 3,
+                 dispatch_ladder: int | None = None):
         """``use_pallas=False`` routes interactions through the jnp oracle —
         the right default on CPU where Pallas runs in interpret mode.  Both
         paths share identical semantics (tests assert equality).
@@ -182,6 +217,10 @@ class DistanceThresholdEngine:
         split factor K handed to ``TemporalBinIndex.build`` — structural
         (the default K=1 makes hierarchical planning degenerate to
         bin-level boxes while keeping the live-tile kernel dispatch).
+
+        ``dispatch_ladder`` (steps per octave) pads every dispatch's
+        candidate and query slices onto ``planner.bucket_rows``'s ladder
+        (see the module docstring); ``None`` dispatches exact slices.
         """
         if compaction not in ops.COMPACTIONS:
             raise ValueError(f"unknown compaction {compaction!r}; "
@@ -208,6 +247,7 @@ class DistanceThresholdEngine:
         # Bounded overflow-retry (PR 10): batches whose hits still exceed
         # capacity after this many doublings raise CapacityError.
         self.max_capacity_retries = int(max_capacity_retries)
+        self.dispatch_ladder = check_ladder(dispatch_ladder)
 
     # ------------------------------------------------------------------
     def dispatcher(self, queries_packed: np.ndarray,

@@ -9,7 +9,7 @@ call).  This module owns all of it:
 
 * :func:`bucket_capacity` — the power-of-two capacity ladder that bounds
   the jit-cache size (moved here from ``engine._bucket``; the engine keeps
-  an alias).
+  an alias), and :func:`bucket_rows`, the ladder of dispatch-block lengths.
 * :class:`QueryPlan` — the full executable description of one query set:
   the :class:`~repro.core.batching.BatchPlan` (which contiguous query runs
   hit which contiguous candidate ranges), a sized result capacity per
@@ -82,6 +82,19 @@ def bucket_capacity(n: int, blk: int = CAPACITY_GRANULARITY) -> int:
     while b < n:
         b *= 2
     return b
+
+
+def bucket_rows(n: int, blk: int, steps: int) -> int:
+    """Rows a dispatch block of ``n`` rows is padded to: whole tiles of
+    ``blk`` rows, the tile count rounded up to a ladder of about ``steps``
+    values per octave (exact up to ``2 * steps`` tiles, then multiples of
+    ``2**k // steps`` in each octave ``(2**k, 2**(k+1)]``, and its top).
+    The padding stays below ``1 / steps`` of the tiles, and ``n`` up to
+    ``T`` tiles meets about ``steps * log2(T / steps)`` lengths."""
+    tiles = max(-(-n // blk), 1)
+    octave = 1 << max((tiles - 1).bit_length() - 1, 0)
+    spacing = max(octave // steps, 1)
+    return min(-(-tiles // spacing) * spacing, 2 * octave) * blk
 
 
 @dataclasses.dataclass
@@ -410,6 +423,6 @@ def as_query_plan(plan: "BatchPlan | QueryPlan", *,
 __all__ = [
     "AUTO_GROUP_HIT_FRACTION", "AUTO_GROUP_HIT_ROWS", "CAPACITY_GRANULARITY",
     "DEFAULT_CAPACITY", "PRUNINGS", "QueryPlan", "QueryPlanner",
-    "as_query_plan", "bucket_capacity", "derive_group_size", "make_groups",
-    "size_capacity",
+    "as_query_plan", "bucket_capacity", "bucket_rows", "derive_group_size",
+    "make_groups", "size_capacity",
 ]

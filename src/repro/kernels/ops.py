@@ -34,11 +34,14 @@ row-major within each kernel tile, tiles in grid order — so consumers that
 need a canonical order sort downstream (``ResultSet.sorted_canonical``,
 ``QueryResult.from_result_set``).
 
-Shape discipline: callers pass *bucketed* (padded) shapes so that the jit
-cache stays small — see ``repro.core.engine``.  Padded entries/queries are
-constructed with temporal extents outside the data range (see
-``SegmentArray.packed``), so they can never hit; correctness does not
-depend on cropping.
+Shape discipline: ``query_block``'s jit is keyed on the row counts it is
+handed, so a dispatcher that meets many batch shapes pads its blocks to a
+ladder of lengths first (the shard dispatcher always,
+``repro.core.distributed``; the single-device one under
+``ExecutionPolicy.dispatch_ladder``, ``repro.core.engine``), with pad rows
+built by :func:`pad_block` at the instants of :func:`pad_instants`:
+temporal extents outside the data range, so they can never hit, and
+correctness does not depend on cropping.
 """
 from __future__ import annotations
 
@@ -72,6 +75,29 @@ COMPACTIONS = ("fused", "fused_rowloop", "dense")
 #: ever changes the result set (the box test uses the conservatively
 #: inflated ``prune_limit`` threshold), only the work.
 PRUNINGS = ("spatial", "hierarchical", "none")
+
+
+def pad_instants(t_end: float, queries: np.ndarray) -> tuple[float, float]:
+    """The instants of a dispatcher's host pad rows: entry pads at the
+    first, query pads at the second.  Both lie beyond ``t_end`` (the
+    database's last instant) and every query's, and one apart, so no pad
+    row overlaps a real row or the other side's pads in time."""
+    t = max(float(t_end), float(queries[:, 7].max(initial=t_end))) + 1.0
+    return t, t + 1.0
+
+
+def pad_block(x: np.ndarray, rows: int, t: float) -> np.ndarray:
+    """Packed rows ``x`` followed by pad rows up to ``rows``: each a
+    zero-length extent at instant ``t`` (:func:`pad_instants`), at the
+    origin."""
+    n = x.shape[0]
+    if rows == n:
+        return x
+    out = np.zeros((rows, 8), x.dtype)
+    out[:, 6:8] = t
+    out[:n] = x
+    return out
+
 
 def _pad_rows(x: jnp.ndarray, multiple: int, pad_t: jnp.ndarray) -> jnp.ndarray:
     """Pad (N, 8) packed segments to a row multiple with non-hitting rows."""
@@ -139,6 +165,20 @@ def _empty_block(capacity: int, dtype) -> dict:
             "count": jnp.zeros((), jnp.int32),
             "pruned_tiles": jnp.zeros((), jnp.int32),
             "num_tiles": jnp.zeros((), jnp.int32)}
+
+
+def _pad_tiles(mbr: np.ndarray, rows: int, blk: int) -> np.ndarray:
+    """A tile-MBR table over real rows, extended to the tiles of ``rows``
+    with the empty box (±inf), whose gap is ``inf``: a tile of host pad
+    rows, which can never hit, is always skipped."""
+    nt = -(-rows // blk)
+    if nt == mbr.shape[0]:
+        return mbr
+    out = np.zeros((nt, 8), np.float32)
+    out[:, 0:3] = np.inf
+    out[:, 3:6] = -np.inf
+    out[:mbr.shape[0]] = mbr
+    return out
 
 
 def _host_tile_mbrs(packed: np.ndarray, blk: int) -> np.ndarray:
@@ -311,7 +351,8 @@ def query_block(entries: jnp.ndarray, queries: jnp.ndarray, d, *,
                 capacity: int, use_pallas: bool = True,
                 interpret: bool | None = None,
                 cand_blk: int = DEFAULT_CAND_BLK, qry_blk: int = DEFAULT_QRY_BLK,
-                compaction: str = "fused", pruning: str = "none"):
+                compaction: str = "fused", pruning: str = "none",
+                valid_rows: tuple[int, int] | None = None):
     """Interaction evaluation + deterministic compaction into flat buffers.
 
     Returns a dict with:
@@ -357,6 +398,11 @@ def query_block(entries: jnp.ndarray, queries: jnp.ndarray, d, *,
     No pruning mode ever changes the result set, only the work; the dense
     path ignores both.
 
+    ``valid_rows=(c, q)`` says that only the first ``c`` entries and ``q``
+    queries are real and the rest are a dispatcher's host pad rows
+    (:func:`pad_block`).  The host box tests then see the real rows alone,
+    as they would unpadded, and every tile of pad rows is skipped.
+
     The bytes of the host arrays handed to the jit call (the upload) are
     added to the ``h2d_bytes`` counter of the caller's recorder
     (``repro.core.spans``).
@@ -367,25 +413,32 @@ def query_block(entries: jnp.ndarray, queries: jnp.ndarray, d, *,
     if pruning not in PRUNINGS:
         raise ValueError(f"unknown pruning {pruning!r}; "
                          f"choose from {PRUNINGS}")
+    real_e, real_q = entries, queries
+    if valid_rows is not None:
+        real_e, real_q = entries[:valid_rows[0]], queries[:valid_rows[1]]
     # Chaos hook (PR 10), gated to host-side dispatch so it can never fire
     # inside an outer trace (shard_map passes tracers for entries/queries).
     if faults.armed() and isinstance(entries, np.ndarray):
         faults.inject("ops.query_block", compaction=compaction,
                       pruning=pruning, use_pallas=use_pallas,
-                      rows=int(entries.shape[0]))
+                      rows=int(real_e.shape[0]))
     prune_arrays = {}
     host_prunable = (use_pallas and compaction in ("fused", "fused_rowloop")
                      and isinstance(entries, np.ndarray)
                      and isinstance(queries, np.ndarray)
-                     and entries.shape[0] and queries.shape[0])
+                     and real_e.shape[0] and real_q.shape[0])
     if pruning == "spatial" and host_prunable:
-        prep = _host_tile_prune(entries, queries, d, cand_blk, qry_blk)
+        prep = _host_tile_prune(real_e, real_q, d, cand_blk, qry_blk)
         if prep is None:
             pruning = "none"           # nothing skippable: unarmed kernel
         else:
-            prune_arrays = dict(zip(("e_mbr", "q_mbr", "d_prune"), prep))
+            e_mbr, q_mbr, d_prune = prep
+            prune_arrays = dict(
+                e_mbr=_pad_tiles(e_mbr, entries.shape[0], cand_blk),
+                q_mbr=_pad_tiles(q_mbr, queries.shape[0], qry_blk),
+                d_prune=d_prune)
     elif pruning == "hierarchical" and host_prunable:
-        prep = _host_live_tiles(entries, queries, d, cand_blk, qry_blk)
+        prep = _host_live_tiles(real_e, real_q, d, cand_blk, qry_blk)
         if prep is None:
             pruning = "none"           # nothing skippable: unarmed kernel
         else:

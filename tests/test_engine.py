@@ -313,3 +313,216 @@ class TestScenarioIntegration:
         plan = batching.periodic(eng.index, queries, 64)
         rs, _ = eng.execute(queries, d, plan)
         _check_equal(rs, bf)
+
+
+# ----------------------------------------------------------------------
+# The single-device dispatch-shape ladder (ExecutionPolicy.dispatch_ladder)
+# ----------------------------------------------------------------------
+class TestBucketRows:
+    @pytest.mark.parametrize("steps", [1, 2, 3, 4, 8])
+    def test_rungs_are_whole_tiles_and_pad_below_one_step(self, steps):
+        from repro.core.planner import bucket_rows
+        blk = 16
+        rungs = set()
+        for n in range(0, 40 * blk):
+            b = bucket_rows(n, blk, steps)
+            tiles = max(-(-n // blk), 1)
+            assert b % blk == 0 and b >= max(n, 1)
+            assert b // blk - tiles < max(tiles / steps, 1)
+            assert bucket_rows(b, blk, steps) == b      # a rung is its own
+            rungs.add(b)
+        # exact up to 2 * steps tiles, then `steps` rungs per octave
+        assert {r // blk for r in rungs if r // blk <= 2 * steps} == set(
+            range(1, min(2 * steps, 40) + 1))
+        per_octave = steps if steps & (steps - 1) == 0 else steps + 1
+        assert all(len({r for r in rungs if lo < r // blk <= 2 * lo})
+                   <= per_octave for lo in (16, 32) if lo >= 2 * steps)
+
+    def test_ladder_must_be_a_positive_count(self, world):
+        db, _, _, _ = world
+        for bad in (0, -2, 1.5, True):
+            with pytest.raises(ValueError):
+                DistanceThresholdEngine(db, num_bins=16, dispatch_ladder=bad)
+
+
+@pytest.fixture(scope="module")
+def randwalk_db():
+    """The paper's RANDWALK-EXP database at scale 0.01 (S9: 7,167
+    segments, 891 query segments, d = 50), in batches of 128 queries."""
+    from repro.api import ExecutionPolicy, TrajectoryDB
+    policy = ExecutionPolicy(batching="periodic", batch_params={"s": 128},
+                             num_bins=100)
+    db = TrajectoryDB.from_scenario("S9", scale=0.01, policy=policy)
+    brute = db.query(db.scenario_queries, db.scenario_d, backend="brute")
+    assert len(brute) > 0
+    return db, brute
+
+
+_ROWS = ("entry_idx", "entry_traj", "entry_seg", "query_idx")
+_ALL = _ROWS + ("t_enter", "t_exit")
+LADDER_MODES = [("jnp", "dense"), ("pallas", "fused"), ("pallas", "dense"),
+                ("pallas", "fused_rowloop")]
+
+
+@pytest.mark.parametrize("pruning", ["spatial", "hierarchical", "none"])
+@pytest.mark.parametrize("backend,compaction", LADDER_MODES)
+def test_randwalk_ladder_matches_brute_force(randwalk_db, backend,
+                                             compaction, pruning):
+    """Padded dispatches give the exact dispatches' canonical result, byte
+    for byte, and both give brute force's rows (intervals to the usual
+    cross-backend f32 tolerance)."""
+    db, brute = randwalk_db
+    queries, d = db.scenario_queries, db.scenario_d
+    exact, padded = (db.query(queries, d, backend=backend,
+                              policy=db.policy.with_(
+                                  compaction=compaction, pruning=pruning,
+                                  dispatch_ladder=ladder))
+                     for ladder in (None, 2))
+    for f in _ALL:
+        np.testing.assert_array_equal(getattr(padded, f), getattr(exact, f),
+                                      err_msg=f)
+    for f in _ROWS:
+        np.testing.assert_array_equal(getattr(exact, f), getattr(brute, f),
+                                      err_msg=f)
+    for f in ("t_enter", "t_exit"):
+        np.testing.assert_allclose(getattr(exact, f), getattr(brute, f),
+                                   rtol=1e-4, atol=1e-3, err_msg=f)
+    assert exact.stats.counts["pad_rows"] == 0
+    assert 0 < padded.stats.counts["pad_rows"] < padded.stats.counts[
+        "dispatched_rows"]
+
+
+def _record_blocks(monkeypatch):
+    """Every ``ops.query_block`` call of the engine: (candidate rows,
+    query rows, valid_rows)."""
+    from repro.core import engine
+    real, seen = engine.ops.query_block, []
+
+    def query_block(entries, queries, d, **kw):
+        seen.append((entries.shape[0], queries.shape[0],
+                     kw.get("valid_rows")))
+        return real(entries, queries, d, **kw)
+
+    monkeypatch.setattr(engine.ops, "query_block", query_block)
+    return seen
+
+
+@pytest.mark.parametrize("ladder", [None, 2])
+def test_dispatch_hands_raw_or_laddered_slices(randwalk_db, monkeypatch,
+                                               ladder):
+    """Unset, the dispatcher hands ``query_block`` the raw slices, as
+    before the ladder existed; set, slices padded onto the ladder, with
+    the real row counts beside them."""
+    from repro.core.planner import bucket_rows
+    db, _ = randwalk_db
+    queries, d = db.scenario_queries, db.scenario_d
+    seen = _record_blocks(monkeypatch)
+    res = db.query(queries, d, backend="jnp",
+                   policy=db.policy.with_(dispatch_ladder=ladder))
+    raw = [(b.num_candidates, b.size) for b in res.plan.batches
+           if b.num_candidates > 0]
+    assert len(seen) == len(raw) == res.stats.num_invocations
+    if ladder is None:
+        assert [(c, q) for c, q, _ in seen] == raw
+        assert all(v is None for _, _, v in seen)
+    else:
+        assert [v for _, _, v in seen] == raw
+        assert [(c, q) for c, q, _ in seen] == [
+            (bucket_rows(c, 256, ladder), bucket_rows(q, 256, ladder))
+            for c, q in raw]
+
+
+def test_many_batch_lengths_lower_few_shapes(randwalk_db, monkeypatch):
+    """A query set whose batches have tens of distinct candidate lengths
+    lowers no more ``_query_block_jit`` shapes than the ladder has rungs
+    for, times the capacities met."""
+    from repro.api import ExecutionPolicy
+    from repro.core.planner import bucket_rows
+    from repro.kernels import ops
+    db, _ = randwalk_db
+    queries, d = db.scenario_queries, db.scenario_d
+    real, keys = ops._query_block_jit, []
+
+    def jitted(entries, queries, d, **kw):
+        keys.append((entries.shape, queries.shape, kw["capacity"],
+                     kw["pruning"]))
+        return real(entries, queries, d, **kw)
+
+    monkeypatch.setattr(ops, "_query_block_jit", jitted)
+    many = ExecutionPolicy(batching="periodic", batch_params={"s": 16},
+                           num_bins=100)
+    shapes = {}
+    for ladder in (None, 4):
+        keys.clear()
+        res = db.query(queries, d, backend="jnp",
+                       policy=many.with_(dispatch_ladder=ladder))
+        shapes[ladder] = set(keys)
+    lengths = {b.num_candidates for b in res.plan.batches}
+    assert len({k[0] for k in shapes[None]}) == len(lengths) > 20
+    rungs = {bucket_rows(n, 256, 4) for n in lengths}
+    qrungs = {bucket_rows(b.size, 256, 4) for b in res.plan.batches}
+    caps = {k[2] for k in shapes[4]}
+    assert {k[0][0] for k in shapes[4]} == rungs
+    assert len(shapes[4]) <= len(rungs) * len(qrungs) * len(caps)
+    assert len(shapes[4]) * 4 < len(shapes[None])
+
+
+def test_box_test_sees_only_real_rows(monkeypatch):
+    """With host pad rows, the spatial box test runs on the real rows
+    alone: the same gating as unpadded, the real tiles' boxes unchanged,
+    and every tile of pad rows the empty box."""
+    from repro.kernels import ops
+    got = {}
+
+    def jitted(entries, queries, d, **kw):
+        got[entries.shape[0]] = kw
+        return {}
+
+    monkeypatch.setattr(ops, "_query_block_jit", jitted)
+    rng = np.random.default_rng(3)
+    blk = 8
+    # Two far-apart clusters of entries, queries near the first: tiles of
+    # the second cluster are skippable.
+    e = rng.uniform(0, 5, (3 * blk + 3, 8)).astype(np.float32)
+    e[2 * blk:, 0:6] += 500.0
+    e[:, 6] = 0.0
+    e[:, 7] = 1.0
+    q = e[:5].copy()
+    t_e, t_q = ops.pad_instants(1.0, q)
+    e_pad = ops.pad_block(e, 8 * blk, t_e)
+    q_pad = ops.pad_block(q, 2 * blk, t_q)
+    kw = dict(capacity=64, use_pallas=True, cand_blk=blk, qry_blk=blk,
+              compaction="fused", pruning="spatial")
+    ops.query_block(e, q, np.float32(2.0), **kw)
+    ops.query_block(e_pad, q_pad, np.float32(2.0), valid_rows=(len(e), 5),
+                    **kw)
+    exact, padded = got[len(e)], got[8 * blk]
+    assert exact["pruning"] == padded["pruning"] == "spatial"
+    nt = exact["e_mbr"].shape[0]
+    np.testing.assert_array_equal(padded["e_mbr"][:nt], exact["e_mbr"])
+    assert np.all(padded["e_mbr"][nt:, 0:3] == np.inf)
+    assert np.all(padded["e_mbr"][nt:, 3:6] == -np.inf)
+    np.testing.assert_array_equal(padded["q_mbr"][:1], exact["q_mbr"])
+    assert padded["d_prune"] == exact["d_prune"]
+    # Pad rows at the origin would have grown the last real tile's box.
+    assert exact["e_mbr"][nt - 1, 0] > 400
+    # Nothing skippable among the real tiles: the unarmed kernel, padded
+    # or not.
+    got.clear()
+    near = e[:2 * blk]
+    ops.query_block(near, q, np.float32(2.0), **kw)
+    ops.query_block(ops.pad_block(near, 4 * blk, t_e), q_pad,
+                    np.float32(2.0), valid_rows=(2 * blk, 5), **kw)
+    assert got[2 * blk]["pruning"] == got[4 * blk]["pruning"] == "none"
+
+
+def test_pad_rows_never_meet_real_rows_or_each_other():
+    from repro.kernels import ops
+    q = np.zeros((3, 8), np.float32)
+    q[:, 7] = [4.0, 9.5, 2.0]
+    t_e, t_q = ops.pad_instants(7.0, q)
+    assert t_e > 9.5 and t_q > t_e
+    block = ops.pad_block(q, 5, t_q)
+    np.testing.assert_array_equal(block[:3], q)
+    assert np.all(block[3:, 6:8] == t_q) and np.all(block[3:, :6] == 0)
+    assert ops.pad_block(q, 3, t_q) is q
