@@ -34,10 +34,12 @@ Algorithms:
 * :func:`greedysetsplit_min` / :func:`greedysetsplit_max` — Algorithm 4:
   one pass of "free" merges (merges that add zero interactions), then one
   constraint pass.  The free-merge pass is a left-to-right scan that
-  prices a look-ahead window of prefix unions per call, each under its own
-  prune threshold (as a one-row call would); the constraint pass decides
-  on sizes alone and prices its merged batches in one call.  O(|Q|) work,
-  about one pricing call per free-merge run, no per-merge array copy.
+  prices look-ahead windows of prefix unions, each under its own prune
+  threshold (as a one-row call would), in lanes cut wherever the
+  singleton price changes, every lane's window of a round in one stacked
+  call; the constraint pass decides on sizes alone and prices its merged
+  batches in one call.  O(|Q|) work, as many rounds as the longest lane
+  needs, no per-merge array copy.
 
 The SETSPLIT loops are vectorized with numpy (all adjacent-pair merge costs
 are evaluated per iteration with ``candidate_range_batch``, the stack under
@@ -343,32 +345,59 @@ def setsplit_max(index: TemporalBinIndex, queries: SegmentArray,
 # ----------------------------------------------------------------------
 # GREEDYSETSPLIT (paper §6.3, Algorithm 4)
 # ----------------------------------------------------------------------
+#: Most rows one GREEDYSETSPLIT pricing call stacks.  The coarse-grid
+#: estimate materialises ``(rows, COARSE_GRID_BINS[, K], 3)`` float64
+#: temporaries, so a round's stack of prefix unions is priced in chunks of
+#: this many rows (about 6 MB a temporary at ``level="bin"``).
+SCAN_CHUNK_ROWS = 2048
+
+
 def _greedy(index: TemporalBinIndex, queries: SegmentArray, bound: int,
             variant: str,
             counter: SpatialInteractionCounter | None = None) -> BatchPlan:
-    """Algorithm 4 as one left-to-right scan and one pass over sizes.
+    """Algorithm 4 as a scan of many lanes at once and one pass over sizes.
 
     Phase 1 (lines 4–11) merges ``B[i]`` and ``B[i+1]`` iff the merge is
     free: ``numInts(merge) == numInts(B[i]) + numInts(B[i+1])``.  The right
     neighbour of the batch being grown is always an untouched singleton,
     so the pass is a scan: from the open batch ``A = [a, j]`` it prices the
     prefix unions ``A ∪ {j+1 … k}`` of a look-ahead window of ``L``
-    segments in one call (running max of ``te``, running min/max of the
-    query MBRs), and the first ``k`` whose price is not the previous
-    prefix's plus singleton ``k``'s closes ``A`` at ``k−1``.  A window that
-    passes whole extends ``A`` and doubles ``L``; a new batch starts with
-    ``L`` twice the closed batch's size.  A scan with no free merge thus
-    makes one call per segment, long free runs O(log) calls each.
+    segments (running max of ``te``, running min/max of the query MBRs),
+    and the first ``k`` whose price is not the previous prefix's plus
+    singleton ``k``'s closes ``A`` at ``k−1``.  A window that passes whole
+    extends ``A`` and doubles ``L``; a new batch starts with ``L`` twice
+    the closed batch's size.
+
+    **Lanes.**  With a price ``c`` that is monotone in the union (the
+    temporal range only grows, the MBR gap only shrinks, the threshold
+    only grows with the coordinate scale — every pricing with
+    ``max_subranges=None``), a merge is free only if
+    ``(s+1)·c(A∪k) = s·c(A) + c(k)`` with ``c(A∪k) ≥ max(c(A), c(k))``,
+    that is only if all three are equal: a phase-1 batch never crosses a
+    change in the singleton price.  The sorted queries are therefore cut
+    into lanes at every change of the singleton price, each lane runs the
+    scan above over its own rows, and every round prices the windows of
+    all unfinished lanes in one stacked call (in chunks of
+    :data:`SCAN_CHUNK_ROWS` rows).  A lane's windows reach one row past
+    its end, which the sequential scan would price to close the lane's
+    last batch.  Where that row still merges free — a price that is not
+    monotone (the ``max_subranges`` surcharge), or singletons priced
+    under a wider shared threshold than the union — the lane absorbs the
+    next lane and scans on into it, and everything that lane found is
+    dropped; its first row was no batch boundary.  So the plan never rests
+    on monotonicity, which only decides how wide the scan goes.
 
     Each prefix union is priced under its own prune threshold
     (:meth:`SpatialInteractionCounter.row_counts`), as the merge-by-merge
-    loop's one-row calls priced it; the singletons are priced in one
-    stacked call, as that loop's initial state was.  Phase 2 (lines
-    13–20) decides on sizes alone, so it runs over the phase-1 sizes and
-    prices only the batches it merged, in one call.  The plan is the
-    merge-by-merge loop's, batch for batch.  The spans counters
-    ``plan_price_calls`` and ``plan_price_rows`` count the pricing calls
-    and the rows they priced.
+    loop's one-row calls priced it, whatever it is stacked with; the
+    singletons are priced in one stacked call, as that loop's initial
+    state was.  Phase 2 (lines 13–20) decides on sizes alone, so it runs
+    over the phase-1 sizes and prices only the batches it merged.  The
+    plan is the merge-by-merge loop's, batch for batch.  The spans
+    counters ``plan_price_calls`` and ``plan_price_rows`` count the pricing
+    calls (chunks included) and the rows they priced; ``plan_scan_lanes``,
+    ``plan_scan_rounds`` and ``plan_scan_restarts`` the lanes formed, the
+    rounds run and the lanes absorbed.
     """
     t_begin = time.perf_counter()
     if not queries.is_sorted():
@@ -383,11 +412,15 @@ def _greedy(index: TemporalBinIndex, queries: SegmentArray, bound: int,
     def price(qt0, qt1, lo, hi, size) -> np.ndarray:
         """numInts of batches, each under its own prune threshold."""
         nonlocal calls, rows
-        calls += 1
         rows += len(qt1)
-        if counter is None:
-            return size * index.num_candidates_batch(qt0, qt1)
-        return size * counter.row_counts(qt0, qt1, lo, hi)
+        out = np.empty(len(qt1), np.int64)
+        for c in range(0, len(qt1), SCAN_CHUNK_ROWS):
+            s = slice(c, c + SCAN_CHUNK_ROWS)
+            calls += 1
+            out[s] = (index.num_candidates_batch(qt0[s], qt1[s])
+                      if counter is None
+                      else counter.row_counts(qt0[s], qt1[s], lo[s], hi[s]))
+        return size * out
 
     if counter is not None:
         qlo, qhi = counter.qlo, counter.qhi
@@ -396,34 +429,78 @@ def _greedy(index: TemporalBinIndex, queries: SegmentArray, bound: int,
         qlo = qhi = np.zeros((nq, 3))
         single = index.num_candidates_batch(ts, te)
 
-    # Phase 1: the open batch is [a, j]; closed batches go to the lists.
-    starts: list[int] = []
-    ints: list[int] = []
-    a = j = 0
-    a_ints, a_t1, a_lo, a_hi = int(single[0]), te[0], qlo[0], qhi[0]
-    width = 2
-    while j < nq - 1:
-        win = slice(j + 1, min(j + width, nq - 1) + 1)
-        t1 = np.maximum(np.maximum.accumulate(te[win]), a_t1)
-        lo = np.minimum(np.minimum.accumulate(qlo[win]), a_lo)
-        hi = np.maximum(np.maximum.accumulate(qhi[win]), a_hi)
-        size = np.arange(j - a + 2, win.stop - a + 1)
-        merged = price(np.full(len(t1), ts[a]), t1, lo, hi, size)
-        prev = np.r_[a_ints, merged[:-1]]
-        free = merged == prev + single[win]
-        if free.all():
-            j = win.stop - 1
-            a_ints, a_t1, a_lo, a_hi = int(merged[-1]), t1[-1], lo[-1], hi[-1]
-            width *= 2
-            continue
-        f = int(np.argmin(free))
-        starts.append(a)
-        ints.append(int(prev[f]))
-        width = 2 * (j + f + 1 - a)
-        a = j = j + f + 1
-        a_ints, a_t1, a_lo, a_hi = int(single[a]), te[a], qlo[a], qhi[a]
-    starts.append(a)
-    ints.append(a_ints)
+    # A prefix union's te, upper MBR corner and negated lower corner are
+    # running maxima, taken over integer ranks into their sorted values:
+    # each round lifts every lane's ranks clear of the lanes before it, so
+    # one flat accumulate never carries from one window into the next.
+    cols = np.c_[te, qhi, -qlo]
+    vals, rank = np.unique(cols, return_inverse=True)
+    rank = rank.reshape(nq, 7)
+
+    # Phase 1: lane i holds the open batch [a[i], j[i]], whose price is
+    # a_ints[i] and running maxima run[i]; its windows reach row lim[i],
+    # one past its end (-1 once absorbed), and nxt[i] is the lane after
+    # it.  Closed batches are kept with their lane, so that an absorbed
+    # lane's can be dropped.
+    a = np.r_[0, np.flatnonzero(np.diff(single)) + 1]
+    end = np.r_[a[1:], nq] - 1
+    lim = np.minimum(end + 1, nq - 1)
+    j = a.copy()
+    a_ints, run = single[a].astype(np.int64), cols[a]
+    width = np.full(len(a), 2)
+    nxt = np.arange(1, len(a) + 1)
+    closed: list[tuple[np.ndarray, np.ndarray, np.ndarray]] = []
+    rounds = restarts = 0
+    live = np.flatnonzero(j < lim)
+    while live.size:
+        rounds += 1
+        jl, al = j[live], a[live]
+        n = np.minimum(jl + width[live], lim[live]) - jl   # at least 1
+        stop = np.cumsum(n)               # flat end of each window
+        head = stop - n                   # and its first flat row
+        flat = np.arange(stop[-1])
+        k = flat + np.repeat(jl + 1 - head, n)
+        lane = np.repeat(live, n)
+        a0 = a[lane]
+        lift = (lane * len(vals))[:, None]
+        acc = np.maximum(
+            vals[np.maximum.accumulate(rank[k] + lift, axis=0) - lift],
+            run[lane])
+        merged = price(ts[a0], acc[:, 0], -acc[:, 4:], acc[:, 1:4],
+                       k - a0 + 1)
+        prev = np.empty_like(merged)
+        prev[1:] = merged[:-1]
+        prev[head] = a_ints[live]
+        # Each window's first merge that is not free, or its end.
+        pos = np.minimum(np.minimum.reduceat(
+            np.where(merged == prev + single[k], stop[-1], flat), head), stop)
+        close = pos < stop                # row k[pos] starts a new batch
+        jn = jl + pos - head              # the open batch's last row
+        row = jn + close                  # the next j, a new batch's start
+        closed.append((live[close], al[close], prev[pos[close]]))
+        a[live] = np.where(close, row, al)
+        j[live] = row
+        width[live] = 2 * np.where(close, row - al, width[live])
+        a_ints[live] = np.where(close, single[row], merged[pos - 1])
+        run[live] = np.where(close[:, None], cols[row], acc[pos - 1])
+        # The batch merged free into the next lane's first row: take that
+        # lane over, last lane first so that chains of crossings compose.
+        for i in live[jn > end[live]][::-1]:
+            restarts += 1
+            end[i], lim[i] = end[nxt[i]], lim[nxt[i]]
+            lim[nxt[i]] = -1
+            nxt[i] = nxt[nxt[i]]
+        live = np.flatnonzero(j < lim)
+    # The open last batch, and every closed one of a lane not absorbed.
+    tail = np.flatnonzero((end == nq - 1) & (lim >= 0))
+    closed.append((tail, a[tail], a_ints[tail]))
+    lanes, starts, ints = (np.concatenate(x) for x in zip(*closed))
+    keep = lim[lanes] >= 0
+    order = np.argsort(starts[keep])
+    starts, ints = starts[keep][order], ints[keep][order]
+    spans.count("plan_scan_lanes", len(a))
+    spans.count("plan_scan_rounds", rounds)
+    spans.count("plan_scan_restarts", restarts)
 
     # Phase 2: merge batch i into its successors while it is under the
     # bound ("min"), or has not yet exceeded it ("max": the bound is soft,

@@ -9,7 +9,7 @@ from _hypothesis_compat import strategies as st
 
 import repro
 from conftest import random_segments
-from repro.core import batching
+from repro.core import batching, spans
 from repro.core.index import TemporalBinIndex
 from repro.core.segments import SegmentArray
 from repro.data import trajgen
@@ -229,8 +229,19 @@ def _single_world():
     return random_segments(rng, 200), random_segments(rng, 1), 2.0
 
 
+def _skewed_world():
+    """RANDWALK-EXP in small (``bench/datasets/randwalk_exp.py``'s
+    distributions): 150 walks of exponential length starting over [0, 20],
+    the queries ten of them, d = 50.  Activity decays with time, so the
+    singleton price changes every few queries: the scan runs many lanes."""
+    db = trajgen.randwalk_exp(num_traj=150, seed=7).segments.sort_by_tstart()
+    rows = np.nonzero(np.isin(db.traj_id, np.unique(db.traj_id)[::15]))[0]
+    return db, db.take(rows), 50.0
+
+
 WORLDS = {"galaxy": _galaxy_world, "randwalk": _randwalk_world,
-          "wide": _wide_world, "single": _single_world}
+          "wide": _wide_world, "single": _single_world,
+          "skewed": _skewed_world}
 #: (level, max_subranges); level None prices the temporal-only count.
 PRICINGS = [(None, None), ("bin", None), ("bin", 2), ("box", None),
             ("box", 2)]
@@ -258,6 +269,80 @@ def test_greedy_scan_matches_merge_loop(world, level, cap, bound, variant):
     got = batching.ALGORITHMS[f"greedysetsplit-{variant}"](
         index, queries, bound, counter=counter)
     assert _batches(got) == _batches(want)
+
+
+class _MidrangeCounter:
+    """Prices a batch at the sum of its MBR's lowest and highest x, each
+    row on its own: not monotone in the union, so queries at x = 5 and
+    x = 15 (singletons 10 and 30) merge free at 20 each, across a change
+    of the singleton price."""
+
+    def __init__(self, queries):
+        self.qlo, self.qhi = queries.mbrs()
+
+    def row_counts(self, qt0, qt1, lo, hi):
+        return (np.asarray(lo)[:, 0] + np.asarray(hi)[:, 0]).astype(np.int64)
+
+    counts = row_counts
+
+
+def _midrange_queries(xs):
+    x = np.asarray(xs, np.float32)
+    n = len(x)
+    ts = np.arange(n, dtype=np.float32)
+    z = np.zeros(n, np.float32)
+    return SegmentArray(x, z, z.copy(), x.copy(), z.copy(), z.copy(), ts,
+                        ts + 0.5, np.arange(n, dtype=np.int32),
+                        np.zeros(n, np.int32))
+
+
+@pytest.mark.parametrize("variant", ["min", "max"])
+def test_greedy_lane_takes_over_the_next_on_a_free_crossing(variant):
+    """A batch that merges free across a change of the singleton price
+    makes its lane absorb the next one; the plan stays the merge loop's."""
+    rng = np.random.default_rng(21)
+    index = TemporalBinIndex.build(random_segments(rng, 200), num_bins=16)
+    queries = _midrange_queries(
+        [5, 15, 10, 10, 3, 7, 5, 2, 4, 6, 9, 1, 11, 6, 6, 8]
+        + rng.integers(0, 12, 48).tolist())
+    counter = _MidrangeCounter(queries)
+    want = _greedy_reference(index, queries, 3, variant, counter)
+    with spans.recording() as rec:
+        got = batching.ALGORITHMS[f"greedysetsplit-{variant}"](
+            index, queries, 3, counter=counter)
+    assert _batches(got) == _batches(want)
+    assert rec.counts["plan_scan_restarts"] >= 1
+
+
+def test_skewed_world_scans_its_lanes_together():
+    """On the skewed world every lane ends where its batch does (no
+    restart), and the lanes go together: fewer pricing calls than lanes."""
+    index, queries, counter = _plan_inputs("skewed", "bin", None)
+    with spans.recording() as rec:
+        batching.greedysetsplit_min(index, queries, 7, counter=counter)
+    c = rec.counts
+    assert c["plan_scan_lanes"] >= 10
+    assert c["plan_scan_restarts"] == 0
+    assert c["plan_price_calls"] < c["plan_scan_lanes"]
+
+
+@pytest.mark.parametrize("world,level,cap", [("skewed", "bin", None),
+                                             ("wide", "box", 2),
+                                             ("randwalk", None, None)])
+def test_greedy_prices_the_same_in_chunks(monkeypatch, world, level, cap):
+    """A round's stack priced in chunks of a few rows gives the plan it
+    gives priced whole."""
+    index, queries, counter = _plan_inputs(world, level, cap)
+    with spans.recording() as whole:
+        want = batching.greedysetsplit_max(index, queries, 7, counter=counter)
+    monkeypatch.setattr(batching, "SCAN_CHUNK_ROWS", 3)
+    with spans.recording() as chunked:
+        got = batching.greedysetsplit_max(index, queries, 7, counter=counter)
+    assert _batches(got) == _batches(want)
+    assert (chunked.counts["plan_price_rows"]
+            == whole.counts["plan_price_rows"])
+    assert (chunked.counts["plan_price_calls"]
+            > whole.counts["plan_price_calls"])
 
 
 def test_wide_world_prices_prefixes_under_their_own_limits():
@@ -342,8 +427,8 @@ def test_setsplit_and_periodic_plans_unchanged(world, level, cap, name, kw):
 
 def test_plan_counts_its_pricing_calls():
     """``db.query`` leaves the scan's pricing calls and rows on
-    ``ExecStats.counts``: about one call per phase-1 batch on GALAXY's
-    free runs, well under one per query segment."""
+    ``ExecStats.counts``: a few stacked calls for GALAXY's 41 lanes,
+    well under one per phase-1 batch or per query segment."""
     db, queries, d = _galaxy_world()
     pol = repro.ExecutionPolicy(num_bins=48, pruning="spatial")
     tdb = repro.TrajectoryDB.from_segments(db, policy=pol)
