@@ -4,7 +4,7 @@ jax/Pallas system.
 
 The stable public surface is :mod:`repro.api` — ``TrajectoryDB`` and
 friends are re-exported lazily here so that ``import repro`` stays cheap
-for subpackages (``repro.data``, ``repro.models``, …) that never touch the
+for subpackages (``repro.data``, ``repro.lint``, …) that never touch the
 query engine.
 """
 from __future__ import annotations

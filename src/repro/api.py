@@ -141,8 +141,7 @@ class ExecutionPolicy:
     #: Pallas interpret mode; None → from the device's platform
     #: (``distthresh.resolve_interpret``: interpreted only on a CPU)
     interpret: bool | None = None
-    compaction: str = "fused"             # "fused" in-kernel | "fused_rowloop"
-    #                                       gather-free hatch | "dense" 2-phase
+    compaction: str = "fused"             # "fused" in-kernel | "dense" 2-phase
     pipeline: bool = True                 # async 2-phase executor (O(1) syncs)
     #: executor dispatch groups per query set (None → one group = classic
     #: O(1)-sync shape; k → marshalling of group i overlaps compute of i+1)
@@ -529,8 +528,7 @@ class TrajectoryDB:
             compaction = pol.compaction if use_pallas else "dense"
             # kernel-level pruning exists only on the fused Pallas path
             # (mirrors ShardedEngine.__init__'s normalization)
-            pruning = (pol.pruning if use_pallas
-                       and compaction in ("fused", "fused_rowloop")
+            pruning = (pol.pruning if use_pallas and compaction == "fused"
                        else "none")
             # pol.pruning itself (not just the kernel-effective value)
             # shapes construction too: hierarchical builds the pod-local
@@ -695,8 +693,8 @@ class TrajectoryDB:
         the returned ``QueryResult.query_idx`` is mapped back to the
         caller's order.  ``batching``/``**batch_params`` are shorthand for a
         one-off policy override (e.g. ``batching="periodic", s=48``), as are
-        ``compaction=`` ("fused" in-kernel vs "fused_rowloop" gather-free vs
-        "dense" two-phase result compaction), ``pipeline=`` (async
+        ``compaction=`` ("fused" in-kernel vs "dense" two-phase result
+        compaction), ``pipeline=`` (async
         O(1)-sync executor vs per-batch sync loop) and ``pruning=``
         ("hierarchical" K-box sub-ranges + device-side live-tile dispatch,
         "spatial" bin-level candidate pruning, or "none" — all three give

@@ -598,8 +598,7 @@ class ShardedEngine:
                 self._packed_perm = self._packed[self._perm]
         # Kernel-level tile pruning only exists on the fused Pallas path;
         # normalizing here keeps the jit-cache key honest.
-        self.pruning = (pruning if use_pallas
-                        and compaction in ("fused", "fused_rowloop")
+        self.pruning = (pruning if use_pallas and compaction == "fused"
                         else "none")
         self._t_end = float(self.db.temporal_extent[1])
         self._fns: dict[int, object] = {}

@@ -198,10 +198,9 @@ class DistanceThresholdEngine:
         interpreter on a CPU, the compiled kernel on a TPU.
 
         ``compaction`` selects the result-compaction strategy ("fused" uses
-        the in-kernel compaction kernel on the Pallas path; "fused_rowloop"
-        its interpret-only per-row variant — see ``repro.kernels.ops``;
-        "dense" forces the two-phase pass; the jnp oracle is always
-        dense).  ``pipeline`` selects the async
+        the in-kernel compaction kernel on the Pallas path — see
+        ``repro.kernels.ops``; "dense" forces the two-phase pass; the jnp
+        oracle is always dense).  ``pipeline`` selects the async
         two-phase executor (see the module docstring); both can be
         overridden per call on :meth:`execute`.
 

@@ -1,1 +1,1 @@
-from repro.data import trajgen, tokens  # noqa: F401
+from repro.data import trajgen  # noqa: F401

@@ -39,21 +39,13 @@ Two kernels share the interval math (:func:`_interval_math`):
   Non-hits never touch HBM, and the exact total hit count comes back with
   the results, so overflow detection needs no dense pass.
 
-The fused kernel has two append strategies (``append=``):
-
-* ``"chunk"`` — compiles for the TPU.  Hits are located 128 result slots
-  at a time with one-hot matrix products on the MXU (prefix sums against
-  triangular ones matrices, row/column selection, and an exact three-way
-  bf16 split for the f32 segment gathers — see :func:`_chunk_tile_body`),
-  the hit pairs' intervals are recomputed on the gathered segments, and
-  each 128-slot window is written with a lane rotation into two buffer
-  rows (:func:`_append_window`).
-* ``"rowloop"`` — a per-row ``fori_loop`` append over the dense tile
-  intervals, kept as an interpret-mode cross-check of the chunk path's
-  order.  It does not lower for the TPU (dynamic value slices and
-  reductions to 1-D vectors) and raises when asked to compile.
-
-Both emit the identical deterministic order.
+The fused kernel's append works in chunks: hits are located 128 result
+slots at a time with one-hot matrix products on the MXU (prefix sums
+against triangular ones matrices, row/column selection, and an exact
+three-way bf16 split for the f32 segment gathers — see
+:func:`_chunk_tile_body`), the hit pairs' intervals are recomputed on the
+gathered segments, and each 128-slot window is written with a lane
+rotation into two buffer rows (:func:`_append_window`).
 
 Both fused kernels optionally take a **tile-level spatial early-out**
 (PR 5, the device half of the two-level pruning subsystem — the host half
@@ -282,19 +274,10 @@ def distthresh_pallas(entries: jnp.ndarray, queries_t: jnp.ndarray, d,
 # ----------------------------------------------------------------------
 # Fused in-kernel compaction (the §5 atomic_inc analogue, sequential grid)
 # ----------------------------------------------------------------------
-def _window_width(append: str, qry_blk: int) -> int:
-    """Slots one append writes from its start: one APPEND_BLK window for
-    the chunk path, a whole tile row (in APPEND_BLK windows) for rowloop."""
-    if append == "rowloop":
-        return -(-qry_blk // APPEND_BLK) * APPEND_BLK
-    return APPEND_BLK
-
-
-def _buffer_rows(capacity: int, width: int) -> int:
-    """Rows of a ``(rows, APPEND_BLK)`` result plane: an append may start
-    at any slot ``<= capacity`` and writes ``width`` slots, its last
-    APPEND_BLK window straddling two rows."""
-    return (capacity + width) // APPEND_BLK + 1
+def _buffer_rows(capacity: int) -> int:
+    """Rows of a ``(rows, APPEND_BLK)`` result plane: an append window may
+    start at any slot ``<= capacity`` and straddles two rows."""
+    return (capacity + APPEND_BLK) // APPEND_BLK + 1
 
 
 def _init_buffers(bufs, count_ref, pruned_ref=None):
@@ -453,74 +436,8 @@ def _chunk_tile_body(i, j, d_ref, entries_ref, queries_t_ref, bufs,
                           _window, 0)
 
 
-def _rowloop_tile_body(i, j, d_ref, entries_ref, queries_t_ref, bufs,
-                       count_ref, *, cand_blk: int, qry_blk: int,
-                       capacity: int, valid_c: int, valid_q: int):
-    """Evaluate tile (i, j) and row-append its hits (shared by the
-    full-grid and live-tile kernels; interpret mode only)."""
-    e_blk = entries_ref[...]
-    q_blk = queries_t_ref[...]
-    d = d_ref[0, 0]
-    t_enter, t_exit, _ = _tile_intervals(e_blk, q_blk, d)
-    hit2 = _hit_mask(i, j, d, e_blk, q_blk, cand_blk=cand_blk,
-                     qry_blk=qry_blk, valid_c=valid_c, valid_q=valid_q)
-
-    hit_i = hit2.astype(jnp.int32)
-    row_cum = jnp.cumsum(hit_i, axis=1)      # (cand_blk, qry_blk)
-    offset = count_ref[0, 0]
-
-    # Per-slot and per-column index planes shared by every row
-    # iteration.
-    slot_plane = jax.lax.broadcasted_iota(jnp.int32,
-                                          (qry_blk, qry_blk), 0)
-    col_plane = jax.lax.broadcasted_iota(jnp.int32,
-                                         (qry_blk, qry_blk), 1)
-    slot_vec = jax.lax.broadcasted_iota(jnp.int32, (qry_blk, 1), 0)[:, 0]
-    zero = jnp.zeros((), bufs[2].dtype)
-    width = _window_width("rowloop", qry_blk)
-    n_win = width // APPEND_BLK
-
-    def _windows(v, pad):
-        v = jnp.pad(v, (0, width - qry_blk), constant_values=pad)
-        return [v[w * APPEND_BLK:(w + 1) * APPEND_BLK][None, :]
-                for w in range(n_win)]
-
-    def _row_body(r, dst):
-        rh = jax.lax.dynamic_slice(hit_i, (r, 0), (1, qry_blk))
-        rcum = jax.lax.dynamic_slice(row_cum, (r, 0), (1, qry_blk))
-        rent = jax.lax.dynamic_slice(t_enter, (r, 0), (1, qry_blk))
-        rext = jax.lax.dynamic_slice(t_exit, (r, 0), (1, qry_blk))
-        n_r = rcum[0, qry_blk - 1]
-        # sel[s, c] = 1 iff column c is the row's (s+1)-th hit:
-        # compaction becomes a masked reduction over columns — no
-        # gathers anywhere.
-        sel = (rcum == slot_plane + 1) & (rh > 0)
-        sel_f = sel.astype(rent.dtype)
-        comp_col = jnp.sum(jnp.where(sel, col_plane, 0), axis=1)
-        comp_ent = jnp.sum(sel_f * rent, axis=1)
-        comp_ext = jnp.sum(sel_f * rext, axis=1)
-        valid = slot_vec < n_r
-        planes = (
-            (jnp.where(valid, i * cand_blk + r, -1).astype(jnp.int32), -1),
-            (jnp.where(valid, j * qry_blk + comp_col,
-                       -1).astype(jnp.int32), -1),
-            (jnp.where(valid, comp_ent, zero), zero),
-            (jnp.where(valid, comp_ext, zero), zero))
-
-        @pl.when((n_r > 0) & (dst <= capacity))  # overflow: drop,
-        def _():                                  # keep count
-            for ref, (vals, pad) in zip(bufs, planes):
-                for w, win in enumerate(_windows(vals, pad)):
-                    _append_window(ref, win, dst + w * APPEND_BLK)
-
-        return dst + n_r
-
-    end = jax.lax.fori_loop(0, cand_blk, _row_body, offset)
-    count_ref[0, 0] = end
-
-
 def _distthresh_compact_kernel(d_ref, entries_ref, queries_t_ref, *refs,
-                               body, cand_blk: int, qry_blk: int,
+                               cand_blk: int, qry_blk: int,
                                capacity: int, valid_c: int, valid_q: int,
                                prune: bool):
     """One grid step: evaluate a tile, append its hits at the running offset.
@@ -555,9 +472,9 @@ def _distthresh_compact_kernel(d_ref, entries_ref, queries_t_ref, *refs,
         _init_buffers(bufs, count_ref, pruned_ref)
 
     def _body():
-        body(i, j, d_ref, entries_ref, queries_t_ref, bufs, count_ref,
-             cand_blk=cand_blk, qry_blk=qry_blk, capacity=capacity,
-             valid_c=valid_c, valid_q=valid_q)
+        _chunk_tile_body(i, j, d_ref, entries_ref, queries_t_ref, bufs,
+                         count_ref, cand_blk=cand_blk, qry_blk=qry_blk,
+                         capacity=capacity, valid_c=valid_c, valid_q=valid_q)
 
     if not prune:
         _body()
@@ -592,28 +509,12 @@ def _tile_mbr_live(embr_ref, qmbr_ref, dprune_ref, i, j):
     return gap2 <= dp * dp
 
 
-#: append strategies accepted by :func:`distthresh_compact_pallas`.
-APPEND_MODES = ("chunk", "rowloop")
-
-
-def _append_body(append: str, interpret: bool, cand_blk: int, qry_blk: int):
-    """The tile body for ``append`` — validated, and refused where it
-    cannot run (an explicit error, never a silent switch of strategy)."""
-    if append not in APPEND_MODES:
-        raise ValueError(f"unknown append mode {append!r}; "
-                         f"choose from {APPEND_MODES}")
-    if append == "rowloop":
-        if not interpret:
-            raise NotImplementedError(
-                "append='rowloop' (compaction='fused_rowloop') does not "
-                "lower for the TPU; it runs only in interpret mode. Use "
-                "compaction='fused' (append='chunk') on the chip.")
-        return _rowloop_tile_body
+def _check_fused_tile(cand_blk: int, qry_blk: int):
+    """Refuse a tile the chunk append cannot rank exactly."""
     if max(cand_blk, qry_blk) > MAX_FUSED_BLK:
-        raise ValueError(f"append='chunk' supports tiles up to "
+        raise ValueError(f"the fused kernel supports tiles up to "
                          f"{MAX_FUSED_BLK}×{MAX_FUSED_BLK}; got "
                          f"{cand_blk}×{qry_blk}")
-    return _chunk_tile_body
 
 
 def _result_planes(rows: int, dtype, index_map):
@@ -642,8 +543,7 @@ def _flat(plane, capacity: int):
 
 
 @functools.partial(jax.jit, static_argnames=(
-    "capacity", "cand_blk", "qry_blk", "valid_c", "valid_q", "interpret",
-    "append"))
+    "capacity", "cand_blk", "qry_blk", "valid_c", "valid_q", "interpret"))
 def distthresh_compact_pallas(entries: jnp.ndarray, queries_t: jnp.ndarray, d,
                               *, capacity: int,
                               cand_blk: int = DEFAULT_CAND_BLK,
@@ -651,7 +551,6 @@ def distthresh_compact_pallas(entries: jnp.ndarray, queries_t: jnp.ndarray, d,
                               valid_c: int | None = None,
                               valid_q: int | None = None,
                               interpret: bool | None = None,
-                              append: str = "chunk",
                               e_mbr: jnp.ndarray | None = None,
                               q_mbr: jnp.ndarray | None = None,
                               d_prune=None):
@@ -667,10 +566,6 @@ def distthresh_compact_pallas(entries: jnp.ndarray, queries_t: jnp.ndarray, d,
         or beyond them are masked out of the result.  Default: all.
       interpret: Pallas interpret mode; ``None`` resolves from the default
         device (:func:`resolve_interpret`).
-      append: ``"chunk"`` — one-hot MXU rank selection in APPEND_BLK-slot
-        windows (the path that compiles for the TPU).  ``"rowloop"`` — the
-        per-row append loop, interpret mode only (same results, same
-        determinism).
       e_mbr / q_mbr / d_prune: the tile-level spatial early-out (PR 5).
         ``e_mbr`` is (C/cand_blk, 8) — per entry tile ``(lo_xyz, hi_xyz,
         0, 0)`` — and ``q_mbr`` (Q/qry_blk, 8) the same per query tile,
@@ -685,12 +580,12 @@ def distthresh_compact_pallas(entries: jnp.ndarray, queries_t: jnp.ndarray, d,
     four (capacity,) buffers — int32 indices (-1 pad) and interval
     endpoints (0 pad) — plus the exact scalar int32 hit count and the
     number of grid tiles the MBR early-out skipped (0 without pruning
-    inputs).  Output order is deterministic (and identical across append
-    modes *and* pruning on/off — pruned tiles contribute no rows): tiles
-    in grid order (query tiles innermost), row-major within each tile.
+    inputs).  Output order is deterministic (and identical with pruning on
+    and off — pruned tiles contribute no rows): tiles in grid order (query
+    tiles innermost), row-major within each tile.
     """
     interpret = resolve_interpret(interpret)
-    body = _append_body(append, interpret, cand_blk, qry_blk)
+    _check_fused_tile(cand_blk, qry_blk)
     prune = e_mbr is not None
     if (q_mbr is None) == prune or (d_prune is None) == prune:
         raise ValueError("e_mbr, q_mbr and d_prune must be given together "
@@ -706,7 +601,7 @@ def distthresh_compact_pallas(entries: jnp.ndarray, queries_t: jnp.ndarray, d,
     dtype = entries.dtype
     d_arr = jnp.asarray(d, dtype).reshape(1, 1)
 
-    rows = _buffer_rows(capacity, _window_width(append, qry_blk))
+    rows = _buffer_rows(capacity)
     out_specs, out_shapes = _result_planes(rows, dtype,
                                            lambda i, j: (0, 0))
     in_specs = [
@@ -721,9 +616,8 @@ def distthresh_compact_pallas(entries: jnp.ndarray, queries_t: jnp.ndarray, d,
                  jnp.asarray(q_mbr, jnp.float32).reshape(-1),
                  jnp.asarray(d_prune, jnp.float32).reshape(1, 1)]
     kernel = functools.partial(
-        _distthresh_compact_kernel, body=body, cand_blk=cand_blk,
-        qry_blk=qry_blk, capacity=capacity, valid_c=valid_c,
-        valid_q=valid_q, prune=prune)
+        _distthresh_compact_kernel, cand_blk=cand_blk, qry_blk=qry_blk,
+        capacity=capacity, valid_c=valid_c, valid_q=valid_q, prune=prune)
     e_idx, q_idx, t_enter, t_exit, count, pruned = pl.pallas_call(
         kernel,
         grid=grid,
@@ -743,7 +637,7 @@ def distthresh_compact_pallas(entries: jnp.ndarray, queries_t: jnp.ndarray, d,
 # ----------------------------------------------------------------------
 def _distthresh_compact_live_kernel(ti_ref, tj_ref, nlive_ref, d_ref,
                                     entries_ref, queries_t_ref, *refs,
-                                    body, cand_blk: int, qry_blk: int,
+                                    cand_blk: int, qry_blk: int,
                                     capacity: int, valid_c: int,
                                     valid_q: int):
     """One live-list slot: evaluate tile ``(ti[s], tj[s])`` if the slot is
@@ -755,8 +649,8 @@ def _distthresh_compact_live_kernel(ti_ref, tj_ref, nlive_ref, d_ref,
     bounds).  The same scalar refs drive the entry/query BlockSpec index
     maps, so the pipeline fetches exactly the live tiles' blocks — a dead
     tile never leaves HBM.  Because the list is sorted in grid order
-    (query tiles innermost) and the append bodies are shared with the
-    full-grid kernels, the output rows are byte-identical to theirs.
+    (query tiles innermost) and the append body is shared with the
+    full-grid kernel, the output rows are byte-identical to that kernel's.
     """
     *bufs, count_ref = refs
     s = pl.program_id(0)
@@ -767,14 +661,14 @@ def _distthresh_compact_live_kernel(ti_ref, tj_ref, nlive_ref, d_ref,
 
     @pl.when(s < nlive_ref[0])
     def _run():
-        body(ti_ref[s], tj_ref[s], d_ref, entries_ref, queries_t_ref, bufs,
-             count_ref, cand_blk=cand_blk, qry_blk=qry_blk,
-             capacity=capacity, valid_c=valid_c, valid_q=valid_q)
+        _chunk_tile_body(ti_ref[s], tj_ref[s], d_ref, entries_ref,
+                         queries_t_ref, bufs, count_ref, cand_blk=cand_blk,
+                         qry_blk=qry_blk, capacity=capacity, valid_c=valid_c,
+                         valid_q=valid_q)
 
 
 @functools.partial(jax.jit, static_argnames=(
-    "capacity", "cand_blk", "qry_blk", "valid_c", "valid_q", "interpret",
-    "append"))
+    "capacity", "cand_blk", "qry_blk", "valid_c", "valid_q", "interpret"))
 def distthresh_compact_live_pallas(entries: jnp.ndarray,
                                    queries_t: jnp.ndarray, d,
                                    tile_i: jnp.ndarray, tile_j: jnp.ndarray,
@@ -783,8 +677,7 @@ def distthresh_compact_live_pallas(entries: jnp.ndarray,
                                    qry_blk: int = DEFAULT_QRY_BLK,
                                    valid_c: int | None = None,
                                    valid_q: int | None = None,
-                                   interpret: bool | None = None,
-                                   append: str = "chunk"):
+                                   interpret: bool | None = None):
     """Fused compaction kernel driven by a precomputed live-tile list.
 
     Where :func:`distthresh_compact_pallas` walks the full
@@ -807,7 +700,7 @@ def distthresh_compact_live_pallas(entries: jnp.ndarray,
         skipped but still prefetched.
       n_live: (1,) int32 count of live slots (``<= S``).  Traced, so one
         compiled kernel serves every list that fits the same padded ``S``.
-      capacity / valid_c / valid_q / interpret / append: as in
+      capacity / valid_c / valid_q / interpret: as in
         :func:`distthresh_compact_pallas`.
 
     Returns ``(entry_idx, query_idx, t_enter, t_exit, count)``; no
@@ -816,7 +709,7 @@ def distthresh_compact_live_pallas(entries: jnp.ndarray,
     list is in grid order and pruned tiles contribute no rows).
     """
     interpret = resolve_interpret(interpret)
-    body = _append_body(append, interpret, cand_blk, qry_blk)
+    _check_fused_tile(cand_blk, qry_blk)
     cc, eight = entries.shape
     assert eight == 8, entries.shape
     eight2, qq = queries_t.shape
@@ -830,13 +723,12 @@ def distthresh_compact_live_pallas(entries: jnp.ndarray,
     dtype = entries.dtype
     d_arr = jnp.asarray(d, dtype).reshape(1, 1)
 
-    rows = _buffer_rows(capacity, _window_width(append, qry_blk))
+    rows = _buffer_rows(capacity)
     out_specs, out_shapes = _result_planes(
         rows, dtype, lambda s, ti, tj, nl: (0, 0))
     kernel = functools.partial(
-        _distthresh_compact_live_kernel, body=body, cand_blk=cand_blk,
-        qry_blk=qry_blk, capacity=capacity, valid_c=valid_c,
-        valid_q=valid_q)
+        _distthresh_compact_live_kernel, cand_blk=cand_blk, qry_blk=qry_blk,
+        capacity=capacity, valid_c=valid_c, valid_q=valid_q)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,          # tile_i, tile_j, n_live
         grid=(n_slots,),

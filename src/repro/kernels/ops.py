@@ -10,7 +10,7 @@ Two layers:
   buffers plus the true hit count, so the caller can detect overflow and
   retry with a larger capacity (mirroring the paper's §5 re-attempt note).
 
-``query_block`` has three compaction strategies (``compaction=``):
+``query_block`` has two compaction strategies (``compaction=``):
 
 * ``"fused"`` (default on the Pallas path) — the hits are compacted *inside*
   the kernel (``distthresh_compact_pallas``): a running counter carried
@@ -18,11 +18,8 @@ Two layers:
   counter, and each tile appends its masked-prefix-sum-compacted hits
   directly into the flat result buffers.  Per-interaction HBM traffic is
   zero for non-hits, and the exact count comes back with the results.
-* ``"fused_rowloop"`` — the same fused kernel with the per-row append
-  loop (``append="rowloop"``).  Identical results and output order; an
-  interpret-mode cross-check only — it does not lower for the TPU and
-  raises there.  No strategy ever stands in for another: a kernel that
-  fails to lower raises to the caller.
+  A kernel that fails to lower raises to the caller; the other strategy
+  never stands in for it.
 * ``"dense"`` — the two-pass fallback (and the only strategy for the jnp
   oracle path): the dense tile's hit mask and intervals are materialized,
   then compacted with an XLA cumsum + scatter.  Kept as the validation
@@ -59,7 +56,7 @@ from repro.kernels.distthresh import (DEFAULT_CAND_BLK, DEFAULT_QRY_BLK,
                                       distthresh_pallas)
 
 #: compaction strategies accepted by :func:`query_block`.
-COMPACTIONS = ("fused", "fused_rowloop", "dense")
+COMPACTIONS = ("fused", "dense")
 
 #: pruning strategies accepted by :func:`query_block`: ``"spatial"``
 #: (PR 5) arms the fused kernels' tile-level MBR early-out — a box test
@@ -368,10 +365,9 @@ def query_block(entries: jnp.ndarray, queries: jnp.ndarray, d, *,
 
     ``compaction="fused"`` routes through the in-kernel compaction kernel
     when ``use_pallas`` is set (the jnp oracle has no kernel to fuse into,
-    so it always uses the dense two-phase pass).  ``"fused_rowloop"``
-    selects the per-row append variant (interpret mode only); ``"dense"``
-    forces the two-phase pass.  A kernel that fails to lower raises; no
-    other strategy is tried.  All orders are deterministic; see the module
+    so it always uses the dense two-phase pass); ``"dense"`` forces the
+    two-phase pass.  A kernel that fails to lower raises; no other
+    strategy is tried.  All orders are deterministic; see the module
     docstring for how they differ.  ``interpret=None`` resolves from the
     device (``distthresh.resolve_interpret``).
 
@@ -423,7 +419,7 @@ def query_block(entries: jnp.ndarray, queries: jnp.ndarray, d, *,
                       pruning=pruning, use_pallas=use_pallas,
                       rows=int(real_e.shape[0]))
     prune_arrays = {}
-    host_prunable = (use_pallas and compaction in ("fused", "fused_rowloop")
+    host_prunable = (use_pallas and compaction == "fused"
                      and isinstance(entries, np.ndarray)
                      and isinstance(queries, np.ndarray)
                      and real_e.shape[0] and real_q.shape[0])
@@ -484,11 +480,10 @@ def _query_block_jit(entries: jnp.ndarray, queries: jnp.ndarray, d, *,
     if c == 0 or q == 0:
         return _empty_block(capacity, compute_dtype)
 
-    if compaction in ("fused", "fused_rowloop") and use_pallas:
+    if compaction == "fused" and use_pallas:
         pad_t = _pad_time(entries, queries)
         ep = _pad_rows(entries, cand_blk, pad_t)
         qp = _pad_rows(queries, qry_blk, pad_t)
-        append = "rowloop" if compaction == "fused_rowloop" else "chunk"
         num_tiles = (ep.shape[0] // cand_blk) * (qp.shape[0] // qry_blk)
         if pruning == "hierarchical":
             if tile_i is None:
@@ -500,7 +495,7 @@ def _query_block_jit(entries: jnp.ndarray, queries: jnp.ndarray, d, *,
              count) = _dt.distthresh_compact_live_pallas(
                 ep, qp.T, d, tile_i, tile_j, n_live, capacity=capacity,
                 cand_blk=cand_blk, qry_blk=qry_blk, valid_c=c, valid_q=q,
-                interpret=interpret, append=append)
+                interpret=interpret)
             pruned = jnp.asarray(num_tiles, jnp.int32) - n_live[0]
             return {"entry_idx": e_idx, "query_idx": q_idx,
                     "t_enter": t_enter, "t_exit": t_exit, "count": count,
@@ -518,7 +513,7 @@ def _query_block_jit(entries: jnp.ndarray, queries: jnp.ndarray, d, *,
          pruned) = _dt.distthresh_compact_pallas(
             ep, qp.T, d, capacity=capacity, cand_blk=cand_blk,
             qry_blk=qry_blk, valid_c=c, valid_q=q, interpret=interpret,
-            append=append, **prune_kw)
+            **prune_kw)
         return {"entry_idx": e_idx, "query_idx": q_idx,
                 "t_enter": t_enter, "t_exit": t_exit, "count": count,
                 "pruned_tiles": pruned,

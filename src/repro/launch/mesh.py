@@ -1,15 +1,8 @@
-"""Production mesh construction.
+"""Device-mesh construction.
 
-Defined as FUNCTIONS (not module-level constants) so importing this module
-never touches jax device state — the dry-run must set XLA_FLAGS before any
-device query, and tests must keep seeing 1 CPU device.
-
-Axes:
-* ``pod``   — 2 pods (multi-pod only).  Data parallelism + the trajectory
-  engine's temporal database partition live here; no parameter sharding
-  crosses it (cross-pod DCI is the slowest link).
-* ``data``  — 16-way batch / FSDP axis within a pod.
-* ``model`` — 16-way tensor/expert-parallel axis (fastest ICI locality).
+A function, not a module-level constant, so importing this module never
+touches jax device state: a process that forces a host device count must
+set ``XLA_FLAGS`` before the first device query.
 """
 from __future__ import annotations
 
@@ -25,26 +18,3 @@ def make_mesh_compat(shape, axes):
         return jax.make_mesh(shape, axes)
     return jax.make_mesh(shape, axes,
                          axis_types=(axis_type.Auto,) * len(axes))
-
-
-def make_production_mesh(*, multi_pod: bool = False):
-    shape = (2, 16, 16) if multi_pod else (16, 16)
-    axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return make_mesh_compat(shape, axes)
-
-
-def make_host_mesh(shape=(2, 2), axes=("data", "model")):
-    """Small mesh over however many (forced) host devices exist — tests."""
-    return make_mesh_compat(shape, axes)
-
-
-def data_axes(mesh) -> tuple[str, ...]:
-    """Axes carrying the batch dimension ("pod" included when present)."""
-    return tuple(a for a in ("pod", "data") if a in mesh.axis_names)
-
-
-def batch_ways(mesh) -> int:
-    n = 1
-    for a in data_axes(mesh):
-        n *= mesh.shape[a]
-    return n

@@ -53,8 +53,7 @@ class LintConfig:
     kern_modules: tuple = (
         "repro/kernels/distthresh.py",
         "repro/kernels/ops.py",
-        "repro/kernels/flashattn.py",
-    )
+        )
     #: grid-spec constructor names whose leading kernel params are
     #: scalar-prefetch refs — the live-tile-list contract (KERN006) is
     #: enforced on kernels launched through them

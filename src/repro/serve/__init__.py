@@ -1,4 +1,4 @@
-from repro.serve import batcher, broker, cache, engine, retry, trajectory  # noqa: F401
+from repro.serve import broker, cache, retry, trajectory  # noqa: F401
 from repro.serve.broker import (  # noqa: F401
     AdmissionError, DeadlineExceededError, Degradation, GroupSlice,
     QueryBroker, QueryTicket, TicketHealth)

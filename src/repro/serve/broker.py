@@ -60,7 +60,7 @@ backoff (deterministic jitter — chaos runs replay bit-identically),
 speculatively duplicates straggling groups (first completion wins; group
 execution is stateless so duplicates are byte-identical), and walks a
 **graceful-degradation ladder** on repeated non-transient failure:
-compaction ``fused → fused_rowloop → dense``, then backend
+compaction ``fused → dense``, then backend
 ``pallas → jnp``; a failing planner steps pruning ``hierarchical →
 spatial → none`` at submit; a dropped pod re-routes the ticket's
 remaining groups through a single-device fallback dispatcher.  Every
@@ -151,8 +151,8 @@ class GroupSlice:
 #: policy's compaction rung and steps down on repeated kernel failure;
 #: the batch plan is compaction/backend-independent, so every rung
 #: reuses it unchanged and produces byte-identical rows.
-DEGRADATION_LADDER = (("pallas", "fused"), ("pallas", "fused_rowloop"),
-                      ("pallas", "dense"), ("jnp", "dense"))
+DEGRADATION_LADDER = (("pallas", "fused"), ("pallas", "dense"),
+                      ("jnp", "dense"))
 
 
 @dataclasses.dataclass

@@ -30,3 +30,22 @@ def random_segments(rng: np.random.Generator, n: int, *, t_span=(0.0, 50.0),
         seg_id=np.arange(n, dtype=np.int32),
         traj_id=(np.arange(n, dtype=np.int32) % 7),
     )
+
+
+#: the (backend, compaction) modes of the single-device device path
+DEVICE_MODES = [("pallas", "fused"), ("pallas", "dense"), ("jnp", "dense")]
+
+#: canonical result columns that must equal brute force's exactly
+EXACT_COLUMNS = ("entry_idx", "entry_traj", "entry_seg", "query_idx")
+
+
+def assert_matches_brute(res, brute):
+    """``res``'s canonical rows are brute force's: ids exactly, intervals
+    to the cross-backend float32 tolerance."""
+    assert len(brute) > 0
+    for f in EXACT_COLUMNS:
+        np.testing.assert_array_equal(getattr(res, f), getattr(brute, f),
+                                      err_msg=f)
+    for f in ("t_enter", "t_exit"):
+        np.testing.assert_allclose(getattr(res, f), getattr(brute, f),
+                                   rtol=1e-4, atol=1e-3, err_msg=f)

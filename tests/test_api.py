@@ -40,13 +40,11 @@ def test_backend_equivalence_on_scenario(scenario_db):
     assert set(BACKENDS) == {"pallas", "jnp", "rtree", "brute", "shard"}
     results = {}
     for name in BACKENDS:
-        for compaction in ("fused", "fused_rowloop", "dense"):
+        for compaction in ("fused", "dense"):
             for pipeline in (True, False):
                 if name in ("rtree", "brute") and (compaction != "fused"
                                                    or not pipeline):
                     continue     # knobs don't reach the CPU baselines
-                if name != "pallas" and compaction == "fused_rowloop":
-                    continue     # rowloop is a Pallas-kernel escape hatch
                 res = db.query(queries, d, backend=name,
                                compaction=compaction, pipeline=pipeline)
                 results[(name, compaction, pipeline)] = res

@@ -2,7 +2,8 @@
 import numpy as np
 import pytest
 
-from conftest import random_segments
+from conftest import (DEVICE_MODES, EXACT_COLUMNS, assert_matches_brute,
+                      random_segments)
 from repro.core import batching
 from repro.core.engine import brute_force
 from repro.core.engine import DistanceThresholdEngine
@@ -358,14 +359,11 @@ def randwalk_db():
     return db, brute
 
 
-_ROWS = ("entry_idx", "entry_traj", "entry_seg", "query_idx")
-_ALL = _ROWS + ("t_enter", "t_exit")
-LADDER_MODES = [("jnp", "dense"), ("pallas", "fused"), ("pallas", "dense"),
-                ("pallas", "fused_rowloop")]
+_ALL = EXACT_COLUMNS + ("t_enter", "t_exit")
 
 
 @pytest.mark.parametrize("pruning", ["spatial", "hierarchical", "none"])
-@pytest.mark.parametrize("backend,compaction", LADDER_MODES)
+@pytest.mark.parametrize("backend,compaction", DEVICE_MODES)
 def test_randwalk_ladder_matches_brute_force(randwalk_db, backend,
                                              compaction, pruning):
     """Padded dispatches give the exact dispatches' canonical result, byte
@@ -381,12 +379,7 @@ def test_randwalk_ladder_matches_brute_force(randwalk_db, backend,
     for f in _ALL:
         np.testing.assert_array_equal(getattr(padded, f), getattr(exact, f),
                                       err_msg=f)
-    for f in _ROWS:
-        np.testing.assert_array_equal(getattr(exact, f), getattr(brute, f),
-                                      err_msg=f)
-    for f in ("t_enter", "t_exit"):
-        np.testing.assert_allclose(getattr(exact, f), getattr(brute, f),
-                                   rtol=1e-4, atol=1e-3, err_msg=f)
+    assert_matches_brute(exact, brute)
     assert exact.stats.counts["pad_rows"] == 0
     assert 0 < padded.stats.counts["pad_rows"] < padded.stats.counts[
         "dispatched_rows"]

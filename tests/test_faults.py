@@ -363,8 +363,7 @@ class TestBrokerRetry:
             res = t.result()
         _assert_same_rows(res, base, "ladder")
         stages = [(g.stage, g.after) for g in t.health.degradations]
-        assert stages == [("compaction", "pallas/fused_rowloop"),
-                          ("compaction", "pallas/dense"),
+        assert stages == [("compaction", "pallas/dense"),
                           ("backend", "jnp/dense")]
         assert res.degraded and t.health.degraded
 

@@ -7,7 +7,6 @@ from _hypothesis_compat import strategies as st
 from conftest import random_segments
 from repro.kernels import ops, ref
 from repro.kernels.distthresh import distthresh_pallas
-from repro.kernels.flashattn import flashattn_pallas, flashattn_ref
 
 
 class TestDistThreshKernel:
@@ -174,89 +173,6 @@ class TestFusedCompaction:
                             compaction="atomic")
 
 
-class TestRowloopEscapeHatch:
-    """The per-row append variant: identical results *and identical
-    order* to the chunked fused kernel; a kernel that fails to lower
-    raises instead of switching strategy."""
-
-    @pytest.mark.parametrize("c,q,cblk,qblk", [
-        (16, 16, 16, 16),      # single tile
-        (40, 24, 16, 8),       # multi-tile + row padding both axes
-        (8, 64, 8, 16),        # query-tile streaming
-    ])
-    def test_rowloop_matches_fused_order_exact(self, c, q, cblk, qblk):
-        rng = np.random.default_rng(c * 31 + q)
-        entries = random_segments(rng, c).packed()
-        queries = random_segments(rng, q).packed()
-        d = np.float32(15.0)
-        fused = ops.query_block(entries, queries, d, capacity=4096,
-                                use_pallas=True, compaction="fused",
-                                cand_blk=cblk, qry_blk=qblk)
-        rowl = ops.query_block(entries, queries, d, capacity=4096,
-                               use_pallas=True, compaction="fused_rowloop",
-                               cand_blk=cblk, qry_blk=qblk)
-        n = int(fused["count"])
-        assert int(rowl["count"]) == n > 0
-        # same deterministic order, not just the same set
-        np.testing.assert_array_equal(np.asarray(rowl["entry_idx"][:n]),
-                                      np.asarray(fused["entry_idx"][:n]))
-        np.testing.assert_array_equal(np.asarray(rowl["query_idx"][:n]),
-                                      np.asarray(fused["query_idx"][:n]))
-        np.testing.assert_allclose(np.asarray(rowl["t_enter"][:n]),
-                                   np.asarray(fused["t_enter"][:n]),
-                                   rtol=1e-4, atol=1e-3)
-        assert np.all(np.asarray(rowl["entry_idx"][n:]) == -1)
-
-    def test_rowloop_overflow_exact_count(self):
-        rng = np.random.default_rng(17)
-        entries = random_segments(rng, 48).packed()
-        queries = random_segments(rng, 32).packed()
-        d = np.float32(50.0)                       # everything hits
-        truth = int(np.asarray(ref.count_hits(entries, queries, d)))
-        out = ops.query_block(entries, queries, d, capacity=16,
-                              use_pallas=True, compaction="fused_rowloop",
-                              cand_blk=16, qry_blk=16)
-        assert int(out["count"]) == truth > 16
-        # the capacity prefix is still a valid (deterministic) hit prefix
-        assert np.all(np.asarray(out["entry_idx"][:16]) >= 0)
-
-    def test_lowering_error_reaches_caller_unchanged(self, monkeypatch):
-        """A kernel that fails to lower raises to the caller as-is: no
-        other compaction strategy is tried in its place."""
-        from repro.kernels import distthresh as dt
-        err = RuntimeError("Mosaic failed to compile TPU kernel")
-        calls = []
-
-        def refuses_to_lower(*args, **kwargs):
-            calls.append(kwargs.get("append", "chunk"))
-            raise err
-
-        monkeypatch.setattr(dt, "distthresh_compact_pallas",
-                            refuses_to_lower)
-        rng = np.random.default_rng(23)
-        # Unseen shapes, so the monkeypatched callable is actually traced.
-        entries = random_segments(rng, 72).packed()
-        queries = random_segments(rng, 24).packed()
-        with pytest.raises(RuntimeError) as info:
-            ops.query_block(entries, queries, np.float32(15.0),
-                            capacity=1024, use_pallas=True,
-                            compaction="fused", cand_blk=8, qry_blk=8)
-        assert info.value is err
-        assert calls == ["chunk"]
-
-    def test_rowloop_refuses_to_compile(self):
-        """The rowloop append is interpret-only: asked to compile, it
-        raises an error that names it instead of running another path."""
-        from repro.kernels import distthresh as dt
-        rng = np.random.default_rng(31)
-        entries = random_segments(rng, 8).packed()
-        queries = random_segments(rng, 8).packed()
-        with pytest.raises(NotImplementedError, match="rowloop"):
-            dt.distthresh_compact_pallas(entries, queries.T, np.float32(1.0),
-                                         capacity=256, cand_blk=8, qry_blk=8,
-                                         interpret=False, append="rowloop")
-
-
 class TestInterpretResolution:
     def test_interpret_resolves_true_on_cpu(self):
         """With no explicit choice, Pallas interprets on the CPU (the test
@@ -291,8 +207,7 @@ class TestEmptyInputGuards:
         assert te.shape == tx.shape == hit.shape == (c, q)
         assert not np.asarray(hit).any()
 
-    @pytest.mark.parametrize("compaction", ["fused", "fused_rowloop",
-                                            "dense"])
+    @pytest.mark.parametrize("compaction", ["fused", "dense"])
     def test_query_block_empty(self, compaction):
         entries = np.zeros((0, 8), np.float32)
         rng = np.random.default_rng(4)
@@ -329,30 +244,26 @@ class TestQueryBlockCompaction:
                               use_pallas=False)
         assert int(out["count"]) > 8                 # caller must retry
 
+    def test_lowering_error_reaches_caller_unchanged(self, monkeypatch):
+        """A kernel that fails to lower raises to the caller as-is: no
+        other compaction strategy is tried in its place."""
+        from repro.kernels import distthresh as dt
+        err = RuntimeError("Mosaic failed to compile TPU kernel")
+        calls = []
 
-class TestFlashAttnKernel:
-    @pytest.mark.parametrize("bkv,g,s,t,hd,bq,bk", [
-        (2, 2, 16, 16, 8, 8, 8),
-        (1, 4, 32, 32, 16, 16, 8),
-        (2, 1, 8, 16, 8, 8, 8),       # windowed: S < T
-        (1, 2, 64, 64, 32, 32, 32),
-    ])
-    def test_matches_ref(self, bkv, g, s, t, hd, bq, bk):
-        rng = np.random.default_rng(bkv * 100 + s)
-        q = rng.normal(size=(bkv * g, s, hd)).astype(np.float32)
-        k = rng.normal(size=(bkv, t, hd)).astype(np.float32)
-        v = rng.normal(size=(bkv, t, hd)).astype(np.float32)
-        o1 = np.asarray(flashattn_pallas(q, k, v, g=g, blk_q=bq, blk_k=bk))
-        o2 = np.asarray(flashattn_ref(q, k, v, g=g))
-        np.testing.assert_allclose(o1, o2, atol=1e-5)
+        def refuses_to_lower(*args, **kwargs):
+            calls.append(kwargs)
+            raise err
 
-    def test_bf16(self):
-        import jax.numpy as jnp
-        rng = np.random.default_rng(3)
-        q = jnp.asarray(rng.normal(size=(2, 16, 8)), jnp.bfloat16)
-        k = jnp.asarray(rng.normal(size=(2, 16, 8)), jnp.bfloat16)
-        v = jnp.asarray(rng.normal(size=(2, 16, 8)), jnp.bfloat16)
-        o1 = flashattn_pallas(q, k, v, g=1, blk_q=8, blk_k=8)
-        o2 = flashattn_ref(q, k, v, g=1)
-        np.testing.assert_allclose(np.asarray(o1, np.float32),
-                                   np.asarray(o2, np.float32), atol=0.1)
+        monkeypatch.setattr(dt, "distthresh_compact_pallas",
+                            refuses_to_lower)
+        rng = np.random.default_rng(23)
+        # Unseen shapes, so the monkeypatched callable is actually traced.
+        entries = random_segments(rng, 72).packed()
+        queries = random_segments(rng, 24).packed()
+        with pytest.raises(RuntimeError) as info:
+            ops.query_block(entries, queries, np.float32(15.0),
+                            capacity=1024, use_pallas=True,
+                            compaction="fused", cand_blk=8, qry_blk=8)
+        assert info.value is err
+        assert len(calls) == 1
